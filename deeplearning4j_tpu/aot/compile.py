@@ -38,19 +38,21 @@ from .keys import arch_fingerprint, cache_key, call_signature, \
     runtime_fingerprint
 from .store import AotCorruptEntry, AotStore, AotStoreError, AotVersionError
 
-_BLOB_SCHEMA = 1
+_BLOB_SCHEMA = 2  # 2: device ids recorded beside the executable
 
 
 def serialize_compiled(compiled) -> bytes:
     """One compiled executable -> portable bytes (payload + arg pytrees +
-    the jax/jaxlib pair that built it, double-checked at load time)."""
+    the ids of the devices it was compiled for + the jax/jaxlib pair that
+    built it, double-checked at load time)."""
     import jax
     import jaxlib
     from jax.experimental import serialize_executable as se
 
     payload, in_tree, out_tree = se.serialize(compiled)
+    devices = [d.id for d in compiled.runtime_executable().local_devices()]
     return pickle.dumps({"schema": _BLOB_SCHEMA, "jax": jax.__version__,
-                         "jaxlib": jaxlib.__version__,
+                         "jaxlib": jaxlib.__version__, "devices": devices,
                          "exe": (payload, in_tree, out_tree)})
 
 
@@ -72,7 +74,17 @@ def deserialize_compiled(blob: bytes):
             f"executable built by jax {rec.get('jax')}/jaxlib "
             f"{rec.get('jaxlib')}, running {jax.__version__}/"
             f"{jaxlib.__version__}")
-    return se.deserialize_and_load(*rec["exe"])
+    # load onto the devices the executable was compiled for: left to its
+    # default, deserialize_and_load targets EVERY local device, and a
+    # one-device executable then rejects its arguments at call time
+    # ("expected N shards") on any multi-device host
+    by_id = {d.id: d for d in jax.devices()}
+    try:
+        devices = [by_id[i] for i in rec["devices"]]
+    except KeyError as e:
+        raise AotStoreError(
+            f"executable compiled for device id {e} absent on this host")
+    return se.deserialize_and_load(*rec["exe"], execution_devices=devices)
 
 
 class AotFunction:

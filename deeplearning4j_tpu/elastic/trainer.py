@@ -156,7 +156,7 @@ class ElasticTrainer:
         self._steps = {d: AotFunction(
             self._make_pstep(d), tag=f"elastic_pstep_dp{d}",
             store=self.store, metrics=metrics, arch=self._arch,
-            component="elastic",
+            component="elastic", donate_argnums=(0, 1, 2),
             compile_counter=self._trace_counts[d]) for d in self._ladder}
         self._warmed = False
 
@@ -236,13 +236,12 @@ class ElasticTrainer:
         opt_sh = self._opt_shardings(d, self.opt_state)
         model, tx = self.model, self.tx
 
-        # deliberately NOT donated: executables that donate operands
-        # corrupt the heap after a serialize_executable round-trip on
-        # current jaxlib (verified against 0.4.36 CPU — nondeterministic
-        # glibc aborts once a store-loaded pstep runs), and the store
-        # round-trip is this trainer's whole no-trace-at-resize contract
-        @partial(jax.jit, out_shardings=(repl, opt_sh, repl, repl))
-        def pstep(params, opt_state, net_state, x, y, rng):  # jaxlint: disable=missing-donate
+        # params/opt-state/net-state are loop-carried: donated, like the
+        # plain Trainer's step (a store-loaded donating executable runs
+        # clean on the installed jaxlib — tests/test_elastic.py drills it)
+        @partial(jax.jit, donate_argnums=(0, 1, 2),
+                 out_shardings=(repl, opt_sh, repl, repl))
+        def pstep(params, opt_state, net_state, x, y, rng):
             def loss_fn(p):
                 loss, new_state = model.score(p, net_state, x, y,
                                               training=True, rng=rng)

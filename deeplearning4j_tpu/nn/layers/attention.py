@@ -71,6 +71,51 @@ def rope_rotate(x, positions, base: float = 10000.0):
                             x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
+def _flash_attend(q, k, v, *, causal, lengths, key_mask, window):
+    """The flash kernel, placed per device when the step is being traced
+    under a multi-device mesh (Trainer/ParallelWrapper ``mesh=``).
+
+    A ``pallas_call`` is opaque to GSPMD: under a multi-device ``jit`` XLA
+    cannot partition it, so the kernel is wrapped in ``shard_map`` over the
+    ambient mesh — batch over the data axis, heads over the model axis
+    (attention is independent per example and per head, so no collective is
+    needed). An axis that does not divide its dim, or any other mesh axis,
+    leaves that dim replicated: every device along it runs the same kernel
+    on the same block."""
+    from ...ops.flash_attention import flash_attention
+    from ..api import ACTIVE_MESH
+
+    mesh = ACTIVE_MESH.get()
+    if mesh is None or mesh.size == 1:
+        return flash_attention(q, k, v, causal=causal, lengths=lengths,
+                               key_mask=key_mask, window=window)
+    from jax.sharding import PartitionSpec as P
+
+    from ...parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+    B, _, H, _ = q.shape
+    dp, tp = mesh.shape.get(DATA_AXIS, 1), mesh.shape.get(MODEL_AXIS, 1)
+    b_ax = DATA_AXIS if dp > 1 and B % dp == 0 else None
+    h_ax = MODEL_AXIS if tp > 1 and H % tp == 0 else None
+    qkv = P(b_ax, None, h_ax, None)
+    operands, specs = [q, k, v], [qkv] * 3
+    if lengths is not None:  # mutually exclusive with key_mask
+        operands.append(lengths)
+        specs.append(P(b_ax))
+    elif key_mask is not None:
+        operands.append(key_mask)
+        specs.append(P(b_ax, None))
+
+    def local(q, k, v, *m):
+        return flash_attention(
+            q, k, v, causal=causal, window=window,
+            lengths=m[0] if lengths is not None else None,
+            key_mask=m[0] if key_mask is not None else None)
+
+    return jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=qkv, check_vma=False)(*operands)
+
+
 @register_layer
 @dataclass(frozen=True)
 class MultiHeadAttention(Layer):
@@ -214,15 +259,14 @@ class MultiHeadAttention(Layer):
             # lengths path (interior-block specialization + tail-block
             # skipping) is used. Attention dropout (weights never
             # materialized) falls back to dense.
-            from ...ops.flash_attention import flash_attention
-
+            lengths = key_mask = None
             if mask is not None and self.ragged:
                 lengths = mask.astype(jnp.int32).sum(axis=-1)
-                y = flash_attention(q, k, v, causal=self.causal,
-                                    lengths=lengths, window=self.window)
             else:
-                y = flash_attention(q, k, v, causal=self.causal,
-                                    key_mask=mask, window=self.window)
+                key_mask = mask
+            y = _flash_attend(q, k, v, causal=self.causal,
+                                  lengths=lengths, key_mask=key_mask,
+                                  window=self.window)
         else:
             attn_mask = None
             if self.causal:

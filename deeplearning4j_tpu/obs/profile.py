@@ -70,19 +70,15 @@ def _jax_fence(value: Any) -> None:
     jax.block_until_ready(value)
 
 
-def _jax_hbm_peak() -> int:
-    """Peak device-memory bytes from the backend allocator, 0 when the
-    backend keeps no stats (CPU) or jax is absent."""
-    try:
-        import jax
+def _jax_hbm_peak() -> Optional[int]:
+    """Peak device-memory bytes from the backend allocator; None when the
+    backend keeps no stats (CPU) — no series is written then."""
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:  # any backend without allocator stats reads as 0  # jaxlint: disable=broad-except
-        return 0
-    if not stats:
-        return 0
-    return int(stats.get("peak_bytes_in_use")
-               or stats.get("bytes_in_use") or 0)
+    stats = jax.local_devices()[0].memory_stats()
+    if not stats or "peak_bytes_in_use" not in stats:
+        return None
+    return int(stats["peak_bytes_in_use"])
 
 
 class _ExecStats:
@@ -242,7 +238,7 @@ class Profiler:
         out = exe(*args)
         self._fence(out)
         dt = self._clock() - t0
-        hbm = self._hbm_probe() if self._hbm_probe is not None else 0
+        hbm = self._hbm_probe() if self._hbm_probe is not None else None
         with self._lock:
             st.sampled += 1
             st.device_s += dt
@@ -251,7 +247,7 @@ class Profiler:
                     st.pairs.append((hint[0], dt))
                 else:  # deterministic ring replacement, no RNG
                     st.pairs[st.sampled % _MAX_PAIRS] = (hint[0], dt)
-            if hbm > self._hbm.get(component, 0):
+            if hbm is not None and hbm > self._hbm.get(component, 0):
                 self._hbm[component] = hbm
             dispatches = st.dispatches
             dev_est = st.device_s_est()
@@ -290,7 +286,7 @@ class Profiler:
         h.observe(dt)
         self._g_disp[mk].set(dispatches)
         self._g_dev_est[mk].set(dev_est)
-        if hbm > 0:
+        if hbm is not None:
             g = self._g_hbm.get(component)
             if g is None:
                 labels = {"component": component}

@@ -18,8 +18,8 @@ had):
 Compile-cache misses are counted at the trainer's ``_batch_sig`` altitude:
 a (structure, shape, dtype) signature never seen before means jax will
 trace+compile — the first call and every shape change. Device memory is
-gauged from ``device.memory_stats()`` where the backend provides it, with a
-host-RSS fallback so CPU runs still chart something honest.
+gauged from ``device.memory_stats()`` where the backend provides it; a
+backend that keeps no allocator statistics (CPU) gets no series.
 
 Everything here is HOST-side: nothing is traced, nothing touches the jitted
 step functions, so telemetry can never introduce a jaxlint host-sync finding
@@ -33,16 +33,6 @@ from typing import Any, Callable, Dict, Iterable, Iterator, Optional, Set
 
 from .metrics import MetricsRegistry
 from .trace import Tracer
-
-
-def _host_rss_bytes() -> float:
-    """Process resident set size; 0.0 where unavailable (non-POSIX)."""
-    try:
-        import resource
-    except ImportError:
-        return 0.0
-    # ru_maxrss is KiB on Linux (bytes on macOS; close enough for a gauge)
-    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024.0
 
 
 class StepTelemetry:
@@ -190,36 +180,24 @@ class StepTelemetry:
         return out
 
     def record_memory(self) -> None:
-        """Device memory gauges, host-RSS fallback when the backend (CPU)
-        exposes no per-device allocator stats."""
+        """Device memory gauges from each device's allocator statistics.
+        A backend that reports none (CPU) gets no series — host memory is
+        never written under a device-memory name."""
         import jax
 
-        g = self.registry.gauge
-        saw_device_stats = False
         for d in jax.local_devices():
-            fn = getattr(d, "memory_stats", None)
-            if fn is None:
-                continue
-            try:
-                stats = fn()
-            except (NotImplementedError, RuntimeError, ValueError):
-                stats = None
+            stats = d.memory_stats()
             if not stats:
                 continue
-            saw_device_stats = True
             for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
                 if key in stats:
-                    g("device_memory_bytes",
-                      {"device": f"{d.platform}:{d.id}", "kind": key},
-                      help="per-device allocator stats (host RSS fallback "
-                           "where the backend has none)"
-                      ).set(float(stats[key]))
-        if not saw_device_stats:
-            rss = _host_rss_bytes()
-            if rss:
-                g("device_memory_bytes", {"device": "host", "kind": "rss"},
-                  help="per-device allocator stats (host RSS fallback "
-                       "where the backend has none)").set(rss)
+                    self.registry.gauge(
+                        "device_memory_bytes",
+                        # bounded by the device count, not traffic
+                        # jaxlint: disable-next=metric-label-cardinality
+                        {"device": f"{d.platform}:{d.id}", "kind": key},
+                        help="per-device allocator stats, where the "
+                             "backend reports them").set(float(stats[key]))
 
     # --- export ---
     def snapshot(self) -> dict:

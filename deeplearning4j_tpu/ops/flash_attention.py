@@ -15,8 +15,9 @@ playbook (/opt/skills/guides/pallas_guide.md):
   saves only (o, logsumexp); gradients are rebuilt q-block-by-q-block in a
   ``lax.scan`` (pure JAX: XLA already fuses the per-block matmul chain well,
   and the scan bounds memory the same way the kernel does)
-- ``interpret=True`` automatically off-TPU, so the same code path is testable
-  on the CPU mesh (pl.pallas_call interpreter mode)
+- ``interpret=True`` automatically on the CPU backend, so the same code path
+  is testable on the CPU mesh (pl.pallas_call interpreter mode); any other
+  non-TPU backend raises
 
 Causal masking and right-padded sequences (T not a multiple of the block)
 are handled with compile-time index masks.
@@ -81,7 +82,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, lens_ref, kmask_ref, o_ref, lse_ref,
             if has_lens:
                 valid = valid & (k_pos < L)      # ragged example length
             if has_kmask:
-                valid = valid & (kmask_ref[0, 0] != 0)[None, :]
+                valid = valid & (kmask_ref[0] != 0)      # (1, bk) row
             if causal:
                 valid = valid & (k_pos <= q_pos)
             if window:  # sliding window: q attends [q-window+1, q]
@@ -235,8 +236,8 @@ def _flash_vjp_fwd(q, k, v, lens, kmask, scale, causal, bq, bk, interpret,
 
 
 # Block cap for the Mosaic backward kernels (the backward keeps more live
-# tiles than the forward, so its VMEM-optimal block is smaller; 512 measured
-# best on v5e at T<=4096 — scripts/chip_flashbwd.py sweeps this).
+# tiles than the forward, so its VMEM-optimal block is smaller; 512 was the
+# best of one v5e sweep at T<=4096 in 2026-07 — not re-measured since).
 BWD_BLOCK_CAP = 512
 
 
@@ -247,7 +248,7 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
     returns (p, ds) with p = exp(s - lse) (masked) and
     ds = p * (do @ v^T - delta) * scale. ``L`` (traced scalar): ragged
     example length — keys >= L are masked like the forward. ``kmask_row``
-    ((bk,) traced): exact key mask block, same forward parity."""
+    ((1, bk) traced): exact key mask block, same forward parity."""
     q = q_ref[0].astype(jnp.float32)          # (bq, D)
     k = k_ref[0].astype(jnp.float32)          # (bk, D)
     s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -261,7 +262,7 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
         if L is not None:
             valid = valid & (k_pos < L)
         if kmask_row is not None:
-            valid = valid & (kmask_row != 0)[None, :]
+            valid = valid & (kmask_row != 0)
         if causal:
             valid = valid & (k_pos <= q_pos)
         if window:
@@ -296,7 +297,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, lens_ref,
                           scale=scale, causal=causal, masked=masked,
                           iq=iq, ik=ik, bq=bq, bk=bk, t_actual=t_actual,
                           L=L if masked else None,
-                          kmask_row=(kmask_ref[0, 0]
+                          kmask_row=(kmask_ref[0]
                                      if masked and has_kmask else None),
                           window=window if masked else 0)
         dq_scr[...] += lax.dot_general(
@@ -353,7 +354,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, lens_ref,
                           scale=scale, causal=causal, masked=masked,
                           iq=iq, ik=ik, bq=bq, bk=bk, t_actual=t_actual,
                           L=L if masked else None,
-                          kmask_row=(kmask_ref[0, 0]
+                          kmask_row=(kmask_ref[0]
                                      if masked and has_kmask else None),
                           window=window if masked else 0)
         # dv += p^T @ do ((bk, bq) @ (bq, D)); p in [0,1] — bf16 operand ok
@@ -401,7 +402,7 @@ def _flash_bwd_pallas(q, k, v, lens, kmask, o, lse, do, scale, causal, bq, bk,
 
     BH, T, D = q.shape
     # more live tiles than the forward (q, k, v, do + p/ds): cap blocks to
-    # stay inside VMEM (sweepable — see scripts/chip_flashbwd.py)
+    # stay inside VMEM
     bq, bk = min(bq, BWD_BLOCK_CAP), min(bk, BWD_BLOCK_CAP)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)       # (BH, T, 1)
@@ -571,8 +572,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
     """Memory-efficient exact attention. q, k, v: (B, T, H, D) (the layout of
     ``dot_product_attention``); returns (B, T, H, D).
 
-    Differentiable (custom flash VJP). Off-TPU the kernel runs in Pallas
-    interpreter mode automatically, so CPU tests exercise the same code.
+    Differentiable (custom flash VJP). On the CPU backend the kernel runs in
+    Pallas interpreter mode automatically, so CPU tests exercise the same
+    code; a backend that is neither TPU nor CPU raises.
 
     ``lengths`` ((B,) int32, optional): ragged example lengths for
     RIGHT-PADDED batches — keys at positions >= lengths[b] are masked out
@@ -639,15 +641,23 @@ def flash_attention(q, k, v, *, causal: bool = False,
         # the O(T·window) claim needs block SKIPPING in the backward too;
         # the XLA scan backward computes full (bq, T) scores per q block
         # and only masks, so windowed calls default to the Mosaic backward
-        # (chip-validated numerics; scripts/chip_flashbwd.py covers the
-        # windowed case)
+        # (both backwards are checked against dense attention on the chip
+        # by chip_smoke.py leg (a), windowed case included)
         bw = "pallas"
     else:
         bw = BACKWARD
     if bw not in ("pallas", "xla"):
         raise ValueError(f"backward must be 'pallas' or 'xla', got {bw!r}")
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        # compiled on TPU; interpreted on the CPU backend (the test suite);
+        # anywhere else there is no lowering for this kernel and silently
+        # interpreting it would hide that
+        platform = jax.default_backend()
+        if platform not in ("tpu", "cpu"):
+            raise NotImplementedError(
+                f"flash_attention has no {platform!r} lowering (TPU "
+                f"compiles, CPU interprets); use dot_product_attention")
+        interpret = platform == "cpu"
     # Python-float scale: embedded as an f32 scalar constant in the kernel —
     # an np.float64 here would silently promote the whole QK^T tree.
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)  # jaxlint: disable=host-sync

@@ -533,7 +533,8 @@ class ContinuousBatcher:
         self._m_completed = m.counter("serve_gen_completed_total", self._lbl(),
                                       help="generation requests finished")
         self._m_tokens = m.counter("serve_gen_tokens_total", self._lbl(),
-                                   help="tokens decoded across all slots")
+                                   help="tokens generated across all slots (each request's "
+                                        "prefill-sampled first token included)")
         self._m_decode_s = m.histogram("serve_gen_decode_seconds", self._lbl(),
                                        help="one all-slots decode tick")
         self._m_prefill_s = m.histogram("serve_gen_prefill_seconds",
@@ -1170,6 +1171,7 @@ class ContinuousBatcher:
             self._peak_active = max(self._peak_active, active)
             self._m_active.set(active)
         req._push(tok0)
+        self._m_tokens.inc()  # the prefill-sampled token is output too
         # a 1-token request (or instant EOS) finishes without ever decoding
         self._maybe_finish(s)
 
@@ -1223,6 +1225,7 @@ class ContinuousBatcher:
             self._peak_active = max(self._peak_active, active)
             self._m_active.set(active)
         req._push(tok0)
+        self._m_tokens.inc()  # the prefill-sampled token is output too
         # a 1-token request (or instant EOS) finishes without ever decoding
         self._maybe_finish(s)
 

@@ -119,12 +119,8 @@ def restore_trainer(directory: str, trainer):
     # genuinely corrupt checkpoint or structure mismatch surfaces as ITS OWN
     # error rather than a second, unrelated-looking retry failure
     saved = _checkpointer().metadata(
-        os.path.join(os.path.abspath(directory), "arrays"))
-    # orbax >= 0.9 wraps the tree in CheckpointMetadata.item_metadata;
-    # earlier releases hand back the metadata tree (a dict) directly
-    item = getattr(saved, "item_metadata", None)
-    if item is not None:
-        saved = item.tree
+        os.path.join(os.path.abspath(directory), "arrays")
+    ).item_metadata.tree
     if saved.get("opt_state") == {}:
         template["opt_state"] = {}
     for opt_key in ("residual", "trainer_rng", "iteration"):

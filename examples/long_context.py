@@ -3,8 +3,9 @@
 Each device holds a (B, T/n, H, D) slice of the sequence; K/V blocks rotate
 around the ring via collective permute while a streaming softmax accumulates
 EXACT attention (no (T, T) score tensor ever exists, and within each ring
-step keys stream in bounded chunks). Falls back to a virtual 8-device CPU
-mesh; on a TPU slice the same code rides the ICI ring.
+step keys stream in bounded chunks). Needs four devices: with
+JAX_PLATFORMS=cpu that is a virtual 8-device CPU mesh; on a TPU slice the
+same code rides the ICI ring.
 """
 
 import sys

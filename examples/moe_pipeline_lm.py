@@ -1,6 +1,7 @@
 """Mixture-of-Experts + pipeline-parallel causal LM — the scaling-axes demo
 (ep + pp; dp/tp/sp are shown in parallel_training.py and the transformer
-sharding rules). Runs anywhere: falls back to a virtual 8-device CPU mesh.
+sharding rules). Needs four devices: a TPU slice, or JAX_PLATFORMS=cpu for
+an 8-device virtual CPU mesh.
 
 1. Trains a Switch-style MoE causal LM with the standard Trainer (the MoE
    load-balancing aux loss flows through Sequential.score automatically).

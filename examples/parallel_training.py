@@ -9,7 +9,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from examples._common import setup
 
-setup(min_devices=2)  # needs a mesh; falls back to 8 virtual CPU devices
+setup(min_devices=2)  # needs a mesh (JAX_PLATFORMS=cpu gives 8 virtual devices)
 
 import numpy as np
 

@@ -8,20 +8,10 @@ Stage B: a copy kernel that loads a (1, bq, 1) block and broadcasts it to
 Stage C: multi-block non-causal, then causal — isolates accumulation and
 the mask/reachability specialization.
 """
+import os
 import sys
-import threading
 
-sys.path.insert(0, "/root/repo")
-
-out = {}
-def probe():
-    import jax
-    out["d"] = jax.devices()
-t = threading.Thread(target=probe, daemon=True)
-t.start(); t.join(90)
-if "d" not in out:
-    print("WEDGED"); raise SystemExit(3)
-print("devices:", out["d"])
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
 import functools
 
@@ -33,6 +23,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 import deeplearning4j_tpu.ops.flash_attention as fa
 
+print("devices:", jax.devices())
 rng = np.random.RandomState(0)
 
 
@@ -77,6 +68,6 @@ cmp("stageA single-block noncausal", 1, 256, 1, 128, False, 256, 256)
 cmp("stageC1 4-block noncausal", 1, 1024, 1, 128, False, 256, 256)
 # Stage C2: multi-block causal (mask + reachability specialization)
 cmp("stageC2 4-block causal", 1, 1024, 1, 128, True, 256, 256)
-# Stage C3: the failing shape from chip_flashbwd
+# Stage C3: the shape that first failed on the chip (2026-07)
 cmp("stageC3 orig", 2, 1024, 4, 64, True, 512, 512)
 print("DONE", flush=True)

@@ -7,10 +7,14 @@ and measures throughput at BENCH_BATCH (default 256).
 
 import os
 import re
+import sys
 import time
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import jax
 import numpy as np
+from model_benches import peak_bf16
 
 from deeplearning4j_tpu.data import BenchmarkIterator
 from deeplearning4j_tpu.models import ResNet50
@@ -70,5 +74,5 @@ t1, params, opt_state, state = run(5, params, opt_state, state)
 t2, params, opt_state, state = run(15, params, opt_state, state)
 per_step = (t2 - t1) / 10
 ips = batch / per_step
-mfu = ips * 3 * 8.18e9 * (img / 224.0) ** 2 / 197e12
+mfu = ips * 3 * 8.18e9 * (img / 224.0) ** 2 / peak_bf16(dev.device_kind)
 print(f"batch {batch}: {per_step*1e3:.2f} ms/step, {ips:.1f} img/s, MFU(2/MAC)={mfu:.3f}")

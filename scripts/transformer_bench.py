@@ -8,12 +8,12 @@ import os
 import sys
 import time
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax
 import numpy as np
-
-PEAK = 197e12  # v5e bf16
+from model_benches import peak_bf16
 
 B = int(os.environ.get("TB_BATCH", 8))
 T = int(os.environ.get("TB_SEQ", 2048))
@@ -67,18 +67,16 @@ def measure(flash):
     n_matmul = n_params - n_embed
     # + causal attention: 12*B*T^2*DM*L/2 (fwd+bwd, halved for causality)
     flops = 6 * n_matmul * B * T + 12 * B * T * T * DM * L // 2
-    return dt, flops / dt / PEAK, lf, n_params, n_matmul
+    peak = peak_bf16(jax.devices()[0].device_kind)
+    return dt, flops / dt / peak, lf, n_params, n_matmul
 
 
 def main():
     for flash in (False, True):
-        try:
-            dt, mfu, loss, n, nm = measure(flash)
-            print(f"flash={flash}: {dt * 1e3:8.2f} ms/step  MFU {mfu:.3f}  "
-                  f"loss {loss:.3f}  params {n / 1e6:.1f}M "
-                  f"(matmul {nm / 1e6:.1f}M)  tokens/s {B * T / dt:,.0f}")
-        except Exception as e:
-            print(f"flash={flash} failed: {type(e).__name__}: {str(e)[:300]}")
+        dt, mfu, loss, n, nm = measure(flash)
+        print(f"flash={flash}: {dt * 1e3:8.2f} ms/step  MFU {mfu:.3f}  "
+              f"loss {loss:.3f}  params {n / 1e6:.1f}M "
+              f"(matmul {nm / 1e6:.1f}M)  tokens/s {B * T / dt:,.0f}")
 
 
 if __name__ == "__main__":

@@ -322,7 +322,8 @@ class TestStepTelemetry:
         prom = tel.to_prometheus()
         assert "# TYPE train_step_seconds histogram" in prom
         assert "compile_cache_misses_total 1" in prom
-        assert "device_memory_bytes" in prom  # CPU fallback keeps the gauge
+        # the CPU backend keeps no allocator stats: no device-memory series
+        assert "device_memory_bytes" not in prom
         assert "train_step_seconds_count 5" in prom
         assert "train_data_wait_seconds" in prom
         assert "train_device_compute_seconds" in prom
@@ -364,13 +365,32 @@ class TestStepTelemetry:
                telemetry=tel)
         assert tel.snapshot()["steps"] == 5
 
-    def test_record_memory_cpu_fallback(self):
+    def test_record_memory_omits_series_without_device_stats(self):
+        """Host memory is never written under a device-memory name: the CPU
+        backend reports no allocator stats, so there is no series; a device
+        that does report them gets one gauge per stat."""
         tel = StepTelemetry()
         tel.record_memory()
-        snap = tel.registry.snapshot()
-        assert "device_memory_bytes" in snap
-        series = snap["device_memory_bytes"]["series"]
-        assert all(s["value"] > 0 for s in series)
+        assert "device_memory_bytes" not in tel.registry.snapshot()
+
+    def test_record_memory_gauges_reported_stats(self, monkeypatch):
+        import jax
+
+        class _Dev:
+            platform, id = "tpu", 0
+
+            def memory_stats(self):
+                return {"bytes_in_use": 5, "peak_bytes_in_use": 9,
+                        "bytes_limit": 16}
+
+        monkeypatch.setattr(jax, "local_devices", lambda: [_Dev()])
+        tel = StepTelemetry()
+        tel.record_memory()
+        series = tel.registry.snapshot()["device_memory_bytes"]["series"]
+        assert {s["labels"]["kind"]: s["value"] for s in series} == {
+            "bytes_in_use": 5.0, "peak_bytes_in_use": 9.0,
+            "bytes_limit": 16.0}
+        assert all(s["labels"]["device"] == "tpu:0" for s in series)
 
     def test_wrap_iterator_times_data_wait(self):
         tel = StepTelemetry()

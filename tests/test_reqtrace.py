@@ -504,9 +504,15 @@ class TestFleetTracingEndToEnd:
                 ei.value.headers["traceparent"])[0]
 
             # the faulted request's record is in the flight ring with the
-            # full admit -> queue -> prefill -> decode -> shed shape
-            rec = [r for r in flight_mod.ACTIVE.requests()
-                   if r["trace_id"] == trace_id]
+            # full admit -> queue -> prefill -> decode -> shed shape (the
+            # handler files it AFTER the reply is written: poll briefly)
+            deadline = time.monotonic() + 5.0
+            while True:
+                rec = [r for r in flight_mod.ACTIVE.requests()
+                       if r["trace_id"] == trace_id]
+                if rec or time.monotonic() > deadline:
+                    break
+                time.sleep(0.02)
             assert len(rec) == 1
             rec = rec[0]
             assert rec["status"] == "error" \
