@@ -134,8 +134,9 @@ def cache_read(cache):
         kp, tables = cache["k_pool"], cache["tables"]
         B, maxb = tables.shape
         bs, Hkv, hd = kp.shape[1:]
-        ck = kp[tables].reshape(B, maxb * bs, Hkv, hd)
-        cv = cache["v_pool"][tables].reshape(B, maxb * bs, Hkv, hd)
+        with jax.named_scope("cache_read"):  # the gather's name in a trace
+            ck = kp[tables].reshape(B, maxb * bs, Hkv, hd)
+            cv = cache["v_pool"][tables].reshape(B, maxb * bs, Hkv, hd)
         return ck, cv
     return cache["k"], cache["v"]
 
@@ -266,25 +267,31 @@ def decode_forward(model: Sequential, params, state, x, caches, pos):
         k = _layer_key(i, layer)
         p = params.get(k, {})
         if cdt is not None:
-            p = _cast_floats(p, cdt)
+            # named scopes are HLO metadata only: a device trace carries
+            # them with every operation (obs/README.md, "Hot-path spans")
+            with jax.named_scope("weight_cast"):
+                p = _cast_floats(p, cdt)
         if isinstance(layer, TransformerEncoderBlock):
-            h = layer._ln(x, p["ln1_g"], p["ln1_b"])
-            a, new[k] = _mha_decode(layer.num_heads, p["attn"], h, new[k],
-                                    pos, rope=layer.rope,
-                                    rope_base=layer.rope_base,
-                                    num_kv_heads=layer.num_kv_heads,
-                                    window=layer.window)
+            with jax.named_scope("attention"):
+                h = layer._ln(x, p["ln1_g"], p["ln1_b"])
+                a, new[k] = _mha_decode(layer.num_heads, p["attn"], h, new[k],
+                                        pos, rope=layer.rope,
+                                        rope_base=layer.rope_base,
+                                        num_kv_heads=layer.num_kv_heads,
+                                        window=layer.window)
             x = x + a
-            h = layer._ln(x, p["ln2_g"], p["ln2_b"])
-            m = (_act.get(layer.activation)(h @ p["w_up"] + p["b_up"])
-                 @ p["w_down"] + p["b_down"])
+            with jax.named_scope("mlp"):
+                h = layer._ln(x, p["ln2_g"], p["ln2_b"])
+                m = (_act.get(layer.activation)(h @ p["w_up"] + p["b_up"])
+                     @ p["w_down"] + p["b_down"])
             x = x + m
         elif isinstance(layer, MultiHeadAttention):
-            x, new[k] = _mha_decode(layer.num_heads, p, x, new[k], pos,
-                                    rope=layer.rope,
-                                    rope_base=layer.rope_base,
-                                    num_kv_heads=layer.num_kv_heads,
-                                    window=layer.window)
+            with jax.named_scope("attention"):
+                x, new[k] = _mha_decode(layer.num_heads, p, x, new[k], pos,
+                                        rope=layer.rope,
+                                        rope_base=layer.rope_base,
+                                        num_kv_heads=layer.num_kv_heads,
+                                        window=layer.window)
         elif isinstance(layer, PositionalEmbedding):
             Tq = x.shape[1]
             pv = _pos_vec(pos)

@@ -349,12 +349,16 @@ class TransformerEncoderBlock(Layer):
                                  rope=self.rope, rope_base=self.rope_base,
                                  num_kv_heads=self.num_kv_heads,
                                  window=self.window, ragged=self.ragged)
-        h = self._ln(x, params["ln1_g"], params["ln1_b"])
-        a, _, _ = mha.apply(params["attn"], {}, h, training=training, rng=rng, mask=mask)
+        # named scopes are HLO metadata only: they name these operations in
+        # a profiler trace (obs/README.md, "Hot-path spans")
+        with jax.named_scope("attention"):
+            h = self._ln(x, params["ln1_g"], params["ln1_b"])
+            a, _, _ = mha.apply(params["attn"], {}, h, training=training, rng=rng, mask=mask)
         x = x + a
-        h = self._ln(x, params["ln2_g"], params["ln2_b"])
-        act = activations.get(self.activation)
-        m = act(h @ params["w_up"] + params["b_up"]) @ params["w_down"] + params["b_down"]
+        with jax.named_scope("mlp"):
+            h = self._ln(x, params["ln2_g"], params["ln2_b"])
+            act = activations.get(self.activation)
+            m = act(h @ params["w_up"] + params["b_up"]) @ params["w_down"] + params["b_down"]
         if training and self.dropout_rate > 0 and rng is not None:
             from ...ops.regularization import dropout as do
 

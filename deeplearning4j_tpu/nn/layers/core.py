@@ -271,9 +271,10 @@ class _LossMixin:
 
     def score_from_preactivation(self, preact: Array, labels: Array, mask=None):
         fn, fused = self._loss_fn_and_preact()
-        if fused:
-            return fn(preact, labels, mask=mask)
-        return fn(activations.get(getattr(self, "activation", "identity"))(preact), labels, mask=mask)
+        with jax.named_scope("loss"):
+            if fused:
+                return fn(preact, labels, mask=mask)
+            return fn(activations.get(getattr(self, "activation", "identity"))(preact), labels, mask=mask)
 
 
 @register_layer
@@ -298,10 +299,11 @@ class Output(Layer, _LossMixin):
         return params, {}
 
     def preactivation(self, params, x):
-        y = x @ params["w"]
-        if self.use_bias:
-            y = y + params["b"]
-        return y
+        with jax.named_scope("head"):
+            y = x @ params["w"]
+            if self.use_bias:
+                y = y + params["b"]
+            return y
 
     def apply(self, params, state, x, *, training=False, rng=None, mask=None):
         x = apply_input_dropout(self, x, rng, training)

@@ -123,7 +123,11 @@ class Histogram:
         # traced observation that landed in that bucket, exported as an
         # OpenMetrics exemplar so a p99 bucket links straight to a trace
         self._exemplars: Dict[int, Tuple[float, str, float]] = {}
-        self._lock = threading.Lock()
+        # re-entrant: a garbage collection can start on an allocation made
+        # while this lock is held (a snapshot copying the counts), and the
+        # collector's hook (obs/trace.py:GcPauses) observes into a histogram
+        # on that same thread
+        self._lock = threading.RLock()
 
     def observe(self, value: float, trace_id: Optional[str] = None) -> None:
         v = float(value)

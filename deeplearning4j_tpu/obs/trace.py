@@ -1,10 +1,22 @@
 """Span tracer — nested wall-clock spans exportable as Chrome-trace JSON
-(loadable in Perfetto / chrome://tracing).
+(loadable in Perfetto / chrome://tracing) — and the one seam through which
+the package's hot paths put host spans into the JAX profiler's own trace.
 
-Stdlib only. Spans use the monotonic ``time.perf_counter_ns`` clock (never
-``time.time`` — NTP steps would produce negative durations) and per-thread
-span stacks, so concurrent threads (AsyncIterator prefetch, server handler
-pools) each get a correctly nested track keyed by ``tid``.
+Stdlib only at import. Spans use the monotonic ``time.perf_counter_ns`` clock
+(never ``time.time`` — NTP steps would produce negative durations) and
+per-thread span stacks, so concurrent threads (AsyncIterator prefetch, server
+handler pools) each get a correctly nested track keyed by ``tid``.
+
+Hot-path spans: :func:`span` opens a ``jax.profiler.TraceAnnotation`` (a
+TraceMe) and nothing else. While a profiler session is live
+(``jax.profiler.start_trace`` / ``ProfilerListener``) the profiler writes the
+event into the same ``.xplane.pb``, on the ``/host:CPU`` plane, as the
+device's ``XLA Ops``; with no session it costs one flag test. The session is
+the only switch. :meth:`Tracer.span` opens the same annotation beside its own
+record, so ``StepTelemetry``'s spans land on the host plane of a traced run
+too. The names below are the table every site, test and trace reader uses
+(``benchmark/harness/host_spans.py`` keeps a copy: the benchmark imports
+nothing from the program).
 
 The trace format is the Chrome trace-event JSON flavor Perfetto ingests
 natively: complete events (``ph: "X"``) with microsecond ``ts``/``dur``,
@@ -14,16 +26,37 @@ instant events (``ph: "i"``), and thread-name metadata (``ph: "M"``). See
 
 from __future__ import annotations
 
+import gc
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
 
+# --- the span-name table (serve/continuous.py unless said) ----------------
+GEN_ADMIT = "gen.admit"                  # _run_loop top: lock, admission, plan
+GEN_PREFILL_CHUNK = "gen.prefill_chunk"  # one _prefill_step (dense: the prefill)
+GEN_FIRST_TOKEN = "gen.first_token"      # first-token sample and its readback
+GEN_TICK = "gen.tick"                    # one _tick; metadata active=<slots>
+GEN_TICK_PREPARE = "gen.tick.prepare"    # locked: CoW, ensure, tables, copies
+GEN_TICK_DISPATCH = "gen.tick.dispatch"  # CoW copies, uploads, decode enqueued
+GEN_TICK_READBACK = "gen.tick.readback"  # tokens and keys back on the host
+GEN_TICK_PUBLISH = "gen.tick.publish"    # metrics, bookkeeping, pushes, finishes
+GEN_TURN = "gen.turn"                    # the worker's turn after admission: the
+#   chunks and the tick nest in it; its own time is what lies between them
+#   (leases, the step's arrays freed, the interpreter lock lent to writers)
+HTTP_STREAM_WRITE = "http.stream_write"  # serve/http.py: one SSE event written
+GC_PAUSE = "gc.pause"                    # one collection; generation=0|1|2
+SPAN_NAMES = (GEN_ADMIT, GEN_PREFILL_CHUNK, GEN_FIRST_TOKEN, GEN_TICK,
+              GEN_TICK_PREPARE, GEN_TICK_DISPATCH, GEN_TICK_READBACK,
+              GEN_TICK_PUBLISH, GEN_TURN, HTTP_STREAM_WRITE, GC_PAUSE)
+
 
 class _NullSpan:
-    """Shared no-op context manager for a disabled tracer (stateless, so one
-    instance is safely reentrant across threads)."""
+    """Shared no-op context manager for a disabled tracer or a process
+    without JAX (stateless, so one instance is safely reentrant across
+    threads)."""
 
     __slots__ = ()
 
@@ -33,14 +66,39 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_metadata(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
+
+_ANNOTATION = None   # jax.profiler.TraceAnnotation, once JAX is in the process
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, looked up once. Never the reason JAX
+    gets imported: a process that has not loaded it (a router, a scraper)
+    has no profiler session either, and keeps getting None."""
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def span(name: str, **args):
+    """Context manager for a hot-path site: a profiler ``TraceAnnotation``
+    called ``name`` (``args`` become the event's stats). ``set_metadata``
+    on the entered span adds stats known only later."""
+    ann = _ANNOTATION or _annotation()
+    return _NULL_SPAN if ann is None else ann(name, **args)
 
 
 class _Span:
     """One live span; created by :meth:`Tracer.span`, records on ``__exit__``."""
 
-    __slots__ = ("tracer", "name", "args", "_t0", "_depth")
+    __slots__ = ("tracer", "name", "args", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.tracer = tracer
@@ -48,9 +106,11 @@ class _Span:
         self.args = args
         self._t0 = 0
         self._depth = 0
+        self._ann = span(name, **args)   # the same span, in the profiler's trace
 
     def __enter__(self):
         tr = self.tracer
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         stack = tr._stack()
         self._depth = len(stack)
@@ -61,6 +121,7 @@ class _Span:
 
     def __exit__(self, *exc):
         end = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
         tr = self.tracer
         stack = tr._stack()
         # Unwind to the depth recorded at __enter__: an exception thrown
@@ -183,3 +244,37 @@ class Tracer:
             self._events.clear()
             self._named_tids.clear()
             self.dropped = 0
+
+
+class GcPauses:
+    """One ``gc.callbacks`` hook: every collection is observed into
+    ``process_gc_pause_seconds{generation}`` and shown as a ``gc.pause``
+    span. A collection stops every Python thread (it runs under the GIL, on
+    whichever thread tripped the threshold) and collections never nest, so
+    one start time is enough. Two clock reads per collection; nothing per
+    tick. ``install``/``remove`` add and drop exactly this one entry."""
+
+    def __init__(self, metrics):
+        self._hist = [metrics.histogram(
+            "process_gc_pause_seconds", {"generation": str(g)},
+            help="stop-the-world time of one garbage collection, by the "
+                 "generation collected") for g in range(3)]
+        self._t0 = 0.0
+        self._span = _NULL_SPAN
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._span = span(GC_PAUSE, generation=info["generation"])
+            self._span.__enter__()
+            self._t0 = time.perf_counter()
+        else:
+            dt = time.perf_counter() - self._t0
+            self._span.__exit__(None, None, None)
+            self._hist[info["generation"]].observe(dt)
+
+    def install(self) -> None:
+        gc.callbacks.append(self)
+
+    def remove(self) -> None:
+        if self in gc.callbacks:
+            gc.callbacks.remove(self)

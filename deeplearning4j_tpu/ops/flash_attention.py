@@ -215,6 +215,7 @@ def _flash_fwd(q, k, v, lens, kmask, scale: float, causal: bool, bq: int,
         # (v5e has 128MB VMEM)
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=96 * 1024 * 1024),
         interpret=interpret,
+        name="flash_fwd",   # stable name in HLO and in a device trace
     )(q, k, v, lens3, km3)
     return o[:, :T], lse[:, :T, 0]
 
@@ -442,6 +443,7 @@ def _flash_bwd_pallas(q, k, v, lens, kmask, o, lse, do, scale, causal, bq, bk,
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=vmem,
         interpret=interpret,
+        name="flash_bwd_dq",   # stable name in HLO and in a device trace
     )(q, k, v, do, lse3, delta, lens3, km3)
 
     dk, dv = pl.pallas_call(
@@ -469,6 +471,7 @@ def _flash_bwd_pallas(q, k, v, lens, kmask, o, lse, do, scale, causal, bq, bk,
                         pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=vmem,
         interpret=interpret,
+        name="flash_bwd_dkv",   # stable name in HLO and in a device trace
     )(q, k, v, do, lse3, delta, lens3, km3)
     return dq[:, :T], dk[:, :T], dv[:, :T]
 

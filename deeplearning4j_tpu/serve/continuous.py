@@ -77,6 +77,7 @@ import numpy as np
 
 from ..chaos import faults as _faults
 from ..obs import profile as _prof
+from ..obs import trace as _trace
 from .engine import PrefillScheduler
 from .errors import (CapacityError, DeadlineExceededError, DrainTimeoutError,
                      ServeError, ServerClosingError, ShedError,
@@ -125,8 +126,9 @@ class _GenRequest:
     """One queued/in-flight generation."""
 
     __slots__ = ("prompt", "max_new", "temperature", "top_k", "eos_id",
-                 "deadline", "enq_t", "event", "result", "error", "out",
-                 "key", "slot", "ctx", "on_done", "cancelled", "_cv")
+                 "deadline", "enq_t", "disp_t", "first_t", "event", "result",
+                 "error", "out", "key", "slot", "ctx", "on_done", "cancelled",
+                 "_cv")
 
     def __init__(self, prompt: np.ndarray, max_new: int, temperature: float,
                  top_k: Optional[int], eos_id: Optional[int],
@@ -138,6 +140,10 @@ class _GenRequest:
         self.eos_id = eos_id
         self.deadline = deadline
         self.enq_t = time.perf_counter()
+        # perf_counter stamps beside enq_t: first prefill chunk dispatched,
+        # first token pushed (serve_gen_queue_seconds / _first_token_seconds)
+        self.disp_t: Optional[float] = None
+        self.first_t: Optional[float] = None
         self.event = threading.Event()
         self.result: Optional[np.ndarray] = None
         self.error: Optional[ServeError] = None
@@ -355,16 +361,17 @@ class ContinuousBatcher:
         def _sample_dynamic(logits, key, temperature, top_k):
             """Fully-traced sampler: temperature 0 -> greedy, top_k as a
             dynamic scalar (top_k == V disables the restriction)."""
-            greedy = jnp.argmax(logits, axis=-1)
-            t = jnp.maximum(temperature, 1e-6)
-            scaled = logits / t
-            srt = jnp.sort(scaled, axis=-1)  # ascending
-            k = jnp.clip(top_k, 1, V)
-            kth = jnp.take(srt, V - k, axis=-1)
-            masked = jnp.where(scaled >= kth, scaled, -1e30)
-            samp = jax.random.categorical(key, masked, axis=-1)
-            return jnp.where(temperature <= 0.0, greedy,
-                             samp).astype(jnp.int32)
+            with jax.named_scope("sample"):  # its name in a device trace
+                greedy = jnp.argmax(logits, axis=-1)
+                t = jnp.maximum(temperature, 1e-6)
+                scaled = logits / t
+                srt = jnp.sort(scaled, axis=-1)  # ascending
+                k = jnp.clip(top_k, 1, V)
+                kth = jnp.take(srt, V - k, axis=-1)
+                masked = jnp.where(scaled >= kth, scaled, -1e30)
+                samp = jax.random.categorical(key, masked, axis=-1)
+                return jnp.where(temperature <= 0.0, greedy,
+                                 samp).astype(jnp.int32)
 
         self._sample = jax.jit(_sample_dynamic)
 
@@ -539,8 +546,15 @@ class ContinuousBatcher:
                                        help="one all-slots decode tick")
         self._m_prefill_s = m.histogram("serve_gen_prefill_seconds",
                                         self._lbl(),
-                                        help="prompt prefill device time "
-                                             "(per chunk when chunked)")
+                                        help="host time to enqueue one prefill "
+                                             "(per chunk when chunked); "
+                                             "unfenced, not device time")
+        self._m_queue_s = m.histogram(
+            "serve_gen_queue_seconds", self._lbl(),
+            help="enqueue to first prefill chunk dispatched, per request")
+        self._m_first_s = m.histogram(
+            "serve_gen_first_token_seconds", self._lbl(),
+            help="enqueue to first token pushed, per request")
         self._m_occupancy = m.histogram(
             "serve_gen_slot_occupancy", self._lbl(),
             buckets=tuple((i + 1) / S for i in range(S)),
@@ -1074,50 +1088,69 @@ class ContinuousBatcher:
         """Advance one chunk of one prompt (paged mode)."""
         import jax.numpy as jnp
 
-        # chunk widths come from _plan_chunks, which only ever emits
-        # members of self._chunk_buckets (see _bucket_chunk)
-        off, true_len, bucket = job.chunks[job.idx]  # jaxlint: dim=bucket:bucket(_chunk_buckets)
-        with self._cond:
-            if self._slot_job[job.slot] is not job:
-                return  # aborted (forced shutdown) since this tick was planned
-            job.pages.ensure(off + true_len)
-            self._write_table_row(job.slot, job.pages.blocks)
-            table_row = self._tables_np[job.slot:job.slot + 1].copy()
-            self._update_kv_gauges()
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :true_len] = job.req.prompt[off:off + true_len]
-        if _prof.ACTIVE is not None:
-            # live prompt tokens vs the chunk bucket they padded to
-            _prof.ACTIVE.hint("generate", true_len, bucket)
-        t0 = time.perf_counter()
-        last, self._pools = self._prefill_paged(
-            snap.params, snap.state, jnp.asarray(ids), self._pools,
-            jnp.asarray(table_row), np.full((1,), off, np.int32),
-            np.int32(true_len))
-        t1 = time.perf_counter()
-        ctx = job.req.ctx
-        if ctx is None:
-            self._m_prefill_s.observe(t1 - t0)
-        else:
-            self._m_prefill_s.observe(t1 - t0, trace_id=ctx.trace_id)
-            if job.idx == 0:  # first chunk closes the queue-wait stage
-                # (its offset is nonzero when a cached prefix was adopted)
-                ctx.add_stage("queue", int(job.req.enq_t * 1e9),
-                              int(t0 * 1e9))
-            ctx.add_stage("prefill_chunk", int(t0 * 1e9), int(t1 * 1e9),
-                          offset=off, bucket=bucket)
-        self._m_pf_chunks.inc()
-        job.gens.add(snap.generation)
-        job.last = last
-        job.idx += 1
-        with self._cond:
-            sig = ("prefill", bucket)
-            if sig not in self._prefill_sigs:
-                self._prefill_sigs.add(sig)
-                if self._aot is None:  # with a store, AotFunction counts real traces
-                    self._m_compiles.inc()
+        with _trace.span(_trace.GEN_PREFILL_CHUNK):
+            # chunk widths come from _plan_chunks, which only ever emits
+            # members of self._chunk_buckets (see _bucket_chunk)
+            off, true_len, bucket = job.chunks[job.idx]  # jaxlint: dim=bucket:bucket(_chunk_buckets)
+            with self._cond:
+                if self._slot_job[job.slot] is not job:
+                    return  # aborted (forced shutdown) since the tick was planned
+                job.pages.ensure(off + true_len)
+                self._write_table_row(job.slot, job.pages.blocks)
+                table_row = self._tables_np[job.slot:job.slot + 1].copy()
+                self._update_kv_gauges()
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :true_len] = job.req.prompt[off:off + true_len]
+            if _prof.ACTIVE is not None:
+                # live prompt tokens vs the chunk bucket they padded to
+                _prof.ACTIVE.hint("generate", true_len, bucket)
+            t0 = time.perf_counter()
+            last, self._pools = self._prefill_paged(
+                snap.params, snap.state, jnp.asarray(ids), self._pools,
+                jnp.asarray(table_row), np.full((1,), off, np.int32),
+                np.int32(true_len))
+            t1 = time.perf_counter()
+            req = job.req
+            ctx = req.ctx
+            if job.idx == 0:  # first chunk closes the queue wait (its offset
+                # is nonzero when a cached prefix was adopted)
+                self._queue_wait_over(req, t0)
+            if ctx is None:
+                self._m_prefill_s.observe(t1 - t0)
+            else:
+                self._m_prefill_s.observe(t1 - t0, trace_id=ctx.trace_id)
+                ctx.add_stage("prefill_chunk", int(t0 * 1e9), int(t1 * 1e9),
+                              offset=off, bucket=bucket)
+            self._m_pf_chunks.inc()
+            job.gens.add(snap.generation)
+            job.last = last
+            job.idx += 1
+            with self._cond:
+                sig = ("prefill", bucket)
+                if sig not in self._prefill_sigs:
+                    self._prefill_sigs.add(sig)
+                    if self._aot is None:  # with a store, AotFunction counts real traces
+                        self._m_compiles.inc()
         if job.idx == len(job.chunks):
-            self._finish_prefill(job)
+            with _trace.span(_trace.GEN_FIRST_TOKEN):
+                self._finish_prefill(job)
+
+    def _queue_wait_over(self, req: _GenRequest, t0: float) -> None:
+        """Stamp the dispatch of a request's first prefill chunk: the one
+        source of ``serve_gen_queue_seconds`` and of reqtrace's ``queue``
+        stage."""
+        req.disp_t = t0
+        self._m_queue_s.observe(t0 - req.enq_t)
+        if req.ctx is not None:
+            req.ctx.add_stage("queue", int(req.enq_t * 1e9), int(t0 * 1e9))
+
+    def _push_first(self, req: _GenRequest, tok0: int) -> None:
+        """The prefill-sampled token: output like any other, and the end of
+        the server's own time to first token."""
+        req._push(tok0)
+        req.first_t = time.perf_counter()
+        self._m_first_s.observe(req.first_t - req.enq_t)
+        self._m_tokens.inc()
 
     def _finish_prefill(self, job: _PrefillJob) -> None:
         """Last chunk done: sample the first token, flip the slot from
@@ -1170,8 +1203,7 @@ class ContinuousBatcher:
             active = sum(1 for r in self._slot_req if r is not None)
             self._peak_active = max(self._peak_active, active)
             self._m_active.set(active)
-        req._push(tok0)
-        self._m_tokens.inc()  # the prefill-sampled token is output too
+        self._push_first(req, tok0)
         # a 1-token request (or instant EOS) finishes without ever decoding
         self._maybe_finish(s)
 
@@ -1187,24 +1219,26 @@ class ContinuousBatcher:
         if _prof.ACTIVE is not None:
             # live prompt tokens vs the prompt bucket they padded to
             _prof.ACTIVE.hint("generate", tp, bucket)
-        t0 = time.perf_counter()
-        last, cache = self._prefill(snap.params, snap.state,
-                                    jnp.asarray(ids), np.int32(tp))
-        t1 = time.perf_counter()
+        with _trace.span(_trace.GEN_PREFILL_CHUNK):
+            t0 = time.perf_counter()
+            last, cache = self._prefill(snap.params, snap.state,
+                                        jnp.asarray(ids), np.int32(tp))
+            t1 = time.perf_counter()
+        self._queue_wait_over(req, t0)
         if req.ctx is None:
             self._m_prefill_s.observe(t1 - t0)
         else:
             self._m_prefill_s.observe(t1 - t0, trace_id=req.ctx.trace_id)
-            req.ctx.add_stage("queue", int(req.enq_t * 1e9), int(t0 * 1e9))
             req.ctx.add_stage("prefill_chunk", int(t0 * 1e9), int(t1 * 1e9),
                               offset=0, bucket=bucket)
             req.ctx.decode_begin()
         self._admitted += 1
-        key = jax.random.fold_in(self._base_key, self._admitted)
-        key, sub = jax.random.split(key)
-        tok0 = int(np.asarray(self._sample(
-            last[0], sub, np.float32(req.temperature),
-            np.int32(req.top_k if req.top_k else self.vocab))))
+        with _trace.span(_trace.GEN_FIRST_TOKEN):
+            key = jax.random.fold_in(self._base_key, self._admitted)
+            key, sub = jax.random.split(key)
+            tok0 = int(np.asarray(self._sample(
+                last[0], sub, np.float32(req.temperature),
+                np.int32(req.top_k if req.top_k else self.vocab))))
         self._caches = self._slot_insert(self._caches, cache, np.int32(s))
         with self._cond:
             sig = ("prefill", bucket)
@@ -1224,8 +1258,7 @@ class ContinuousBatcher:
             active = sum(1 for r in self._slot_req if r is not None)
             self._peak_active = max(self._peak_active, active)
             self._m_active.set(active)
-        req._push(tok0)
-        self._m_tokens.inc()  # the prefill-sampled token is output too
+        self._push_first(req, tok0)
         # a 1-token request (or instant EOS) finishes without ever decoding
         self._maybe_finish(s)
 
@@ -1281,99 +1314,113 @@ class ContinuousBatcher:
         # decode step without ever corrupting donated buffers
         if _faults.ACTIVE is not None:
             _faults.ACTIVE.hit("serve.decode_step")
-        with self._cond:
-            if self._epoch != epoch:
-                return  # staled by a crash-only restart; the new worker owns the slots
-            active = [s for s in range(self.slots)
-                      if self._slot_req[s] is not None]
-            if not active:
-                return
-            if self.kv == "paged":
-                # grow lazily to cover the token this tick writes; the
-                # admission-time worst-case commitment guarantees success
-                cow: List[tuple] = []
+        with _trace.span(_trace.GEN_TICK) as tick:
+            with _trace.span(_trace.GEN_TICK_PREPARE):
+                with self._cond:
+                    if self._epoch != epoch:
+                        # staled by a crash-only restart; the new worker
+                        # owns the slots
+                        return
+                    active = [s for s in range(self.slots)
+                              if self._slot_req[s] is not None]
+                    if not active:
+                        return
+                    if self.kv == "paged":
+                        # grow lazily to cover the token this tick writes;
+                        # the admission-time worst-case commitment guarantees
+                        # success
+                        cow: List[tuple] = []
+                        for s in active:
+                            pages = self._slot_pages[s]
+                            pages.ensure(int(self._pos[s]) + 1)
+                            wb = int(self._pos[s]) // self.block_size
+                            blk = pages.blocks[wb]
+                            if self._alloc.refcount(blk) > 1:
+                                # copy-on-write: someone else (a fork peer)
+                                # still references the block this tick writes
+                                # — swap in a private copy first. Only ever
+                                # the partial tail: whole shared blocks are
+                                # never write targets.
+                                new = self._alloc.alloc(1)[0]
+                                if blk in pages.shared:
+                                    self._ledger_drop([blk])
+                                pages.swap(wb, new)
+                                cow.append((blk, new))
+                                self._cow_copies += 1
+                                self._m_cow.inc()
+                            self._write_table_row(s, pages.blocks)
+                        self._update_kv_gauges()
+                        mask = np.zeros(self.slots, bool)
+                        mask[active] = True
+                        # inactive rows: zero tables (writes -> trash),
+                        # position 0
+                        tables = np.where(mask[:, None], self._tables_np, 0)
+                        pos = np.where(mask, self._pos, 0).astype(np.int32)
+                    else:
+                        pos = np.array(self._pos)
+                    toks = np.array(self._next_tok)
+                    temps = np.array(self._temps)
+                    topks = np.array(self._topks)
+                    keys = np.array(self._keys)
+                    tick.set_metadata(active=len(active))
+            if _prof.ACTIVE is not None:
+                # live slots vs the fixed slot axis the decode step pads to
+                _prof.ACTIVE.hint("generate", len(active), self.slots)
+            # serve_gen_decode_seconds: dispatch + readback, by construction
+            t0 = time.perf_counter()
+            with _trace.span(_trace.GEN_TICK_DISPATCH):
+                if self.kv == "paged" and cow:
+                    # device-side CoW copies, outside the lock (pools are
+                    # only ever touched by this worker thread), before the
+                    # decode dispatch
+                    self._copy_blocks(cow)
+                if self.kv == "paged":
+                    nxt, self._pools, new_keys = self._decode(
+                        snap.params, snap.state, jnp.asarray(toks), self._pools,
+                        jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(keys),
+                        jnp.asarray(temps), jnp.asarray(topks))
+                else:
+                    nxt, caches, new_keys = self._decode(
+                        snap.params, snap.state, jnp.asarray(toks), self._caches,
+                        jnp.asarray(pos), jnp.asarray(keys), jnp.asarray(temps),
+                        jnp.asarray(topks))
+                    self._caches = caches
+            with _trace.span(_trace.GEN_TICK_READBACK):
+                nxt_np = np.asarray(nxt)
+                keys_np = np.asarray(new_keys, np.uint32)
+            t1 = time.perf_counter()
+            with _trace.span(_trace.GEN_TICK_PUBLISH):
+                self._m_decode_s.observe(t1 - t0)
+                self._m_occupancy.observe(len(active) / self.slots)
+                self._m_tokens.inc(len(active))
+                t0_ns = t1_ns = -1  # converted lazily: only for traced reqs
+                pushes = []
+                with self._cond:
+                    if self._epoch != epoch:
+                        # restart raced the device call: drop the bookkeeping
+                        return
+                    sig = ("decode", self.slots)
+                    if sig not in self._decode_sigs:
+                        self._decode_sigs.add(sig)
+                        if self._aot is None:  # with a store, AotFunction counts
+                            self._m_compiles.inc()
+                    for s in active:
+                        req = self._slot_req[s]
+                        if req is None:
+                            continue
+                        if req.ctx is not None:
+                            if t1_ns < 0:
+                                t0_ns, t1_ns = int(t0 * 1e9), int(t1 * 1e9)
+                            req.ctx.decode_tick(t0_ns, t1_ns)
+                        tok = int(nxt_np[s])
+                        self._next_tok[s] = tok
+                        self._pos[s] = self._pos[s] + 1
+                        self._keys[s] = keys_np[s]
+                        pushes.append((req, tok))
+                for req, tok in pushes:
+                    req._push(tok)
                 for s in active:
-                    pages = self._slot_pages[s]
-                    pages.ensure(int(self._pos[s]) + 1)
-                    wb = int(self._pos[s]) // self.block_size
-                    blk = pages.blocks[wb]
-                    if self._alloc.refcount(blk) > 1:
-                        # copy-on-write: someone else (a fork peer) still
-                        # references the block this tick writes — swap in a
-                        # private copy first. Only ever the partial tail:
-                        # whole shared blocks are never write targets.
-                        new = self._alloc.alloc(1)[0]
-                        if blk in pages.shared:
-                            self._ledger_drop([blk])
-                        pages.swap(wb, new)
-                        cow.append((blk, new))
-                        self._cow_copies += 1
-                        self._m_cow.inc()
-                    self._write_table_row(s, pages.blocks)
-                self._update_kv_gauges()
-                mask = np.zeros(self.slots, bool)
-                mask[active] = True
-                # inactive rows: zero tables (writes -> trash) + position 0
-                tables = np.where(mask[:, None], self._tables_np, 0)
-                pos = np.where(mask, self._pos, 0).astype(np.int32)
-            else:
-                pos = np.array(self._pos)
-            toks = np.array(self._next_tok)
-            temps = np.array(self._temps)
-            topks = np.array(self._topks)
-            keys = np.array(self._keys)
-        if _prof.ACTIVE is not None:
-            # live slots vs the fixed slot axis the decode step pads to
-            _prof.ACTIVE.hint("generate", len(active), self.slots)
-        t0 = time.perf_counter()
-        if self.kv == "paged" and cow:
-            # device-side CoW copies, outside the lock (pools are only ever
-            # touched by this worker thread), before the decode dispatch
-            self._copy_blocks(cow)
-        if self.kv == "paged":
-            nxt, self._pools, new_keys = self._decode(
-                snap.params, snap.state, jnp.asarray(toks), self._pools,
-                jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(keys),
-                jnp.asarray(temps), jnp.asarray(topks))
-        else:
-            nxt, caches, new_keys = self._decode(
-                snap.params, snap.state, jnp.asarray(toks), self._caches,
-                jnp.asarray(pos), jnp.asarray(keys), jnp.asarray(temps),
-                jnp.asarray(topks))
-            self._caches = caches
-        nxt_np = np.asarray(nxt)
-        keys_np = np.asarray(new_keys, np.uint32)
-        t1 = time.perf_counter()
-        self._m_decode_s.observe(t1 - t0)
-        self._m_occupancy.observe(len(active) / self.slots)
-        self._m_tokens.inc(len(active))
-        t0_ns = t1_ns = -1  # ns conversion done lazily: only for traced reqs
-        pushes = []
-        with self._cond:
-            if self._epoch != epoch:
-                return  # restart raced the device call; drop the bookkeeping
-            sig = ("decode", self.slots)
-            if sig not in self._decode_sigs:
-                self._decode_sigs.add(sig)
-                if self._aot is None:  # with a store, AotFunction counts real traces
-                    self._m_compiles.inc()
-            for s in active:
-                req = self._slot_req[s]
-                if req is None:
-                    continue
-                if req.ctx is not None:
-                    if t1_ns < 0:
-                        t0_ns, t1_ns = int(t0 * 1e9), int(t1 * 1e9)
-                    req.ctx.decode_tick(t0_ns, t1_ns)
-                tok = int(nxt_np[s])
-                self._next_tok[s] = tok
-                self._pos[s] = self._pos[s] + 1
-                self._keys[s] = keys_np[s]
-                pushes.append((req, tok))
-        for req, tok in pushes:
-            req._push(tok)
-        for s in active:
-            self._maybe_finish(s)
+                    self._maybe_finish(s)
 
     def _loop(self, epoch: int) -> None:
         try:
@@ -1397,76 +1444,93 @@ class ContinuousBatcher:
 
     def _run_loop(self, epoch: int) -> None:
         while True:
-            # registry generation, read OUTSIDE self._cond (the registry
-            # has its own lock): keys prefix-cache adoption, so a publish
-            # flushes stale runs at the next admission
-            gen = (self.registry.generation
-                   if self.kv == "paged" and self._prefix is not None else 0)
-            with self._cond:
-                if self._epoch != epoch:
-                    return  # staled by a crash-only restart
-                self._hb = time.monotonic()
-                has_active = any(r is not None for r in self._slot_req)
-                has_jobs = bool(self._jobs)
-                if self._closing and not self._queue and not has_active \
-                        and not has_jobs:
-                    return
-                if not self._queue and not has_active and not has_jobs:
-                    self._cond.wait(0.05)
-                    continue
-                admits = self._admit_locked(gen)
-                # dense admits are popped from the queue but not yet in a
-                # slot: track them so a restart can still answer them
-                self._admitting = [r for _, r in admits]
-                self._m_qdepth.set(len(self._queue))
-                jobs = list(self._jobs)
-                decoding = any(r is not None for r in self._slot_req)
-            now = time.perf_counter()
-            if self.kv == "paged":
-                for job in self.scheduler.plan(jobs, decoding):
-                    if job.req.cancelled is not None:
-                        # consumer vanished mid-prefill: abort here, where
-                        # no device call holds the job's table row
-                        self._abort_job(job, job.req.cancelled)
-                        continue
-                    if job.idx == 0 and job.req.deadline is not None \
-                            and now > job.req.deadline:
-                        self._abort_job(job, DeadlineExceededError(
-                            "deadline exceeded waiting for a decode slot"))
-                        continue
-                    try:
-                        # one lease per chunk: hot-swap drains at chunk
-                        # granularity, not whole-prompt granularity
-                        with self.registry.lease(tag="gen_prefill") as snap:
-                            self._prefill_step(job, snap)
-                    except ServeError as e:
-                        self._abort_job(job, e)
-                    except Exception as e:  # slot loop must outlive any bad request  # jaxlint: disable=broad-except
-                        self._abort_job(job,
-                                        ServeError(f"{type(e).__name__}: {e}"))
-                with self.registry.lease(tag="gen_decode") as snap:
-                    self._tick(snap, epoch)
-            else:
-                with self.registry.lease(tag="gen_decode") as snap:
-                    for s, req in admits:
-                        if req.event.is_set():
-                            continue  # already shed by a racing restart
-                        if req.cancelled is not None:
-                            req._finish(req.cancelled)
+            with _trace.span(_trace.GEN_ADMIT):
+                # registry generation, read OUTSIDE self._cond (the registry
+                # has its own lock): keys prefix-cache adoption, so a publish
+                # flushes stale runs at the next admission
+                gen = (self.registry.generation
+                       if self.kv == "paged" and self._prefix is not None
+                       else 0)
+                with self._cond:
+                    if self._epoch != epoch:
+                        return  # staled by a crash-only restart
+                    self._hb = time.monotonic()
+                    has_active = any(r is not None for r in self._slot_req)
+                    idle = not self._queue and not has_active \
+                        and not self._jobs
+                    if idle and self._closing:
+                        return
+                    if not idle:
+                        admits = self._admit_locked(gen)
+                        # dense admits are popped from the queue but not yet
+                        # in a slot: track them so a restart can still
+                        # answer them
+                        self._admitting = [r for _, r in admits]
+                        self._m_qdepth.set(len(self._queue))
+                        jobs = list(self._jobs)
+                        decoding = any(r is not None for r in self._slot_req)
+                if not idle:
+                    now = time.perf_counter()
+                    plan = (self.scheduler.plan(jobs, decoding)
+                            if self.kv == "paged" else [])
+            if idle:
+                # nothing to do: sleep outside the span (waiting for work is
+                # not admission), after a second look under the lock
+                with self._cond:
+                    if not self._queue and not self._closing \
+                            and self._epoch == epoch:
+                        self._cond.wait(0.05)
+                continue
+            # the chunks and the tick nest in gen.turn; its own time is what
+            # lies between them, up to the loop's back edge: the leases, the
+            # step's device arrays freed as _tick returns (which lends the
+            # interpreter lock to the stream writers the tick just woke)
+            with _trace.span(_trace.GEN_TURN):
+                if self.kv == "paged":
+                    for job in plan:
+                        if job.req.cancelled is not None:
+                            # consumer vanished mid-prefill: abort here, where
+                            # no device call holds the job's table row
+                            self._abort_job(job, job.req.cancelled)
                             continue
-                        if req.deadline is not None and now > req.deadline:
-                            req._finish(DeadlineExceededError(
+                        if job.idx == 0 and job.req.deadline is not None \
+                                and now > job.req.deadline:
+                            self._abort_job(job, DeadlineExceededError(
                                 "deadline exceeded waiting for a decode slot"))
                             continue
                         try:
-                            self._admit_into_slot(s, req, snap)
+                            # one lease per chunk: hot-swap drains at chunk
+                            # granularity, not whole-prompt granularity
+                            with self.registry.lease(tag="gen_prefill") as snap:
+                                self._prefill_step(job, snap)
                         except ServeError as e:
-                            req._finish(e)
+                            self._abort_job(job, e)
                         except Exception as e:  # slot loop must outlive any bad request  # jaxlint: disable=broad-except
-                            req._finish(ServeError(f"{type(e).__name__}: {e}"))
-                    with self._cond:
-                        self._admitting = []
-                    self._tick(snap, epoch)
+                            self._abort_job(job,
+                                            ServeError(f"{type(e).__name__}: {e}"))
+                    with self.registry.lease(tag="gen_decode") as snap:
+                        self._tick(snap, epoch)
+                else:
+                    with self.registry.lease(tag="gen_decode") as snap:
+                        for s, req in admits:
+                            if req.event.is_set():
+                                continue  # already shed by a racing restart
+                            if req.cancelled is not None:
+                                req._finish(req.cancelled)
+                                continue
+                            if req.deadline is not None and now > req.deadline:
+                                req._finish(DeadlineExceededError(
+                                    "deadline exceeded waiting for a decode slot"))
+                                continue
+                            try:
+                                self._admit_into_slot(s, req, snap)
+                            except ServeError as e:
+                                req._finish(e)
+                            except Exception as e:  # slot loop must outlive any bad request  # jaxlint: disable=broad-except
+                                req._finish(ServeError(f"{type(e).__name__}: {e}"))
+                        with self._cond:
+                            self._admitting = []
+                        self._tick(snap, epoch)
 
     # ------------------------------------------------- watchdog + crash-only
     def heartbeat(self) -> float:

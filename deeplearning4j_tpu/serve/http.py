@@ -43,6 +43,7 @@ from ..chaos import faults as _faults
 from ..obs import flight as _flight
 from ..obs import profile as _profile
 from ..obs import reqtrace as _rt
+from ..obs import trace as _trace
 from ..obs.metrics import MetricsRegistry
 from ..utils.httpd import JsonHTTPServerMixin, JsonRequestHandler
 from .continuous import ContinuousBatcher
@@ -179,6 +180,7 @@ class ModelServer(JsonHTTPServerMixin):
         self.aot_store = aot_store
         self.strict_aot = bool(strict_aot)
         self.metrics = metrics if metrics is not None else MetricsRegistry()
+        self._gc_pauses = _trace.GcPauses(self.metrics)  # on from start() to stop()
         if self.strict_aot and aot_store is None:
             raise ValueError("strict_aot=True requires an aot_store")
         if aot_manifest is not None:
@@ -480,9 +482,10 @@ class ModelServer(JsonHTTPServerMixin):
                 self.reply(200, body)
 
             def _sse(self, payload):
-                self.wfile.write(
-                    b"data: " + json.dumps(payload).encode() + b"\n\n")
-                self.wfile.flush()  # one event per decoded token
+                with _trace.span(_trace.HTTP_STREAM_WRITE):
+                    self.wfile.write(
+                        b"data: " + json.dumps(payload).encode() + b"\n\n")
+                    self.wfile.flush()  # one event per decoded token
 
             def _generate(self, req, query):
                 ctx = getattr(self, "_obs_ctx", None)
@@ -554,6 +557,12 @@ class ModelServer(JsonHTTPServerMixin):
         return Handler
 
     # --- lifecycle ---
+    def start(self, background: bool = True):
+        # collector pauses stop every stream at once: count them from the
+        # first request on (process_gc_pause_seconds, gc.pause spans)
+        self._gc_pauses.install()
+        return super().start(background)
+
     def stop(self, drain: bool = True):
         """Graceful by default: readiness flips first (load balancers stop
         routing), admitted work completes, then the listener closes."""
@@ -566,3 +575,4 @@ class ModelServer(JsonHTTPServerMixin):
         if batcher is not None:
             batcher.shutdown(drain=drain)
         super().stop()
+        self._gc_pauses.remove()

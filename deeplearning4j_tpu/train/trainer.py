@@ -386,8 +386,9 @@ class Trainer:
                 return loss, new_state
 
             (loss, new_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, new_state, loss
 
         return one_step
@@ -469,8 +470,9 @@ class Trainer:
             # clamp like losses._reduce: an all-masked batch yields 0, not NaN
             w_sum = jnp.maximum(w_sum, 1.0)
             g = jax.tree.map(lambda a: a / w_sum, g)
-            updates, opt_state = tx.update(g, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(g, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, net_state, loss_sum / w_sum
 
         return step
@@ -525,8 +527,9 @@ class Trainer:
                 return loss, (new_state, new_carries)
 
             (loss, (new_state, new_carries)), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, new_state, new_carries, loss
 
         return step
