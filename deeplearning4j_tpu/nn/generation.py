@@ -250,6 +250,21 @@ def init_caches(model: Sequential, batch: int, capacity: int, dtype):
 _init_caches = init_caches  # back-compat alias (pre-ISSUE-5 internal name)
 
 
+def decode_params(model: Sequential, params):
+    """``params`` as :func:`decode_forward` reads them: float leaves in the
+    model's ``compute_dtype``, on the device; the tree itself when the model
+    computes in its parameters' dtype. A caller whose parameters stay fixed
+    over many calls (a server between two publishes) casts ONCE with this
+    and passes the copy: ``decode_forward`` casts whatever it is given, and
+    on leaves that already have the compute dtype that traces to nothing, so
+    the compiled step then reads the weights at compute width and holds no
+    convert. Both ways every matmul sees the same operands."""
+    if not model.config.compute_dtype:
+        return params
+    return _cast_floats(jax.device_put(params),
+                        DTYPES[model.config.compute_dtype])
+
+
 def decode_forward(model: Sequential, params, state, x, caches, pos):
     """Run one decode chunk through the stack. ``x``: (B, Tq) int ids or
     (B, Tq, F) features at absolute offset ``pos`` — a scalar, or a (B,)

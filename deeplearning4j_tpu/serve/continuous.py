@@ -602,6 +602,29 @@ class ContinuousBatcher:
                 help="slots forked by block-table row copy")
             self._update_kv_gauges()
 
+        # --- the parameters the compiled programs read. A model with a
+        # compute_dtype gets, per params generation, ONE copy of the
+        # registry's tree already cast to it (nn.generation.decode_params):
+        # made by _warm_for at construction and in the registry's pre-flip
+        # warmer, never inside a tick, so decode and prefill stream the
+        # weights at compute width instead of re-casting them every step.
+        # The registry's own tree (checkpoints, hot-swap, rollback) is
+        # untouched; without a compute_dtype there is no copy ---
+        self._casts = bool(mdl.config.compute_dtype)
+        self._cast_lock = threading.Lock()
+        # (registry tree, its copy, bytes), oldest first: the generation
+        # being served, then candidates whose flip the worker has not seen
+        self._copies: List[tuple] = []
+        self._served: tuple = (None, None)  # (generation, tree to pass)
+        self._m_casts = m.counter(
+            "serve_params_cast_total", self._lbl(),
+            help="compute-dtype copies of the served parameters made "
+                 "(one per params generation, none inside a tick)")
+        self._m_cast_bytes = m.gauge(
+            "serve_params_compute_bytes", self._lbl(),
+            help="bytes held by compute-dtype copies of the served "
+                 "parameters (two generations coexist during a swap)")
+
         # --- persistent AOT store (optional): every generation executable
         # loads from disk before tracing, and is warmed eagerly so the
         # decode loop never traces in the request path after boot.
@@ -614,10 +637,10 @@ class ContinuousBatcher:
                              "a storeless batcher can only trace")
         self._aot = None
         self._aot_fns: Dict[str, Any] = {}
+        snap0 = self.registry.current()
         if aot_store is not None:
             from ..aot import AotFunction, arch_fingerprint
 
-            snap0 = self.registry.current()
             arch = arch_fingerprint(snap0.params, snap0.state)
 
             def _wrap(fn, tag, donate=()):
@@ -640,14 +663,16 @@ class ContinuousBatcher:
                                           "gen_slot_insert", (0,))
                 self._decode = _wrap(self._decode, "gen_decode_dense", (3,))
             self._aot = aot_store
-            t0 = time.perf_counter()
-            self._warm_for(snap0.params, snap0.state)
+        t0 = time.perf_counter()
+        self._warm_for(snap0.params, snap0.state)
+        if self._aot is not None:
             m.gauge("serve_cold_start_seconds",
                     self._lbl({"component": "generate"}),
                     help="wall time to materialize the serving executables"
                     ).set(time.perf_counter() - t0)
-            # precompile-before-flip: publish warms the candidate against
-            # the full decode/prefill/sample executable set
+        if self._aot is not None or self._casts:
+            # before the flip, publish casts the candidate and warms the
+            # full decode/prefill/sample executable set against the copy
             self.registry.add_warmer(self._warm_for)
 
         self._spawn_worker()
@@ -678,13 +703,18 @@ class ContinuousBatcher:
 
     # ---------------------------------------------------------------- warming
     def _warm_for(self, params, state) -> None:
-        """Load-or-compile the full static executable set for one params
-        generation — the lifetime decode step, every prefill bucket, and
-        the sampler — via abstract shapes (nothing executes, nothing is
-        donated). Runs at construction for the current generation and as a
-        registry warmer for each publish candidate."""
+        """Ready one params generation for the worker: make its
+        compute-dtype copy, then (with a store) load-or-compile the full
+        static executable set — the lifetime decode step, every prefill
+        bucket, and the sampler — against the COPY's dtypes, via abstract
+        shapes (nothing executes, nothing is donated). Runs at construction
+        for the current generation and as a registry warmer for each
+        publish candidate."""
         import jax
 
+        params = self._cast_params(params)
+        if self._aot is None:
+            return
         S, V = self.slots, self.vocab
         sds = jax.ShapeDtypeStruct
 
@@ -718,6 +748,66 @@ class ContinuousBatcher:
             for b in self.prompt_buckets:
                 self._prefill.warm(params, state, sds((1, b), i32),
                                    sds((), i32))
+
+    # ------------------------------------------------- compute-dtype params
+    def _make_copy(self, params) -> tuple:
+        import jax
+
+        from ..nn.generation import decode_params
+
+        copy = decode_params(self.model, params)
+        nbytes = sum(c.nbytes for c, a in zip(jax.tree.leaves(copy),
+                                              jax.tree.leaves(params))
+                     if c is not a)
+        self._m_casts.inc()
+        return params, copy, nbytes
+
+    def _cast_params(self, params):
+        """Make and keep the compute-dtype copy of one registry tree (the
+        tree itself when the model has no compute_dtype). Callers hold the
+        registry's publish lock (the warmer) or run before the worker
+        exists (construction)."""
+        if not self._casts:
+            return params
+        entry = self._make_copy(params)
+        cur = self.registry.current().params
+        with self._cast_lock:
+            # publishes are serialized, so a copy that is neither the one
+            # being served (first) nor the current snapshot's belongs to a
+            # publish that failed after this warmer ran
+            self._copies = [e for i, e in enumerate(self._copies)
+                            if i == 0 or e[0] is cur] + [entry]
+            self._m_cast_bytes.set(sum(e[2] for e in self._copies))
+        return entry[1]
+
+    def _params_for(self, snap):
+        """The tree to hand the compiled programs for ``snap``. Worker
+        thread only: its first call after a flip adopts the copy the
+        pre-flip warmer made and drops the copies before it — this thread
+        has returned every lease on them."""
+        if not self._casts:
+            return snap.params
+        gen, tree = self._served
+        if gen == snap.generation:
+            return tree
+        entry = None
+        while True:
+            with self._cast_lock:
+                mine = [i for i, e in enumerate(self._copies)
+                        if e[0] is snap.params]
+                if mine:
+                    del self._copies[:mine[-1]]
+                elif entry is not None:
+                    self._copies[:1] = [entry]
+                if mine or entry is not None:
+                    tree = self._copies[0][1]
+                    self._m_cast_bytes.set(sum(e[2] for e in self._copies))
+                    break
+            # flipped without this batcher's warmer (a publish that raced
+            # its construction): cast here, once, rather than serve f32
+            entry = self._make_copy(snap.params)
+        self._served = (snap.generation, tree)
+        return tree
 
     # ------------------------------------------------------------------ admit
     def _lbl(self, labels: Optional[dict] = None) -> dict:
@@ -1106,7 +1196,8 @@ class ContinuousBatcher:
                 _prof.ACTIVE.hint("generate", true_len, bucket)
             t0 = time.perf_counter()
             last, self._pools = self._prefill_paged(
-                snap.params, snap.state, jnp.asarray(ids), self._pools,
+                self._params_for(snap), snap.state, jnp.asarray(ids),
+                self._pools,
                 jnp.asarray(table_row), np.full((1,), off, np.int32),
                 np.int32(true_len))
             t1 = time.perf_counter()
@@ -1221,7 +1312,7 @@ class ContinuousBatcher:
             _prof.ACTIVE.hint("generate", tp, bucket)
         with _trace.span(_trace.GEN_PREFILL_CHUNK):
             t0 = time.perf_counter()
-            last, cache = self._prefill(snap.params, snap.state,
+            last, cache = self._prefill(self._params_for(snap), snap.state,
                                         jnp.asarray(ids), np.int32(tp))
             t1 = time.perf_counter()
         self._queue_wait_over(req, t0)
@@ -1369,6 +1460,7 @@ class ContinuousBatcher:
             # serve_gen_decode_seconds: dispatch + readback, by construction
             t0 = time.perf_counter()
             with _trace.span(_trace.GEN_TICK_DISPATCH):
+                params = self._params_for(snap)
                 if self.kv == "paged" and cow:
                     # device-side CoW copies, outside the lock (pools are
                     # only ever touched by this worker thread), before the
@@ -1376,12 +1468,12 @@ class ContinuousBatcher:
                     self._copy_blocks(cow)
                 if self.kv == "paged":
                     nxt, self._pools, new_keys = self._decode(
-                        snap.params, snap.state, jnp.asarray(toks), self._pools,
+                        params, snap.state, jnp.asarray(toks), self._pools,
                         jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(keys),
                         jnp.asarray(temps), jnp.asarray(topks))
                 else:
                     nxt, caches, new_keys = self._decode(
-                        snap.params, snap.state, jnp.asarray(toks), self._caches,
+                        params, snap.state, jnp.asarray(toks), self._caches,
                         jnp.asarray(pos), jnp.asarray(keys), jnp.asarray(temps),
                         jnp.asarray(topks))
                     self._caches = caches
@@ -1448,9 +1540,13 @@ class ContinuousBatcher:
                 # registry generation, read OUTSIDE self._cond (the registry
                 # has its own lock): keys prefix-cache adoption, so a publish
                 # flushes stale runs at the next admission
-                gen = (self.registry.generation
+                cur = self.registry.current()
+                gen = (cur.generation
                        if self.kv == "paged" and self._prefix is not None
                        else 0)
+                # no lease is held here: an idle server, too, lets go of
+                # the copy a publish retired
+                self._params_for(cur)
                 with self._cond:
                     if self._epoch != epoch:
                         return  # staled by a crash-only restart
@@ -1670,6 +1766,14 @@ class ContinuousBatcher:
             for req in finish:
                 req._finish(err)
         self._thread.join(timeout)
+        # no worker will read what a later publish would ready here, and
+        # the copies' device memory goes with it, not with the last
+        # reference to this object
+        self.registry.remove_warmer(self._warm_for)
+        with self._cast_lock:
+            self._copies = []
+            self._served = (None, None)
+            self._m_cast_bytes.set(0)
         if not self._thread.is_alive():
             return True
         with self._cond:

@@ -201,6 +201,13 @@ class ModelRegistry:
         with self._cond:
             self._warmers.append(fn)
 
+    def remove_warmer(self, fn: Callable[[Any, Any], None]) -> None:
+        """Unregister a hook (a tier that shuts down while the registry
+        lives on must not keep warming candidates nobody will serve)."""
+        with self._cond:
+            if fn in self._warmers:
+                self._warmers.remove(fn)
+
     def publish(self, params, state=None, version: Optional[str] = None,
                 drain: bool = False, timeout: Optional[float] = None
                 ) -> ModelSnapshot:
