@@ -121,6 +121,47 @@ class CausalLM(ZooModel):
         return b.build()
 
 
+@register_model
+class OlmoeLM(ZooModel):
+    """OLMoE (``allenai/OLMoE-1B-7B``): RoPE decoder blocks with q/k RMSNorm
+    and a dropless top-k sparse SwiGLU expert layer (``nn/layers/olmoe.py``),
+    a final RMSNorm and an untied, bias-free head. The defaults are the
+    published 1B-7B sizes; ``dtype`` is the dtype the parameters are HELD in
+    (``NetConfig.dtype``): ``"bfloat16"`` serves one bf16 tree, as the
+    publication ships it, with no second copy (no ``compute_dtype``)."""
+
+    input_shape = (4096,)
+
+    def __init__(self, num_classes=None, seed=12345, input_shape=None, *,
+                 num_layers=16, d_model=2048, num_heads=16, num_kv_heads=None,
+                 num_experts=64, top_k=8, expert_width=1024, vocab=50304,
+                 rms_eps=1e-5, rope_base=10000.0, dtype="float32", **kw):
+        super().__init__(num_classes, seed, input_shape, **kw)
+        self.num_layers, self.d_model, self.vocab = num_layers, d_model, vocab
+        self.num_classes = vocab
+        self.rms_eps = rms_eps
+        self.dtype = dtype
+        self.block = L.OlmoeBlock(
+            num_heads=num_heads, num_kv_heads=num_kv_heads,
+            num_experts=num_experts, top_k=top_k, expert_width=expert_width,
+            eps=rms_eps, rope_base=rope_base)
+
+    def build(self) -> Sequential:
+        init = "normal_0.02"   # initializer_range
+        b = (SequentialBuilder(NetConfig(
+                seed=self.seed, dtype=self.dtype,
+                updater={"type": "adamw", "learning_rate": 3e-4}))
+             .input_shape(self.input_shape[0])
+             .layer(L.EmbeddingSequence(n_in=self.vocab, n_out=self.d_model,
+                                        weight_init=init)))
+        for _ in range(self.num_layers):
+            b.layer(self.block)
+        b.layer(L.RMSNorm(eps=self.rms_eps))
+        b.layer(L.RnnOutput(n_out=self.vocab, activation="softmax",
+                            loss="mcxent", use_bias=False, weight_init=init))
+        return b.build()
+
+
 # ---------------------------------------------------------------------------
 # Fully-sharded training step: dp x tp x sp over one mesh.
 # ---------------------------------------------------------------------------

@@ -165,14 +165,29 @@ def _mha_decode(num_heads: int, params, x, cache, pos, *, rope=False,
     q = q.reshape(B, Tq, H, hd)
     k = k.reshape(B, Tq, Hkv, hd)
     v = v.reshape(B, Tq, Hkv, hd)
-    pv = _pos_vec(pos)
     if rope:
+        pv = _pos_vec(pos)
         if pv is None:
             abs_pos = pos + jnp.arange(Tq)
         else:
             abs_pos = pv[:, None] + jnp.arange(Tq)[None]  # (B, Tq)
         q = rope_rotate(q, abs_pos, rope_base)
         k = rope_rotate(k, abs_pos, rope_base)
+    y, cache = attend_cached(q, k, v, cache, pos, window=window)
+    y = y @ params["w_o"] + params["b_o"]
+    return y, cache
+
+
+def attend_cached(q, k, v, cache, pos, *, window=None):
+    """The layout-agnostic half of a cached attention: append the chunk's
+    ``k``/``v`` (B, Tq, Hkv, hd; already rotated) at ``pos``, read the cache
+    back, and attend ``q`` (B, Tq, H, hd) causally over slots 0..pos+t.
+    Returns ((B, Tq, H*hd), new_cache). A layer with projections of its own
+    (``layers/olmoe.py``) calls this from its ``decode``."""
+    B, Tq, H, hd = q.shape
+    Hkv = k.shape[2]
+    D = H * hd
+    pv = _pos_vec(pos)
     cache = cache_append(cache, k, v, pos)
     ck, cv = cache_read(cache)
     C = ck.shape[1]
@@ -210,7 +225,6 @@ def _mha_decode(num_heads: int, params, x, cache, pos, *, rope=False,
         scores = jnp.where(vmask, scores, -1e30)
         w = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
         y = jnp.einsum("bhqk,bkhd->bqhd", w, cv).reshape(B, Tq, D)
-    y = y @ params["w_o"] + params["b_o"]
     return y, cache
 
 
@@ -222,12 +236,30 @@ def cache_spec(model: Sequential):
     opaque layer-owned state with no append/read contract."""
     spec = []
     for i, layer in enumerate(model.layers):
-        if isinstance(layer, (TransformerEncoderBlock, MultiHeadAttention)):
-            d = model._shapes[i][-1]
-            hd = d // layer.num_heads
-            hkv = layer.num_kv_heads or layer.num_heads  # GQA: smaller cache
-            spec.append((_layer_key(i, layer), hkv, hd))
+        kv = _kv_shape(layer, model._shapes[i])
+        if kv is not None:
+            spec.append((_layer_key(i, layer),) + kv)
     return spec
+
+
+def says_how_it_decodes(layer) -> bool:
+    """The one hook a stateful layer is reached through (ROADMAP D1): a
+    layer that has ``decode(params, x, cache, pos) -> (y, cache)`` and
+    ``cache_spec(input_shape) -> (kv_heads, head_dim)`` is asked before any
+    ``isinstance`` ladder here or in the batcher is walked. What is left on
+    the ladders is the dense block and the bare attention layer."""
+    return hasattr(layer, "decode") and hasattr(layer, "cache_spec")
+
+
+def _kv_shape(layer, input_shape):
+    """(kv_heads, head_dim) of the KV cache ``layer`` decodes against, or
+    None for a layer that keeps none."""
+    if says_how_it_decodes(layer):
+        return tuple(layer.cache_spec(input_shape))
+    if isinstance(layer, (TransformerEncoderBlock, MultiHeadAttention)):
+        hd = input_shape[-1] // layer.num_heads
+        return (layer.num_kv_heads or layer.num_heads), hd  # GQA: smaller
+    return None
 
 
 def init_caches(model: Sequential, batch: int, capacity: int, dtype):
@@ -236,11 +268,9 @@ def init_caches(model: Sequential, batch: int, capacity: int, dtype):
     caches: Dict[str, Any] = {}
     for i, layer in enumerate(model.layers):
         k = _layer_key(i, layer)
-        if isinstance(layer, (TransformerEncoderBlock, MultiHeadAttention)):
-            d = model._shapes[i][-1]
-            hd = d // layer.num_heads
-            hkv = layer.num_kv_heads or layer.num_heads  # GQA: smaller cache
-            z = jnp.zeros((batch, capacity, hkv, hd), dtype)
+        kv = _kv_shape(layer, model._shapes[i])
+        if kv is not None:
+            z = jnp.zeros((batch, capacity) + kv, dtype)
             caches[k] = {"k": z, "v": z}
         elif isinstance(layer, RecurrentLayer):
             caches[k] = layer.init_carry(batch, model._shapes[i], dtype)
@@ -286,7 +316,9 @@ def decode_forward(model: Sequential, params, state, x, caches, pos):
             # them with every operation (obs/README.md, "Hot-path spans")
             with jax.named_scope("weight_cast"):
                 p = _cast_floats(p, cdt)
-        if isinstance(layer, TransformerEncoderBlock):
+        if says_how_it_decodes(layer):
+            x, new[k] = layer.decode(p, x, new[k], pos)
+        elif isinstance(layer, TransformerEncoderBlock):
             with jax.named_scope("attention"):
                 h = layer._ln(x, p["ln1_g"], p["ln1_b"])
                 a, new[k] = _mha_decode(layer.num_heads, p["attn"], h, new[k],
@@ -324,7 +356,9 @@ def decode_forward(model: Sequential, params, state, x, caches, pos):
         else:  # token-local layers: embedding, norms, dense, dropout(eval)...
             x, _, mask = layer.apply(p, state.get(k, {}), x,
                                      training=False, mask=mask)
-    if cdt is not None:
+    if cdt is not None or x.dtype == jnp.bfloat16:
+        # logits leave in f32 whether the width came from a compute_dtype or
+        # from parameters held in bf16: samplers have one signature
         x = x.astype(jnp.float32)
     return x, new
 
@@ -370,6 +404,8 @@ def generate(model: Sequential, prompt, max_new_tokens: int, *,
     if capacity < total:
         raise ValueError(f"capacity {capacity} < prompt+new tokens {total}")
     for i, layer in enumerate(model.layers):
+        if says_how_it_decodes(layer):
+            continue
         if isinstance(layer, PositionalEmbedding):
             if layer.max_len < total:
                 raise ValueError(
@@ -390,7 +426,9 @@ def generate(model: Sequential, prompt, max_new_tokens: int, *,
                 f"generate() does not support layer {i} "
                 f"{type(layer).__name__}: it is not token-local along the "
                 f"sequence (decoding it one token at a time would disagree "
-                f"with the full forward pass)")
+                f"with the full forward pass) and does not say how it "
+                f"decodes (no decode(params, x, cache, pos) and "
+                f"cache_spec(input_shape): see says_how_it_decodes)")
     out_layer = model.layers[-1]
     V = getattr(out_layer, "n_out", 0) or model._shapes[-1][-1]
     # rng convention: pass an explicit key for streamed/nested sampling; with

@@ -17,6 +17,7 @@ from .core import (ActivationLayer, AlphaDropout, CenterLossOutput,
 from .custom import CustomLayer, Lambda, resolve_function
 from .moe import MoE, MoETransformerBlock
 from .norm import LRN, BatchNorm, LayerNorm, RMSNorm
+from .olmoe import OlmoeBlock
 from .pooling import Flatten, GlobalPooling, Reshape
 from .recurrent import (GRU, LSTM, Bidirectional, GravesLSTM, LastTimeStep,
                         RecurrentLayer, SimpleRnn)
@@ -33,7 +34,7 @@ __all__ = [
     "Frozen", "GRU", "GlobalPooling", "GravesLSTM", "LRN", "LSTM", "Lambda",
     "LastTimeStep",
     "LayerNorm", "LossLayer", "MoE", "MoETransformerBlock",
-    "MultiHeadAttention", "Output", "PReLU",
+    "MultiHeadAttention", "OlmoeBlock", "Output", "PReLU",
     "PositionalEmbedding", "RMSNorm", "RecurrentLayer", "Reshape", "RnnLossLayer", "RnnOutput",
     "SeparableConv2D", "SimpleRnn", "SpaceToBatch", "SpaceToDepth",
     "Subsampling1D", "Subsampling2D", "TransformerEncoderBlock", "Upsampling1D",

@@ -129,6 +129,17 @@ class LayerNorm(Layer):
         return y, state, mask
 
 
+def rms_norm(x, gamma, eps: float):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis. The mean
+    square and the scaling run in f32 for anything narrower (a bf16 mean over
+    2048 columns keeps three digits), and the result is rounded to
+    ``x.dtype`` once. On f32 input this is the plain formula."""
+    wide = jnp.promote_types(x.dtype, jnp.float32)
+    xf = x.astype(wide)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * lax.rsqrt(ms + eps) * gamma.astype(wide)).astype(x.dtype)
+
+
 @register_layer
 @dataclass(frozen=True)
 class RMSNorm(Layer):
@@ -140,5 +151,4 @@ class RMSNorm(Layer):
         return {"gamma": jnp.ones((input_shape[-1],), dtype)}, {}
 
     def apply(self, params, state, x, *, training=False, rng=None, mask=None):
-        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        return x * lax.rsqrt(ms + self.eps) * params["gamma"], state, mask
+        return rms_norm(x, params["gamma"], self.eps), state, mask

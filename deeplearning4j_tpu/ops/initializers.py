@@ -72,6 +72,12 @@ def normal(key, shape, fan_in, fan_out, dtype=jnp.float32):
     return jax.random.normal(key, shape, dtype) / jnp.sqrt(jnp.asarray(fan_in, dtype))
 
 
+@register("normal_0.02")
+def normal_002(key, shape, fan_in, fan_out, dtype=jnp.float32):
+    # N(0, 0.02) whatever the fans: the GPT-2 / OLMo ``initializer_range``
+    return 0.02 * jax.random.normal(key, shape, dtype)
+
+
 @register("uniform")
 def uniform(key, shape, fan_in, fan_out, dtype=jnp.float32):
     # DL4J UNIFORM: U(-a, a), a = sqrt(3/fan_in)

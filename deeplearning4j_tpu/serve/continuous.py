@@ -292,11 +292,13 @@ class ContinuousBatcher:
         import jax.numpy as jnp
         from jax import lax
 
-        from ..nn.generation import cache_spec, decode_forward, init_caches
+        from ..nn.generation import (_TOKEN_LOCAL, cache_spec, decode_forward,
+                                     init_caches, says_how_it_decodes)
         from ..nn.layers import (Embedding, EmbeddingSequence,
                                  MultiHeadAttention, Output,
                                  PositionalEmbedding, TransformerEncoderBlock)
         from ..nn.layers.recurrent import RecurrentLayer
+        from ..nn.model import _layer_key
         from ..obs.metrics import MetricsRegistry
         from .paged import build_pools
 
@@ -324,19 +326,33 @@ class ContinuousBatcher:
                              or _default_prompt_buckets(self.capacity))
             if b <= self.capacity))) or (self.capacity,)
 
-        # --- model contract: embedding-front, causal, no recurrence ---
+        # --- model contract: embedding-front, causal, and every stateful
+        # layer says how it decodes (serve/README.md) ---
         first = model.layers[0]
         if not isinstance(first, (Embedding, EmbeddingSequence)):
             raise ValueError(
                 "continuous batching requires an embedding-front token model "
                 "(CausalLM family); one-hot char models stay on "
                 "nn.generation.generate")
+        known = _TOKEN_LOCAL + (TransformerEncoderBlock, MultiHeadAttention,
+                                PositionalEmbedding, Output)
         for i, layer in enumerate(model.layers):
+            if says_how_it_decodes(layer):
+                continue   # the layer's own decode() and cache_spec()
             if isinstance(layer, RecurrentLayer):
                 raise ValueError(
                     f"layer {i} {type(layer).__name__}: recurrent carries "
                     f"cannot survive a right-padded prefill — use whole-batch "
                     f"nn.generation.generate for RNN models")
+            if not isinstance(layer, known):
+                raise ValueError(
+                    f"layer {i} {type(layer).__name__} does not say how it "
+                    f"decodes: it is not token-local, and has no "
+                    f"decode(params, x, cache, pos) -> (y, cache) with "
+                    f"cache_spec(input_shape) -> (kv_heads, head_dim) "
+                    f"(nn.generation.says_how_it_decodes). Decoding it one "
+                    f"token at a time without a cache would disagree with "
+                    f"its full forward pass")
             if isinstance(layer, (TransformerEncoderBlock, MultiHeadAttention)) \
                     and not layer.causal:
                 raise ValueError(
@@ -417,11 +433,26 @@ class ContinuousBatcher:
             self._committed = 0
             self._block_bytes = block_bytes(mdl, self.block_size, mdl.dtype)
             lks = self._lks
+            # layers with experts (layers/olmoe.py) report what routing did
+            # to the rows marked live; the sums leave each program as three
+            # int32 (nn.layers.olmoe.ROUTING_FIELDS). A model without such
+            # layers builds the programs it always built
+            routed = [_layer_key(i, layer)
+                      for i, layer in enumerate(mdl.layers)
+                      if says_how_it_decodes(layer)
+                      and getattr(layer, "num_experts", 0)]
+            self._routed = len(routed)
 
-            def _as_caches(pools, tables):
-                return {lk: {"k_pool": pools[lk]["k"],
-                             "v_pool": pools[lk]["v"],
-                             "tables": tables} for lk in lks}
+            def _as_caches(pools, tables, live=None):
+                caches = {lk: {"k_pool": pools[lk]["k"],
+                               "v_pool": pools[lk]["v"],
+                               "tables": tables} for lk in lks}
+                for lk in routed:
+                    caches[lk]["live"] = live
+                return caches
+
+            def _routing(caches):
+                return sum(caches[lk]["routing"] for lk in routed)
 
             def _as_pools(caches):
                 return {lk: {"k": caches[lk]["k_pool"],
@@ -433,10 +464,14 @@ class ContinuousBatcher:
                 right-padded; ``pos`` (1,) chunk offset; pad garbage writes
                 past the row's blocks land in the trash block. Logits are
                 gathered at the last REAL token of the chunk."""
+                live = (jnp.arange(ids.shape[1]) < true_len)[None] \
+                    if routed else None
                 lg, caches = decode_forward(
                     mdl, params, state, ids,
-                    _as_caches(pools, table_row), pos)
+                    _as_caches(pools, table_row, live), pos)
                 last = jnp.take(lg, true_len - 1, axis=1)  # (1, V)
+                if routed:
+                    return last, _as_pools(caches), _routing(caches)
                 return last, _as_pools(caches)
 
             def _decode_paged_fn(params, state, toks, pools, tables, pos,
@@ -446,15 +481,20 @@ class ContinuousBatcher:
                 lifetime (tables/pos are traced operands). Inactive slots
                 carry zeroed table rows, so their writes land in the trash
                 block and their sampled garbage is discarded host-side."""
+                # a live slot's first block is never the trash block
+                live = (tables[:, :1] != 0) if routed else None
                 lg, caches = decode_forward(
                     mdl, params, state, toks[:, None].astype(jnp.int32),
-                    _as_caches(pools, tables), pos)
+                    _as_caches(pools, tables, live), pos)
 
                 def one(l, key, temp, tk):
                     key, sub = jax.random.split(key)
                     return _sample_dynamic(l, sub, temp, tk), key
 
                 nxt, new_keys = jax.vmap(one)(lg[:, 0], keys, temps, tks)
+                if routed:
+                    # the three sums ride the tokens' readback: (S + 3,)
+                    nxt = jnp.concatenate([nxt, _routing(caches)])
                 return nxt, _as_pools(caches), new_keys
 
             # pools are the loop-carried buffers: donated every step
@@ -467,6 +507,7 @@ class ContinuousBatcher:
             self.prefill_chunk = None
             self._committed = 0
             self._prefix = None
+            self._routed = 0   # routing is counted on the paged path only
 
             def _prefill(params, state, ids, true_len):
                 """ids (1, Tb) right-padded prompt; logits are gathered at
@@ -601,6 +642,22 @@ class ContinuousBatcher:
                 "serve_gen_forks_total", self._lbl(),
                 help="slots forked by block-table row copy")
             self._update_kv_gauges()
+        # what routing did, per program kind; nothing for a model without
+        # experts. A chunk's sums stay on the device until the next readback
+        # of something computed behind them (_count_chunks_routing)
+        self._routing_pending: List[Any] = []
+        if self._routed:
+            from ..nn.layers.olmoe import ROUTING_FIELDS
+
+            self._m_routing = {
+                prog: [m.counter(f"serve_moe_{f}_total",
+                                 self._lbl({"program": prog}), help=what)
+                       for f, what in ROUTING_FIELDS.items()]
+                + [m.counter("serve_moe_layer_programs_total",
+                             self._lbl({"program": prog}),
+                             help="expert layers run: layers x decode steps "
+                                  "and prefill chunks")]
+                for prog in ("decode", "prefill")}
 
         # --- the parameters the compiled programs read. A model with a
         # compute_dtype gets, per params generation, ONE copy of the
@@ -1195,11 +1252,12 @@ class ContinuousBatcher:
                 # live prompt tokens vs the chunk bucket they padded to
                 _prof.ACTIVE.hint("generate", true_len, bucket)
             t0 = time.perf_counter()
-            last, self._pools = self._prefill_paged(
+            last, self._pools, *routing = self._prefill_paged(
                 self._params_for(snap), snap.state, jnp.asarray(ids),
                 self._pools,
                 jnp.asarray(table_row), np.full((1,), off, np.int32),
                 np.int32(true_len))
+            self._routing_pending += routing
             t1 = time.perf_counter()
             req = job.req
             ctx = req.ctx
@@ -1275,6 +1333,7 @@ class ContinuousBatcher:
         tok0 = int(_np.asarray(self._sample(
             job.last[0], sub, np.float32(req.temperature),
             np.int32(req.top_k if req.top_k else self.vocab))))
+        self._count_chunks_routing()
         with self._cond:
             if job in self._jobs:
                 self._jobs.remove(job)
@@ -1482,6 +1541,9 @@ class ContinuousBatcher:
                 keys_np = np.asarray(new_keys, np.uint32)
             t1 = time.perf_counter()
             with _trace.span(_trace.GEN_TICK_PUBLISH):
+                if self._routed:
+                    self._count_routing("decode", nxt_np[self.slots:])
+                    self._count_chunks_routing()
                 self._m_decode_s.observe(t1 - t0)
                 self._m_occupancy.observe(len(active) / self.slots)
                 self._m_tokens.inc(len(active))
@@ -1513,6 +1575,21 @@ class ContinuousBatcher:
                     req._push(tok)
                 for s in active:
                     self._maybe_finish(s)
+
+    def _count_routing(self, program: str, sums) -> None:
+        """One program's routing sums (ROUTING_FIELDS) into the counters."""
+        *fields, programs = self._m_routing[program]
+        for counter, v in zip(fields, sums):
+            counter.inc(int(v))
+        programs.inc(self._routed)
+
+    def _count_chunks_routing(self) -> None:
+        """The sums of the prefill chunks run since the last call. Called
+        only behind a readback of something the device computed after them
+        (a tick's tokens, a first token), so reading them waits for nothing."""
+        for sums in self._routing_pending:
+            self._count_routing("prefill", np.asarray(sums))
+        self._routing_pending = []
 
     def _loop(self, epoch: int) -> None:
         try:
