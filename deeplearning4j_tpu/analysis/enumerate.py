@@ -37,23 +37,16 @@ from .compilesurface import _parse_bound
 MANIFEST_VERSION = 1
 
 #: site id -> (AotFunction tag, gate). The gate names which boot paths
-#: build the executable: ``engine`` (always), ``gen`` (any batcher),
-#: ``paged`` / ``dense`` (only that KV mode's batcher).
+#: build the executable: ``engine`` (always), ``gen`` (a batcher).
 SITE_TAGS: Dict[str, Tuple[str, str]] = {
     "deeplearning4j_tpu.serve.engine:fwd":
         ("engine_forward", "engine"),
-    "deeplearning4j_tpu.serve.continuous:_sample_dynamic":
+    "deeplearning4j_tpu.serve.programs:_sample_dynamic":
         ("gen_sample", "gen"),
-    "deeplearning4j_tpu.serve.continuous:_decode_paged_fn":
-        ("gen_decode_paged", "paged"),
-    "deeplearning4j_tpu.serve.continuous:_prefill_chunk_fn":
-        ("gen_prefill_chunk", "paged"),
-    "deeplearning4j_tpu.serve.continuous:_decode_step":
-        ("gen_decode_dense", "dense"),
-    "deeplearning4j_tpu.serve.continuous:_prefill":
-        ("gen_prefill_dense", "dense"),
-    "deeplearning4j_tpu.serve.continuous:_slot_insert":
-        ("gen_slot_insert", "dense"),
+    "deeplearning4j_tpu.serve.programs:_decode_paged_fn":
+        ("gen_decode_paged", "gen"),
+    "deeplearning4j_tpu.serve.programs:_prefill_chunk_fn":
+        ("gen_prefill_chunk", "gen"),
 }
 
 _DEFAULT_BATCH_BUCKETS = (1, 2, 4, 8, 16, 32)
@@ -107,30 +100,11 @@ def resolve_tables(config: dict) -> Dict[str, list]:
     # the constructor's normalization: ints, deduped, capped at capacity
     prompt = tuple(sorted(set(
         int(b) for b in prompt if int(b) <= capacity))) or (capacity,)
-    kv = str(gen.get("kv") or "paged")
     prefill_chunk = gen.get("prefill_chunk", _DEFAULT_PREFILL_CHUNK)
-    if kv == "paged":
-        chunks = chunk_buckets(
-            prompt, int(prefill_chunk) if prefill_chunk is not None
-            else None)
-    else:
-        chunks = prompt
+    chunks = chunk_buckets(
+        prompt, int(prefill_chunk) if prefill_chunk is not None else None)
     return {"batch_buckets": batch, "length_buckets": length,
             "prompt_buckets": list(prompt), "_chunk_buckets": list(chunks)}
-
-
-def _gate_open(gate: str, kv: str, predict_only: bool) -> Optional[str]:
-    """None when this boot builds the executable, else the skip reason."""
-    if gate == "engine":
-        return None
-    if predict_only:
-        return "predict-only config: no generation stack is built"
-    if gate == "gen":
-        return None
-    if gate != kv:
-        return (f"kv={kv!r} boot never builds this executable "
-                f"({gate}-path only)")
-    return None
 
 
 def enumerate_surface(report: dict, budget: dict, config: dict) -> dict:
@@ -140,15 +114,13 @@ def enumerate_surface(report: dict, budget: dict, config: dict) -> dict:
     Every budgeted site is either *enumerated* — its symbolic factors
     resolved against the config's bucket tables, signatures = the cross
     product — or *excluded* with a machine-checkable reason (statically
-    unknown bound, no call sites, not a serving executable, wrong KV
-    mode). A serving-tagged site whose bound carries a factor the tables
+    unknown bound, no call sites, not a serving executable, no generation
+    stack). A serving-tagged site whose bound carries a factor the tables
     cannot resolve raises ``ValueError``: an unresolvable factor means the
     manifest would under-cover the surface, which is exactly the silent
     hole strict mode exists to forbid.
     """
     tables = resolve_tables(config)
-    gen = dict(config.get("gen") or {})
-    kv = str(gen.get("kv") or "paged")
     predict_only = bool(config.get("predict_only"))
     budgeted = budget.get("sites", {})
     sites_out: List[dict] = []
@@ -168,8 +140,8 @@ def enumerate_surface(report: dict, budget: dict, config: dict) -> dict:
             unb, unk, factors, _numeric = _parse_bound(bound)
             if unb or unk:
                 reason = f"bound {bound!r} is not statically enumerable"
-            else:
-                reason = _gate_open(gate, kv, predict_only)
+            elif gate == "gen" and predict_only:
+                reason = "predict-only config: no generation stack is built"
         if reason is not None:
             excluded.append({"site": site, "bound": bound,
                              "reason": reason})

@@ -145,8 +145,8 @@ class BlockingUnderLockRule(Rule):
 #: are resolved nominally, so look-alike ``ensure``/``alloc`` methods on
 #: unrelated classes never match.
 _ACQ_PROTOCOLS: Dict[Tuple[str, str], Tuple[str, ...]] = {
-    ("BlockAllocator", "alloc"): ("free", "release"),
-    ("SlotPages", "ensure"): ("release", "free"),
+    ("BlockAllocator", "alloc"): ("release",),
+    ("SlotPages", "ensure"): ("release",),
 }
 
 #: (class-name suffix, method) whose boolean/token result must be used —
@@ -168,7 +168,7 @@ class AcquireReleaseRule(Rule):
     typed receivers:
 
     1. an allocation (``BlockAllocator.alloc``, ``SlotPages.ensure``)
-       bound to a local must be released (``free``/``release``) or have
+       bound to a local must be released (``release``) or have
        its ownership transferred (returned, stored, passed on) — on the
        normal path, on early returns, and when a call between acquire
        and release can raise (release must sit in a ``finally`` or an

@@ -291,7 +291,7 @@ class FleetEntry:
                 "generate_ready": self._batcher is not None,
             }
             batcher = self._batcher
-        if batcher is not None and batcher.kv == "paged":
+        if batcher is not None:
             # sharing picture per tenant-facing model: block usage,
             # prefix-cache hit rates, CoW/fork counts (router placement
             # and dashboards read this off the heartbeat)

@@ -277,9 +277,6 @@ def init_caches(model: Sequential, batch: int, capacity: int, dtype):
     return caches
 
 
-_init_caches = init_caches  # back-compat alias (pre-ISSUE-5 internal name)
-
-
 def decode_params(model: Sequential, params):
     """``params`` as :func:`decode_forward` reads them: float leaves in the
     model's ``compute_dtype``, on the device; the tree itself when the model
@@ -363,7 +360,59 @@ def decode_forward(model: Sequential, params, state, x, caches, pos):
     return x, new
 
 
-_decode_forward = decode_forward  # back-compat alias (pre-ISSUE-5 name)
+def check_decodes(model: Sequential, context: int, what: str, *,
+                  served: bool = False) -> int:
+    """The model contract of decoding one chunk at a time against a cache,
+    for ``context`` positions (``what`` names them in the error). Every
+    layer is token-local, recurrent, causal attention, a positional table
+    at least ``context`` long, says how it decodes itself
+    (:func:`says_how_it_decodes`), or the final Output. ``served`` adds the
+    continuous batcher's terms, which right-pads token prompts into slots:
+    an embedding front, no recurrent carry, an Output last. Returns the
+    vocabulary size."""
+    if served and not isinstance(model.layers[0],
+                                 (Embedding, EmbeddingSequence)):
+        raise ValueError(
+            "continuous batching requires an embedding-front token model "
+            "(CausalLM family); one-hot char models stay on "
+            "nn.generation.generate")
+    last = len(model.layers) - 1
+    for i, layer in enumerate(model.layers):
+        if says_how_it_decodes(layer):
+            continue   # the layer's own decode() and cache_spec()
+        if isinstance(layer, RecurrentLayer):
+            if served:
+                raise ValueError(
+                    f"layer {i} {type(layer).__name__}: recurrent carries "
+                    f"cannot survive a right-padded prefill — use whole-batch "
+                    f"nn.generation.generate for RNN models")
+        elif isinstance(layer, PositionalEmbedding):
+            # a learned positional TABLE bounds context; rope models have no
+            # such layer, so paged capacity is free to exceed training length
+            if layer.max_len < context:
+                raise ValueError(
+                    f"PositionalEmbedding(max_len={layer.max_len}) is shorter "
+                    f"than {what} {context}")
+        elif isinstance(layer, (TransformerEncoderBlock, MultiHeadAttention)):
+            if not layer.causal:
+                raise ValueError(
+                    f"layer {i} {type(layer).__name__}(causal=False) cannot "
+                    f"be decoded autoregressively — generation needs causal "
+                    f"attention")
+        elif not (isinstance(layer, _TOKEN_LOCAL)
+                  or (isinstance(layer, Output) and i == last)):
+            raise ValueError(
+                f"layer {i} {type(layer).__name__} does not say how it "
+                f"decodes: it is not token-local, and has no "
+                f"decode(params, x, cache, pos) -> (y, cache) with "
+                f"cache_spec(input_shape) -> (kv_heads, head_dim) "
+                f"(nn.generation.says_how_it_decodes). Decoding it one "
+                f"token at a time without a cache would disagree with "
+                f"its full forward pass")
+    out_layer = model.layers[last]
+    if served and not isinstance(out_layer, Output):
+        raise ValueError("model must end in an Output layer")
+    return int(getattr(out_layer, "n_out", 0) or model._shapes[-1][-1])
 
 
 def sample_logits(logits, rng, temperature: float = 1.0,
@@ -403,34 +452,7 @@ def generate(model: Sequential, prompt, max_new_tokens: int, *,
     capacity = capacity or total
     if capacity < total:
         raise ValueError(f"capacity {capacity} < prompt+new tokens {total}")
-    for i, layer in enumerate(model.layers):
-        if says_how_it_decodes(layer):
-            continue
-        if isinstance(layer, PositionalEmbedding):
-            if layer.max_len < total:
-                raise ValueError(
-                    f"PositionalEmbedding(max_len={layer.max_len}) is shorter "
-                    f"than prompt+new tokens {total}")
-        elif isinstance(layer, (TransformerEncoderBlock, MultiHeadAttention)):
-            if not layer.causal:
-                raise ValueError(
-                    f"layer {i} {type(layer).__name__}(causal=False) cannot "
-                    f"be decoded autoregressively — generation needs causal "
-                    f"attention")
-        elif isinstance(layer, (RecurrentLayer, _TOKEN_LOCAL)):
-            pass
-        elif isinstance(layer, Output) and i == len(model.layers) - 1:
-            pass
-        else:
-            raise ValueError(
-                f"generate() does not support layer {i} "
-                f"{type(layer).__name__}: it is not token-local along the "
-                f"sequence (decoding it one token at a time would disagree "
-                f"with the full forward pass) and does not say how it "
-                f"decodes (no decode(params, x, cache, pos) and "
-                f"cache_spec(input_shape): see says_how_it_decodes)")
-    out_layer = model.layers[-1]
-    V = getattr(out_layer, "n_out", 0) or model._shapes[-1][-1]
+    V = check_decodes(model, total, "prompt+new tokens")
     # rng convention: pass an explicit key for streamed/nested sampling; with
     # rng=None each call derives its stream from ``seed`` (deterministic,
     # caller-controlled — never a library-internal constant key)

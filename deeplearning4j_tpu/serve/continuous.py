@@ -18,48 +18,47 @@ Static shapes throughout (the TPU contract):
   ``prefill chunk buckets``) before prefill, with the true length traced —
   compile count is ``<= |prompt_buckets| + 1``.
 
-Two KV layouts (``kv=`` constructor arg; contract in ``nn/generation.py``):
+This module is the scheduler; the device programs it feeds (the sampler,
+the prefill chunk, the decode step), their KV pools and their operand
+lists are ``serve/programs.py``'s.
 
-``kv="paged"`` (default) — one shared block pool per attention layer
-  (``serve/paged.py``); each slot owns an ``int32`` block-table row that
-  maps logical block ``p // block_size`` to a physical block. The table is
-  a *traced operand* of the one decode executable, so allocation, growth,
-  and copy-free retirement (free the ids, zero the row) never recompile
-  anything. HBM cost is O(live tokens); per-request ``capacity`` is a
-  logical limit decoupled from any dense buffer — rope models (no
-  ``PositionalEmbedding`` table) can serve contexts far past their
-  training length. Admission commits worst-case blocks up front
-  (``ceil((prompt+max_new)/block_size)``), so a decode can never run out
-  of memory mid-flight; physical blocks are allocated lazily as tokens
-  materialize, which is what makes the live-KV-bytes gauge track live
-  data. Prefill is **chunked**: a long prompt advances ``prefill_chunk``
-  tokens per step, interleaved with decode ticks under a priority-aware
-  :class:`~.engine.PrefillScheduler`, so a prompt burst cannot stall
-  in-flight decodes for its whole prefill.
+The KV cache is paged (layout contract in ``nn/generation.py``): one shared
+block pool per attention layer (``serve/paged.py``); each slot owns an
+``int32`` block-table row that maps logical block ``p // block_size`` to a
+physical block. The table is a *traced operand* of the one decode
+executable, so allocation, growth, and copy-free retirement (free the ids,
+zero the row) never recompile anything. HBM cost is O(live tokens);
+per-request ``capacity`` is a logical limit decoupled from any dense
+buffer — rope models (no ``PositionalEmbedding`` table) can serve contexts
+far past their training length. Admission commits worst-case blocks up
+front (``ceil((prompt+max_new)/block_size)``), so a decode can never run
+out of memory mid-flight; physical blocks are allocated lazily as tokens
+materialize, which is what makes the live-KV-bytes gauge track live data.
+Prefill is **chunked**: a long prompt advances ``prefill_chunk`` tokens per
+step, interleaved with decode ticks under a priority-aware
+:class:`~.engine.PrefillScheduler`, so a prompt burst cannot stall
+in-flight decodes for its whole prefill.
 
-  Paged mode shares KV across requests (``prefix_cache=True``): whole
-  prompt blocks are inserted into a :class:`~.paged.PrefixCache` keyed on
-  ``(params generation, rolling sha256 of block token runs)`` as prefills
-  complete, and admission adopts the longest cached run — refcount++ on
-  the shared physical blocks, prefill computes only the non-shared
-  suffix, and the worst-case commitment charges only non-shared blocks.
-  Cached-but-idle runs form an LRU the allocator reclaims under capacity
-  pressure before anything sheds; a registry generation flip invalidates
-  the cache wholesale so stale-params KV is never adopted. Decode writes
-  always land in a slot's private tail block, so copy-on-write triggers
-  exactly when a slot must write a block someone else still references
-  (a forked tail): the batcher copies that one block eagerly (host-side
-  dispatch, never a new jit site), swaps the table row, refcount--.
-  :meth:`ContinuousBatcher.fork` clones a decoding slot by duplicating
-  its table row with refcount++ on every block — one int32 row copy,
-  never KV bytes. All sharing is host-side bookkeeping: the decode step
-  stays ONE executable for the server lifetime, enforced by the
-  committed compile-surface budget.
+KV is shared across requests (``prefix_cache=True``): whole prompt blocks
+are inserted into a :class:`~.paged.PrefixCache` keyed on ``(params
+generation, rolling sha256 of block token runs)`` as prefills complete,
+and admission adopts the longest cached run — refcount++ on the shared
+physical blocks, prefill computes only the non-shared suffix, and the
+worst-case commitment charges only non-shared blocks. Cached-but-idle runs
+form an LRU the allocator reclaims under capacity pressure before anything
+sheds; a registry generation flip invalidates the cache wholesale so
+stale-params KV is never adopted. Decode writes always land in a slot's
+private tail block, so copy-on-write triggers exactly when a slot must
+write a block someone else still references (a forked tail): the batcher
+copies that one block eagerly (host-side dispatch, never a new jit site),
+swaps the table row, refcount--. :meth:`ContinuousBatcher.fork` clones a
+decoding slot by duplicating its table row with refcount++ on every
+block — one int32 row copy, never KV bytes. All sharing is host-side
+bookkeeping: the decode step stays ONE executable for the server lifetime,
+enforced by the committed compile-surface budget.
 
-``kv="dense"`` — the original slot-major ``(slots, 1, capacity, ...)``
-  buffers written with ``lax.dynamic_update_slice`` and a vmapped decode;
-  kept as the bit-exact baseline and for models where one big
-  un-chunked prefill is preferable.
+The bit-exact baseline for all of it is whole-batch
+``nn.generation.generate`` over its contiguous caches.
 
 Scope: embedding-front causal-attention stacks (the CausalLM family).
 Recurrent layers are rejected — a right-padded prefill would run the RNN
@@ -71,7 +70,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional, Sequence
+from typing import Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
 
@@ -84,6 +83,7 @@ from .errors import (CapacityError, DeadlineExceededError, DrainTimeoutError,
                      WorkerStallError)
 from .paged import (BlockAllocator, PrefixCache, SlotPages, block_bytes,
                     blocks_needed, prefix_hashes)
+from .programs import GenPrograms
 from .registry import ModelRegistry
 
 
@@ -98,8 +98,9 @@ def _default_prompt_buckets(capacity: int) -> tuple:
 
 # Constructor knobs a tuned config (aot/tuned.py) may set on the batcher.
 # Unknown keys in a stored "gen" group are dropped, so configs written by a
-# newer tuner never break an older binary at boot.
-GEN_KNOBS = frozenset({"slots", "capacity", "kv", "block_size", "kv_blocks",
+# newer tuner (or an older one: "kv", when there were two layouts) never
+# break this binary at boot.
+GEN_KNOBS = frozenset({"slots", "capacity", "block_size", "kv_blocks",
                        "prefill_chunk", "prompt_buckets", "queue_limit",
                        "seed", "prefix_cache", "prefix_cache_blocks"})
 
@@ -264,7 +265,7 @@ class ContinuousBatcher:
 
     ``slots``: concurrent in-flight sequences (the decode batch size).
     ``capacity``: max context per request (``len(prompt) + max_new_tokens
-    <= capacity``). With ``kv="paged"`` this is a *logical* bound backed by
+    <= capacity``). This is a *logical* bound backed by
     ``kv_blocks`` shared physical blocks of ``block_size`` tokens — a pool
     smaller than ``slots * capacity`` oversubscribes gracefully: requests
     queue while blocks are committed elsewhere and shed with a typed
@@ -278,7 +279,7 @@ class ContinuousBatcher:
 
     def __init__(self, model, registry: Optional[ModelRegistry] = None,
                  params=None, state=None, *, slots: int = 4,
-                 capacity: int = 256, kv: str = "paged",
+                 capacity: int = 256,
                  block_size: int = 16, kv_blocks: Optional[int] = None,
                  prefix_cache: bool = True,
                  prefix_cache_blocks: Optional[int] = None,
@@ -289,21 +290,10 @@ class ContinuousBatcher:
                  aot_store=None, strict_aot: bool = False,
                  model_name: Optional[str] = None):
         import jax
-        import jax.numpy as jnp
-        from jax import lax
 
-        from ..nn.generation import (_TOKEN_LOCAL, cache_spec, decode_forward,
-                                     init_caches, says_how_it_decodes)
-        from ..nn.layers import (Embedding, EmbeddingSequence,
-                                 MultiHeadAttention, Output,
-                                 PositionalEmbedding, TransformerEncoderBlock)
-        from ..nn.layers.recurrent import RecurrentLayer
-        from ..nn.model import _layer_key
+        from ..nn.generation import check_decodes
         from ..obs.metrics import MetricsRegistry
-        from .paged import build_pools
 
-        if kv not in ("paged", "dense"):
-            raise ValueError(f"kv must be 'paged' or 'dense', got {kv!r}")
         self.model = model
         # fleet serving: model=<name> on every batcher metric; None keeps
         # the historical single-model label sets (absent == empty label)
@@ -314,7 +304,6 @@ class ContinuousBatcher:
                 state if state is not None else model.state, metrics=metrics,
                 model=model_name)
         self.registry = registry
-        self.kv = kv
         self.slots = int(slots)
         self.capacity = int(capacity)
         self.queue_limit = int(queue_limit)
@@ -325,227 +314,56 @@ class ContinuousBatcher:
             int(b) for b in (prompt_buckets
                              or _default_prompt_buckets(self.capacity))
             if b <= self.capacity))) or (self.capacity,)
-
-        # --- model contract: embedding-front, causal, and every stateful
-        # layer says how it decodes (serve/README.md) ---
-        first = model.layers[0]
-        if not isinstance(first, (Embedding, EmbeddingSequence)):
-            raise ValueError(
-                "continuous batching requires an embedding-front token model "
-                "(CausalLM family); one-hot char models stay on "
-                "nn.generation.generate")
-        known = _TOKEN_LOCAL + (TransformerEncoderBlock, MultiHeadAttention,
-                                PositionalEmbedding, Output)
-        for i, layer in enumerate(model.layers):
-            if says_how_it_decodes(layer):
-                continue   # the layer's own decode() and cache_spec()
-            if isinstance(layer, RecurrentLayer):
-                raise ValueError(
-                    f"layer {i} {type(layer).__name__}: recurrent carries "
-                    f"cannot survive a right-padded prefill — use whole-batch "
-                    f"nn.generation.generate for RNN models")
-            if not isinstance(layer, known):
-                raise ValueError(
-                    f"layer {i} {type(layer).__name__} does not say how it "
-                    f"decodes: it is not token-local, and has no "
-                    f"decode(params, x, cache, pos) -> (y, cache) with "
-                    f"cache_spec(input_shape) -> (kv_heads, head_dim) "
-                    f"(nn.generation.says_how_it_decodes). Decoding it one "
-                    f"token at a time without a cache would disagree with "
-                    f"its full forward pass")
-            if isinstance(layer, (TransformerEncoderBlock, MultiHeadAttention)) \
-                    and not layer.causal:
-                raise ValueError(
-                    f"layer {i} {type(layer).__name__}(causal=False) cannot "
-                    f"be decoded autoregressively")
-            # a learned positional TABLE bounds context; rope models have no
-            # such layer, so paged capacity is free to exceed training length
-            if isinstance(layer, PositionalEmbedding) \
-                    and layer.max_len < self.capacity:
-                raise ValueError(
-                    f"PositionalEmbedding(max_len={layer.max_len}) is shorter "
-                    f"than cache capacity {self.capacity}")
-        out_layer = model.layers[-1]
-        if not isinstance(out_layer, Output):
-            raise ValueError("model must end in an Output layer")
-        self.vocab = int(getattr(out_layer, "n_out", 0)
-                         or model._shapes[-1][-1])
+        # model contract: embedding-front, causal, and every stateful layer
+        # says how it decodes (serve/README.md)
+        self.vocab = check_decodes(model, self.capacity, "cache capacity",
+                                   served=True)
+        # strict_aot: a store miss raises a typed AotTraceError instead of
+        # tracing — and because _warm_for runs at construction, the FIRST
+        # uncovered signature fails the boot itself, never a request
+        self.strict_aot = bool(strict_aot)
+        if self.strict_aot and aot_store is None:
+            raise ValueError("strict_aot=True requires an aot_store — "
+                             "a storeless batcher can only trace")
 
         S, C, V = self.slots, self.capacity, self.vocab
-        mdl = model
-
-        def _sample_dynamic(logits, key, temperature, top_k):
-            """Fully-traced sampler: temperature 0 -> greedy, top_k as a
-            dynamic scalar (top_k == V disables the restriction)."""
-            with jax.named_scope("sample"):  # its name in a device trace
-                greedy = jnp.argmax(logits, axis=-1)
-                t = jnp.maximum(temperature, 1e-6)
-                scaled = logits / t
-                srt = jnp.sort(scaled, axis=-1)  # ascending
-                k = jnp.clip(top_k, 1, V)
-                kth = jnp.take(srt, V - k, axis=-1)
-                masked = jnp.where(scaled >= kth, scaled, -1e30)
-                samp = jax.random.categorical(key, masked, axis=-1)
-                return jnp.where(temperature <= 0.0, greedy,
-                                 samp).astype(jnp.int32)
-
-        self._sample = jax.jit(_sample_dynamic)
-
-        if kv == "paged":
-            self.block_size = int(block_size)
-            self._maxb = blocks_needed(C, self.block_size)
-            if kv_blocks is None:
-                # dense-equivalent coverage + the reserved trash block
-                kv_blocks = S * self._maxb + 1
-            self.kv_blocks = int(kv_blocks)
-            if prefill_chunk is not None and prefill_chunk < 1:
-                raise ValueError("prefill_chunk must be >= 1 or None")
-            self.prefill_chunk = (int(prefill_chunk)
-                                  if prefill_chunk is not None else None)
-            if self.prefill_chunk is not None:
-                self._chunk_buckets = tuple(sorted(set(
-                    [b for b in self.prompt_buckets
-                     if b <= self.prefill_chunk] + [self.prefill_chunk])))
-            else:
-                self._chunk_buckets = self.prompt_buckets
-            self._alloc = BlockAllocator(self.kv_blocks)
-            self._prefix: Optional[PrefixCache] = None
-            if prefix_cache:
-                self._prefix = PrefixCache(self._alloc, self.block_size,
-                                           prefix_cache_blocks)
-                # cached-but-idle runs are reclaimed before anyone sheds
-                self._alloc.set_reclaimer(self._prefix.reclaim)
-            # distinct physical blocks slots hold via retain (adopted prefix
-            # runs, fork rows) — these sit OUTSIDE every worst-case
-            # commitment, so admission subtracts them from the pool
-            self._shared_ledger: Dict[int, int] = {}
-            self._cow_copies = 0
-            self._forks = 0
-            self._fork_salt = 0  # every attempt, successful or not
-            self._px_hits = 0
-            self._px_misses = 0
-            self._pools = build_pools(mdl, self.kv_blocks, self.block_size,
-                                      mdl.dtype)
-            self._lks = [lk for lk, _, _ in cache_spec(mdl)]
-            self._tables_np = np.zeros((S, self._maxb), np.int32)
-            self._slot_pages: List[Optional[SlotPages]] = [None] * S
-            self._slot_worst = np.zeros(S, np.int64)
-            self._committed = 0
-            self._block_bytes = block_bytes(mdl, self.block_size, mdl.dtype)
-            lks = self._lks
-            # layers with experts (layers/olmoe.py) report what routing did
-            # to the rows marked live; the sums leave each program as three
-            # int32 (nn.layers.olmoe.ROUTING_FIELDS). A model without such
-            # layers builds the programs it always built
-            routed = [_layer_key(i, layer)
-                      for i, layer in enumerate(mdl.layers)
-                      if says_how_it_decodes(layer)
-                      and getattr(layer, "num_experts", 0)]
-            self._routed = len(routed)
-
-            def _as_caches(pools, tables, live=None):
-                caches = {lk: {"k_pool": pools[lk]["k"],
-                               "v_pool": pools[lk]["v"],
-                               "tables": tables} for lk in lks}
-                for lk in routed:
-                    caches[lk]["live"] = live
-                return caches
-
-            def _routing(caches):
-                return sum(caches[lk]["routing"] for lk in routed)
-
-            def _as_pools(caches):
-                return {lk: {"k": caches[lk]["k_pool"],
-                             "v": caches[lk]["v_pool"]} for lk in lks}
-
-            def _prefill_chunk_fn(params, state, ids, pools, table_row, pos,
-                                  true_len):
-                """One prompt chunk for one slot. ``ids`` (1, Tb)
-                right-padded; ``pos`` (1,) chunk offset; pad garbage writes
-                past the row's blocks land in the trash block. Logits are
-                gathered at the last REAL token of the chunk."""
-                live = (jnp.arange(ids.shape[1]) < true_len)[None] \
-                    if routed else None
-                lg, caches = decode_forward(
-                    mdl, params, state, ids,
-                    _as_caches(pools, table_row, live), pos)
-                last = jnp.take(lg, true_len - 1, axis=1)  # (1, V)
-                if routed:
-                    return last, _as_pools(caches), _routing(caches)
-                return last, _as_pools(caches)
-
-            def _decode_paged_fn(params, state, toks, pools, tables, pos,
-                                 keys, temps, tks):
-                """One token for every slot, batched over the slot axis
-                against the shared pools — ONE executable for the server's
-                lifetime (tables/pos are traced operands). Inactive slots
-                carry zeroed table rows, so their writes land in the trash
-                block and their sampled garbage is discarded host-side."""
-                # a live slot's first block is never the trash block
-                live = (tables[:, :1] != 0) if routed else None
-                lg, caches = decode_forward(
-                    mdl, params, state, toks[:, None].astype(jnp.int32),
-                    _as_caches(pools, tables, live), pos)
-
-                def one(l, key, temp, tk):
-                    key, sub = jax.random.split(key)
-                    return _sample_dynamic(l, sub, temp, tk), key
-
-                nxt, new_keys = jax.vmap(one)(lg[:, 0], keys, temps, tks)
-                if routed:
-                    # the three sums ride the tokens' readback: (S + 3,)
-                    nxt = jnp.concatenate([nxt, _routing(caches)])
-                return nxt, _as_pools(caches), new_keys
-
-            # pools are the loop-carried buffers: donated every step
-            self._prefill_paged = jax.jit(_prefill_chunk_fn,
-                                          donate_argnums=(3,))
-            self._decode = jax.jit(_decode_paged_fn, donate_argnums=(3,))
+        self.block_size = int(block_size)
+        self._maxb = blocks_needed(C, self.block_size)
+        if kv_blocks is None:
+            # dense-equivalent coverage + the reserved trash block
+            kv_blocks = S * self._maxb + 1
+        self.kv_blocks = int(kv_blocks)
+        if prefill_chunk is not None and prefill_chunk < 1:
+            raise ValueError("prefill_chunk must be >= 1 or None")
+        self.prefill_chunk = (int(prefill_chunk)
+                              if prefill_chunk is not None else None)
+        if self.prefill_chunk is not None:
+            self._chunk_buckets = tuple(sorted(set(
+                [b for b in self.prompt_buckets
+                 if b <= self.prefill_chunk] + [self.prefill_chunk])))
         else:
-            self.block_size = None
-            self.kv_blocks = None
-            self.prefill_chunk = None
-            self._committed = 0
-            self._prefix = None
-            self._routed = 0   # routing is counted on the paged path only
-
-            def _prefill(params, state, ids, true_len):
-                """ids (1, Tb) right-padded prompt; logits are gathered at
-                the last REAL token so padding never leaks into sampling."""
-                caches = init_caches(mdl, 1, C, mdl.dtype)
-                lg, c = decode_forward(mdl, params, state, ids, caches, 0)
-                last = jnp.take(lg, true_len - 1, axis=1)  # (1, V)
-                return last, c
-
-            def _slot_insert(big, small, s):
-                def wr(b, sm):
-                    return lax.dynamic_update_slice(
-                        b, sm.astype(b.dtype)[None],
-                        (s,) + (0,) * (b.ndim - 1))
-                return jax.tree.map(wr, big, small)
-
-            def _decode_step(params, state, toks, caches, pos, keys, temps,
-                             tks):
-                """One token for every slot. All per-slot scalars are traced
-                and vmapped, so this is ONE executable for the server's
-                lifetime."""
-                def one(tok, cache, p, key, temp, tk):
-                    x = tok.reshape(1, 1).astype(jnp.int32)
-                    lg, c2 = decode_forward(mdl, params, state, x, cache, p)
-                    key, sub = jax.random.split(key)
-                    nxt = _sample_dynamic(lg[0, 0], sub, temp, tk)
-                    return nxt, c2, key
-
-                return jax.vmap(one, in_axes=(0, 0, 0, 0, 0, 0))(
-                    toks, caches, pos, keys, temps, tks)
-
-            self._prefill = jax.jit(_prefill)
-            self._slot_insert = jax.jit(_slot_insert, donate_argnums=(0,))
-            # caches are the loop-carried buffer: donate them every tick
-            self._decode = jax.jit(_decode_step, donate_argnums=(3,))
-
-            cache0 = init_caches(model, 1, C, model.dtype)
-            self._caches = jax.tree.map(lambda z: jnp.stack([z] * S), cache0)
+            self._chunk_buckets = self.prompt_buckets
+        self._alloc = BlockAllocator(self.kv_blocks)
+        self._prefix: Optional[PrefixCache] = None
+        if prefix_cache:
+            self._prefix = PrefixCache(self._alloc, self.block_size,
+                                       prefix_cache_blocks)
+            # cached-but-idle runs are reclaimed before anyone sheds
+            self._alloc.set_reclaimer(self._prefix.reclaim)
+        # distinct physical blocks slots hold via retain (adopted prefix
+        # runs, fork rows) — these sit OUTSIDE every worst-case
+        # commitment, so admission subtracts them from the pool
+        self._shared_ledger: Dict[int, int] = {}
+        self._cow_copies = 0
+        self._forks = 0
+        self._fork_salt = 0  # every attempt, successful or not
+        self._px_hits = 0
+        self._px_misses = 0
+        self._tables_np = np.zeros((S, self._maxb), np.int32)
+        self._slot_pages: List[Optional[SlotPages]] = [None] * S
+        self._slot_worst = np.zeros(S, np.int64)
+        self._committed = 0
+        self._block_bytes = block_bytes(model, self.block_size, model.dtype)
 
         self._base_key = jax.random.PRNGKey(seed)
 
@@ -554,7 +372,6 @@ class ContinuousBatcher:
         self._jobs: List[_PrefillJob] = []
         self._slot_req: List[Optional[_GenRequest]] = [None] * S
         self._slot_job: List[Optional[_PrefillJob]] = [None] * S
-        self._admitting: List[_GenRequest] = []  # dense: popped, not slotted
         self._closing = False
         # crash-only worker lifecycle (see ServeEngine): epoch stales a hung
         # worker, restart sheds its in-flight sequences with typed errors
@@ -603,62 +420,44 @@ class ContinuousBatcher:
         self._m_compiles = m.counter(
             "serve_compile_misses_total", self._lbl({"component": "generate"}),
             help="new (bucket, shape) signatures — each is an XLA compile")
-        if kv == "paged":
-            m.gauge("serve_kv_blocks_total", self._lbl(),
-                    help="allocatable KV blocks (excl. trash block)"
-                    ).set(self._alloc.usable)
-            self._m_kv_used = m.gauge("serve_kv_blocks_used", self._lbl(),
-                                      help="KV blocks currently allocated")
-            self._m_kv_util = m.gauge(
-                "serve_kv_block_utilization", self._lbl(),
-                help="allocated / allocatable KV blocks")
-            self._m_kv_bytes = m.gauge(
-                "serve_kv_live_bytes", self._lbl(),
-                help="bytes of KV pool backing live tokens (all layers)")
-            self._m_pf_depth = m.gauge(
-                "serve_prefill_queue_depth", self._lbl(),
-                help="prompts mid-prefill (chunked jobs in flight)")
-            self._m_pf_chunks = m.counter(
-                "serve_prefill_chunks_total", self._lbl(),
-                help="prefill chunks executed")
-            self._m_px_hits = m.counter(
-                "serve_prefix_cache_hits_total", self._lbl(),
-                help="admissions that adopted >= 1 cached prefix block")
-            self._m_px_miss = m.counter(
-                "serve_prefix_cache_misses_total", self._lbl(),
-                help="admissions that found no cached prefix run")
-            self._m_px_saved = m.counter(
-                "serve_prefill_tokens_saved_total", self._lbl(),
-                help="prompt tokens skipped by adopting cached prefix blocks")
-            self._m_px_shared = m.gauge(
-                "serve_prefix_blocks_shared", self._lbl(),
-                help="distinct KV blocks slots hold via sharing "
-                     "(adopted prefix runs + fork rows)")
-            self._m_cow = m.counter(
-                "serve_kv_cow_copies_total", self._lbl(),
-                help="copy-on-write block copies (a still-shared block "
-                     "was about to be written)")
-            self._m_forks = m.counter(
-                "serve_gen_forks_total", self._lbl(),
-                help="slots forked by block-table row copy")
-            self._update_kv_gauges()
-        # what routing did, per program kind; nothing for a model without
-        # experts. A chunk's sums stay on the device until the next readback
-        # of something computed behind them (_count_chunks_routing)
-        self._routing_pending: List[Any] = []
-        if self._routed:
-            from ..nn.layers.olmoe import ROUTING_FIELDS
-
-            self._m_routing = {
-                prog: [m.counter(f"serve_moe_{f}_total",
-                                 self._lbl({"program": prog}), help=what)
-                       for f, what in ROUTING_FIELDS.items()]
-                + [m.counter("serve_moe_layer_programs_total",
-                             self._lbl({"program": prog}),
-                             help="expert layers run: layers x decode steps "
-                                  "and prefill chunks")]
-                for prog in ("decode", "prefill")}
-
+        m.gauge("serve_kv_blocks_total", self._lbl(),
+                help="allocatable KV blocks (excl. trash block)"
+                ).set(self._alloc.usable)
+        self._m_kv_used = m.gauge("serve_kv_blocks_used", self._lbl(),
+                                  help="KV blocks currently allocated")
+        self._m_kv_util = m.gauge(
+            "serve_kv_block_utilization", self._lbl(),
+            help="allocated / allocatable KV blocks")
+        self._m_kv_bytes = m.gauge(
+            "serve_kv_live_bytes", self._lbl(),
+            help="bytes of KV pool backing live tokens (all layers)")
+        self._m_pf_depth = m.gauge(
+            "serve_prefill_queue_depth", self._lbl(),
+            help="prompts mid-prefill (chunked jobs in flight)")
+        self._m_pf_chunks = m.counter(
+            "serve_prefill_chunks_total", self._lbl(),
+            help="prefill chunks executed")
+        self._m_px_hits = m.counter(
+            "serve_prefix_cache_hits_total", self._lbl(),
+            help="admissions that adopted >= 1 cached prefix block")
+        self._m_px_miss = m.counter(
+            "serve_prefix_cache_misses_total", self._lbl(),
+            help="admissions that found no cached prefix run")
+        self._m_px_saved = m.counter(
+            "serve_prefill_tokens_saved_total", self._lbl(),
+            help="prompt tokens skipped by adopting cached prefix blocks")
+        self._m_px_shared = m.gauge(
+            "serve_prefix_blocks_shared", self._lbl(),
+            help="distinct KV blocks slots hold via sharing "
+                 "(adopted prefix runs + fork rows)")
+        self._m_cow = m.counter(
+            "serve_kv_cow_copies_total", self._lbl(),
+            help="copy-on-write block copies (a still-shared block "
+                 "was about to be written)")
+        self._m_forks = m.counter(
+            "serve_gen_forks_total", self._lbl(),
+            help="slots forked by block-table row copy")
+        self._update_kv_gauges()
         # --- the parameters the compiled programs read. A model with a
         # compute_dtype gets, per params generation, ONE copy of the
         # registry's tree already cast to it (nn.generation.decode_params):
@@ -667,7 +466,7 @@ class ContinuousBatcher:
         # weights at compute width instead of re-casting them every step.
         # The registry's own tree (checkpoints, hot-swap, rollback) is
         # untouched; without a compute_dtype there is no copy ---
-        self._casts = bool(mdl.config.compute_dtype)
+        self._casts = bool(model.config.compute_dtype)
         self._cast_lock = threading.Lock()
         # (registry tree, its copy, bytes), oldest first: the generation
         # being served, then candidates whose flip the worker has not seen
@@ -682,44 +481,32 @@ class ContinuousBatcher:
             help="bytes held by compute-dtype copies of the served "
                  "parameters (two generations coexist during a swap)")
 
-        # --- persistent AOT store (optional): every generation executable
-        # loads from disk before tracing, and is warmed eagerly so the
-        # decode loop never traces in the request path after boot.
-        # strict_aot: a store miss raises a typed AotTraceError instead of
-        # tracing — and because _warm_for runs at construction, the FIRST
-        # uncovered signature fails the boot itself, never a request ---
-        self.strict_aot = bool(strict_aot)
-        if self.strict_aot and aot_store is None:
-            raise ValueError("strict_aot=True requires an aot_store — "
-                             "a storeless batcher can only trace")
-        self._aot = None
-        self._aot_fns: Dict[str, Any] = {}
+        # --- the device programs and their pools (serve/programs.py). With
+        # a persistent AOT store every generation executable loads from
+        # disk before tracing, and is warmed eagerly so the decode loop
+        # never traces in the request path after boot ---
+        self._aot = aot_store
         snap0 = self.registry.current()
-        if aot_store is not None:
-            from ..aot import AotFunction, arch_fingerprint
+        self._programs = GenPrograms(
+            model, slots=S, table_blocks=self._maxb, vocab=V,
+            kv_blocks=self.kv_blocks, block_size=self.block_size,
+            chunk_buckets=self._chunk_buckets, metrics=m,
+            compile_counter=self._m_compiles, store=aot_store,
+            strict=self.strict_aot, snapshot=snap0)
+        # what routing did, per program kind; nothing for a model without
+        # experts
+        if self._programs.routed:
+            from ..nn.layers.olmoe import ROUTING_FIELDS
 
-            arch = arch_fingerprint(snap0.params, snap0.state)
-
-            def _wrap(fn, tag, donate=()):
-                wrapped = AotFunction(
-                    fn, tag=tag, store=aot_store, metrics=m, arch=arch,
-                    component="generate", donate_argnums=donate,
-                    compile_counter=self._m_compiles,
-                    strict=self.strict_aot)
-                self._aot_fns[tag] = wrapped
-                return wrapped
-
-            self._sample = _wrap(self._sample, "gen_sample")
-            if kv == "paged":
-                self._prefill_paged = _wrap(self._prefill_paged,
-                                            "gen_prefill_chunk", (3,))
-                self._decode = _wrap(self._decode, "gen_decode_paged", (3,))
-            else:
-                self._prefill = _wrap(self._prefill, "gen_prefill_dense")
-                self._slot_insert = _wrap(self._slot_insert,
-                                          "gen_slot_insert", (0,))
-                self._decode = _wrap(self._decode, "gen_decode_dense", (3,))
-            self._aot = aot_store
+            self._m_routing = {
+                prog: [m.counter(f"serve_moe_{f}_total",
+                                 self._lbl({"program": prog}), help=what)
+                       for f, what in ROUTING_FIELDS.items()]
+                + [m.counter("serve_moe_layer_programs_total",
+                             self._lbl({"program": prog}),
+                             help="expert layers run: layers x decode steps "
+                                  "and prefill chunks")]
+                for prog in ("decode", "prefill")}
         t0 = time.perf_counter()
         self._warm_for(snap0.params, snap0.state)
         if self._aot is not None:
@@ -761,50 +548,11 @@ class ContinuousBatcher:
     # ---------------------------------------------------------------- warming
     def _warm_for(self, params, state) -> None:
         """Ready one params generation for the worker: make its
-        compute-dtype copy, then (with a store) load-or-compile the full
-        static executable set — the lifetime decode step, every prefill
-        bucket, and the sampler — against the COPY's dtypes, via abstract
-        shapes (nothing executes, nothing is donated). Runs at construction
-        for the current generation and as a registry warmer for each
-        publish candidate."""
-        import jax
-
-        params = self._cast_params(params)
-        if self._aot is None:
-            return
-        S, V = self.slots, self.vocab
-        sds = jax.ShapeDtypeStruct
-
-        def abstract(tree):
-            return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
-
-        i32, f32, u32 = np.int32, np.float32, np.uint32
-        self._sample.warm(sds((V,), f32), sds((2,), u32), sds((), f32),
-                          sds((), i32))
-        if self.kv == "paged":
-            pools = abstract(self._pools)
-            self._decode.warm(params, state, sds((S,), i32), pools,
-                              sds((S, self._maxb), i32), sds((S,), i32),
-                              sds((S, 2), u32), sds((S,), f32),
-                              sds((S,), i32))
-            for b in self._chunk_buckets:
-                self._prefill_paged.warm(
-                    params, state, sds((1, b), i32), pools,
-                    sds((1, self._maxb), i32), sds((1,), i32),
-                    sds((), i32))
-        else:
-            from ..nn.generation import init_caches
-
-            caches = abstract(self._caches)
-            cache1 = abstract(init_caches(self.model, 1, self.capacity,
-                                          self.model.dtype))
-            self._decode.warm(params, state, sds((S,), i32), caches,
-                              sds((S,), i32), sds((S, 2), u32),
-                              sds((S,), f32), sds((S,), i32))
-            self._slot_insert.warm(caches, cache1, sds((), i32))
-            for b in self.prompt_buckets:
-                self._prefill.warm(params, state, sds((1, b), i32),
-                                   sds((), i32))
+        compute-dtype copy, then (with a store) load-or-compile the
+        programs' full static executable set against the COPY's dtypes.
+        Runs at construction for the current generation and as a registry
+        warmer for each publish candidate."""
+        self._programs.warm(self._cast_params(params), state)
 
     # ------------------------------------------------- compute-dtype params
     def _make_copy(self, params) -> tuple:
@@ -895,16 +643,15 @@ class ContinuousBatcher:
             raise CapacityError(
                 f"prompt ({prompt.shape[0]}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds cache capacity {self.capacity}")
-        if self.kv == "paged":
-            worst = blocks_needed(prompt.shape[0] + int(max_new_tokens),
-                                  self.block_size)
-            if worst > self._alloc.usable:
-                # queueing can't help: this request can NEVER fit
-                self._shed_counter("over_capacity").inc()
-                raise CapacityError(
-                    f"request needs {worst} KV blocks but the pool only has "
-                    f"{self._alloc.usable} — raise kv_blocks or lower "
-                    f"max_new_tokens")
+        worst = blocks_needed(prompt.shape[0] + int(max_new_tokens),
+                              self.block_size)
+        if worst > self._alloc.usable:
+            # queueing can't help: this request can NEVER fit
+            self._shed_counter("over_capacity").inc()
+            raise CapacityError(
+                f"request needs {worst} KV blocks but the pool only has "
+                f"{self._alloc.usable} — raise kv_blocks or lower "
+                f"max_new_tokens")
         deadline = (time.perf_counter() + timeout_ms / 1e3
                     if timeout_ms is not None else None)
         req = _GenRequest(prompt, max_new_tokens, temperature, top_k,
@@ -1010,15 +757,12 @@ class ContinuousBatcher:
         sampled continuations diverge; at ``temperature=0`` both chains
         stay greedy and identical. Whole shared blocks are never written
         again; the partial tail block is copied on first write
-        (copy-on-write), so forking is O(blocks) host work. ``kv="paged"``
-        only. Raises :class:`ShedError` when no free slot or insufficient
-        block headroom exists, :class:`ServeError` when ``req`` is not
-        currently decoding in a slot."""
+        (copy-on-write), so forking is O(blocks) host work. Raises
+        :class:`ShedError` when no free slot or insufficient block headroom
+        exists, :class:`ServeError` when ``req`` is not currently decoding
+        in a slot."""
         import jax
 
-        if self.kv != "paged":
-            raise ServeError("fork() requires kv='paged' (block-table rows "
-                             "are what make forking copy-free)")
         with self._cond:
             self._fork_salt += 1
             salt = self._fork_salt
@@ -1157,28 +901,23 @@ class ContinuousBatcher:
         row[:len(blocks)] = blocks
         self._tables_np[s] = row
 
-    # --- paged admission: commit worst-case blocks, start a prefill job ---
-    def _admit_locked(self, generation: int = 0) -> List[tuple]:
-        """Under ``self._cond``: hand free slots to queued requests. Dense
-        mode returns (slot, req) pairs to prefill under the caller's lease;
-        paged mode creates :class:`_PrefillJob` state machines (FIFO — a
-        head request waiting on blocks holds the line, so big requests
-        cannot be starved by a stream of small ones).
+    # --- admission: commit worst-case blocks, start a prefill job ---
+    def _admit_locked(self, generation: int = 0) -> None:
+        """Under ``self._cond``: hand free slots to queued requests as
+        :class:`_PrefillJob` state machines (FIFO — a head request waiting
+        on blocks holds the line, so big requests cannot be starved by a
+        stream of small ones).
 
-        Paged admission charges only NON-shared blocks: the longest cached
+        Admission charges only NON-shared blocks: the longest cached
         prefix run is matched first (``generation`` is the registry
         generation read by the caller — a flip flushes the cache before
         any stale block can match), the gate subtracts both the charge and
         every shared block outside any commitment, and only then are the
         cached blocks adopted (refcount++) and the suffix planned."""
-        admits = []
         for s in range(self.slots):
             if not self._queue:
                 break
             if self._slot_req[s] is not None or self._slot_job[s] is not None:
-                continue
-            if self.kv == "dense":
-                admits.append((s, self._queue.pop(0)))
                 continue
             req = self._queue[0]
             tp = req.prompt.shape[0]
@@ -1215,9 +954,7 @@ class ContinuousBatcher:
                 shared=shared, hashes=hashes)
             self._slot_job[s] = job
             self._jobs.append(job)
-        if self.kv == "paged":
-            self._m_pf_depth.set(len(self._jobs))
-        return admits
+        self._m_pf_depth.set(len(self._jobs))
 
     def _abort_job(self, job: _PrefillJob, err: ServeError) -> None:
         with self._cond:
@@ -1232,13 +969,9 @@ class ContinuousBatcher:
         job.req._finish(err)
 
     def _prefill_step(self, job: _PrefillJob, snap) -> None:
-        """Advance one chunk of one prompt (paged mode)."""
-        import jax.numpy as jnp
-
+        """Advance one chunk of one prompt."""
         with _trace.span(_trace.GEN_PREFILL_CHUNK):
-            # chunk widths come from _plan_chunks, which only ever emits
-            # members of self._chunk_buckets (see _bucket_chunk)
-            off, true_len, bucket = job.chunks[job.idx]  # jaxlint: dim=bucket:bucket(_chunk_buckets)
+            off, true_len, bucket = job.chunks[job.idx]
             with self._cond:
                 if self._slot_job[job.slot] is not job:
                     return  # aborted (forced shutdown) since the tick was planned
@@ -1246,18 +979,13 @@ class ContinuousBatcher:
                 self._write_table_row(job.slot, job.pages.blocks)
                 table_row = self._tables_np[job.slot:job.slot + 1].copy()
                 self._update_kv_gauges()
-            ids = np.zeros((1, bucket), np.int32)
-            ids[0, :true_len] = job.req.prompt[off:off + true_len]
             if _prof.ACTIVE is not None:
                 # live prompt tokens vs the chunk bucket they padded to
                 _prof.ACTIVE.hint("generate", true_len, bucket)
             t0 = time.perf_counter()
-            last, self._pools, *routing = self._prefill_paged(
-                self._params_for(snap), snap.state, jnp.asarray(ids),
-                self._pools,
-                jnp.asarray(table_row), np.full((1,), off, np.int32),
-                np.int32(true_len))
-            self._routing_pending += routing
+            last = self._programs.prefill_chunk(
+                self._params_for(snap), snap.state,
+                job.req.prompt[off:off + true_len], bucket, table_row, off)
             t1 = time.perf_counter()
             req = job.req
             ctx = req.ctx
@@ -1330,9 +1058,9 @@ class ContinuousBatcher:
             req.ctx.decode_begin()
         key = jax.random.fold_in(self._base_key, n)
         key, sub = jax.random.split(key)
-        tok0 = int(_np.asarray(self._sample(
-            job.last[0], sub, np.float32(req.temperature),
-            np.int32(req.top_k if req.top_k else self.vocab))))
+        tok0 = int(_np.asarray(self._programs.sample(
+            job.last[0], sub, req.temperature,
+            req.top_k if req.top_k else self.vocab)))
         self._count_chunks_routing()
         with self._cond:
             if job in self._jobs:
@@ -1357,61 +1085,6 @@ class ContinuousBatcher:
         # a 1-token request (or instant EOS) finishes without ever decoding
         self._maybe_finish(s)
 
-    # --- dense admission (whole-prompt prefill under the caller's lease) ---
-    def _admit_into_slot(self, s: int, req: _GenRequest, snap) -> None:
-        import jax
-        import jax.numpy as jnp
-
-        tp = req.prompt.shape[0]
-        bucket = self._bucket(tp)
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :tp] = req.prompt
-        if _prof.ACTIVE is not None:
-            # live prompt tokens vs the prompt bucket they padded to
-            _prof.ACTIVE.hint("generate", tp, bucket)
-        with _trace.span(_trace.GEN_PREFILL_CHUNK):
-            t0 = time.perf_counter()
-            last, cache = self._prefill(self._params_for(snap), snap.state,
-                                        jnp.asarray(ids), np.int32(tp))
-            t1 = time.perf_counter()
-        self._queue_wait_over(req, t0)
-        if req.ctx is None:
-            self._m_prefill_s.observe(t1 - t0)
-        else:
-            self._m_prefill_s.observe(t1 - t0, trace_id=req.ctx.trace_id)
-            req.ctx.add_stage("prefill_chunk", int(t0 * 1e9), int(t1 * 1e9),
-                              offset=0, bucket=bucket)
-            req.ctx.decode_begin()
-        self._admitted += 1
-        with _trace.span(_trace.GEN_FIRST_TOKEN):
-            key = jax.random.fold_in(self._base_key, self._admitted)
-            key, sub = jax.random.split(key)
-            tok0 = int(np.asarray(self._sample(
-                last[0], sub, np.float32(req.temperature),
-                np.int32(req.top_k if req.top_k else self.vocab))))
-        self._caches = self._slot_insert(self._caches, cache, np.int32(s))
-        with self._cond:
-            sig = ("prefill", bucket)
-            if sig not in self._prefill_sigs:
-                self._prefill_sigs.add(sig)
-                if self._aot is None:  # with a store, AotFunction counts real traces
-                    self._m_compiles.inc()
-            req.slot = s
-            req.key = None
-            self._slot_req[s] = req
-            self._next_tok[s] = tok0
-            self._pos[s] = tp
-            self._temps[s] = req.temperature
-            self._topks[s] = req.top_k if req.top_k else self.vocab
-            self._keys[s] = np.asarray(key, np.uint32)
-            self._m_admitted.inc()
-            active = sum(1 for r in self._slot_req if r is not None)
-            self._peak_active = max(self._peak_active, active)
-            self._m_active.set(active)
-        self._push_first(req, tok0)
-        # a 1-token request (or instant EOS) finishes without ever decoding
-        self._maybe_finish(s)
-
     def _maybe_finish(self, s: int) -> None:
         with self._cond:
             req = self._slot_req[s]
@@ -1424,7 +1097,7 @@ class ContinuousBatcher:
             if not done:
                 return
             self._slot_req[s] = None
-            if self.kv == "paged" and self._slot_pages[s] is not None:
+            if self._slot_pages[s] is not None:
                 # copy-free retirement: blocks drop one reference (cached/
                 # shared ones survive in their other holders) and the table
                 # row zeroes (points at trash) — no device work
@@ -1438,27 +1111,8 @@ class ContinuousBatcher:
             self._m_active.set(sum(1 for r in self._slot_req if r is not None))
         req._finish(req.cancelled)
 
-    def _copy_blocks(self, pairs: List[tuple]) -> None:
-        """Copy-on-write device work: duplicate each ``(src, dst)`` block
-        row in every layer's K/V pool. Eager indexed updates — deliberately
-        NOT a jit site, so the committed compile-surface budget (decode ==
-        one executable) is untouched; the indices ride as device operands,
-        so XLA's eager cache reuses one executable per pool shape."""
-        import jax.numpy as jnp
-
-        src = jnp.asarray(np.fromiter((p[0] for p in pairs), np.int32,
-                                      len(pairs)))
-        dst = jnp.asarray(np.fromiter((p[1] for p in pairs), np.int32,
-                                      len(pairs)))
-        for lk in self._lks:
-            pool = self._pools[lk]
-            pool["k"] = pool["k"].at[dst].set(pool["k"][src])
-            pool["v"] = pool["v"].at[dst].set(pool["v"][src])
-
     def _tick(self, snap, epoch: int) -> None:
         """Decode one token for every slot; bookkeep the active ones."""
-        import jax.numpy as jnp
-
         # chaos seam, deliberately BEFORE any device dispatch or pool
         # mutation: an injected error/hang here simulates a wedged or dying
         # decode step without ever corrupting donated buffers
@@ -1475,39 +1129,36 @@ class ContinuousBatcher:
                               if self._slot_req[s] is not None]
                     if not active:
                         return
-                    if self.kv == "paged":
-                        # grow lazily to cover the token this tick writes;
-                        # the admission-time worst-case commitment guarantees
-                        # success
-                        cow: List[tuple] = []
-                        for s in active:
-                            pages = self._slot_pages[s]
-                            pages.ensure(int(self._pos[s]) + 1)
-                            wb = int(self._pos[s]) // self.block_size
-                            blk = pages.blocks[wb]
-                            if self._alloc.refcount(blk) > 1:
-                                # copy-on-write: someone else (a fork peer)
-                                # still references the block this tick writes
-                                # — swap in a private copy first. Only ever
-                                # the partial tail: whole shared blocks are
-                                # never write targets.
-                                new = self._alloc.alloc(1)[0]
-                                if blk in pages.shared:
-                                    self._ledger_drop([blk])
-                                pages.swap(wb, new)
-                                cow.append((blk, new))
-                                self._cow_copies += 1
-                                self._m_cow.inc()
-                            self._write_table_row(s, pages.blocks)
-                        self._update_kv_gauges()
-                        mask = np.zeros(self.slots, bool)
-                        mask[active] = True
-                        # inactive rows: zero tables (writes -> trash),
-                        # position 0
-                        tables = np.where(mask[:, None], self._tables_np, 0)
-                        pos = np.where(mask, self._pos, 0).astype(np.int32)
-                    else:
-                        pos = np.array(self._pos)
+                    # grow lazily to cover the token this tick writes;
+                    # the admission-time worst-case commitment guarantees
+                    # success
+                    cow: List[tuple] = []
+                    for s in active:
+                        pages = self._slot_pages[s]
+                        pages.ensure(int(self._pos[s]) + 1)
+                        wb = int(self._pos[s]) // self.block_size
+                        blk = pages.blocks[wb]
+                        if self._alloc.refcount(blk) > 1:
+                            # copy-on-write: someone else (a fork peer)
+                            # still references the block this tick writes
+                            # — swap in a private copy first. Only ever
+                            # the partial tail: whole shared blocks are
+                            # never write targets.
+                            new = self._alloc.alloc(1)[0]
+                            if blk in pages.shared:
+                                self._ledger_drop([blk])
+                            pages.swap(wb, new)
+                            cow.append((blk, new))
+                            self._cow_copies += 1
+                            self._m_cow.inc()
+                        self._write_table_row(s, pages.blocks)
+                    self._update_kv_gauges()
+                    mask = np.zeros(self.slots, bool)
+                    mask[active] = True
+                    # inactive rows: zero tables (writes -> trash),
+                    # position 0
+                    tables = np.where(mask[:, None], self._tables_np, 0)
+                    pos = np.where(mask, self._pos, 0).astype(np.int32)
                     toks = np.array(self._next_tok)
                     temps = np.array(self._temps)
                     topks = np.array(self._topks)
@@ -1520,29 +1171,21 @@ class ContinuousBatcher:
             t0 = time.perf_counter()
             with _trace.span(_trace.GEN_TICK_DISPATCH):
                 params = self._params_for(snap)
-                if self.kv == "paged" and cow:
+                if cow:
                     # device-side CoW copies, outside the lock (pools are
                     # only ever touched by this worker thread), before the
                     # decode dispatch
-                    self._copy_blocks(cow)
-                if self.kv == "paged":
-                    nxt, self._pools, new_keys = self._decode(
-                        params, snap.state, jnp.asarray(toks), self._pools,
-                        jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(keys),
-                        jnp.asarray(temps), jnp.asarray(topks))
-                else:
-                    nxt, caches, new_keys = self._decode(
-                        params, snap.state, jnp.asarray(toks), self._caches,
-                        jnp.asarray(pos), jnp.asarray(keys), jnp.asarray(temps),
-                        jnp.asarray(topks))
-                    self._caches = caches
+                    self._programs.copy_blocks(cow)
+                nxt, new_keys = self._programs.decode(
+                    params, snap.state, toks, tables, pos, keys, temps, topks)
             with _trace.span(_trace.GEN_TICK_READBACK):
                 nxt_np = np.asarray(nxt)
                 keys_np = np.asarray(new_keys, np.uint32)
             t1 = time.perf_counter()
             with _trace.span(_trace.GEN_TICK_PUBLISH):
-                if self._routed:
-                    self._count_routing("decode", nxt_np[self.slots:])
+                if self._programs.routed:
+                    self._count_routing(
+                        "decode", self._programs.decode_routing(nxt_np))
                     self._count_chunks_routing()
                 self._m_decode_s.observe(t1 - t0)
                 self._m_occupancy.observe(len(active) / self.slots)
@@ -1581,15 +1224,14 @@ class ContinuousBatcher:
         *fields, programs = self._m_routing[program]
         for counter, v in zip(fields, sums):
             counter.inc(int(v))
-        programs.inc(self._routed)
+        programs.inc(self._programs.routed)
 
     def _count_chunks_routing(self) -> None:
         """The sums of the prefill chunks run since the last call. Called
         only behind a readback of something the device computed after them
         (a tick's tokens, a first token), so reading them waits for nothing."""
-        for sums in self._routing_pending:
-            self._count_routing("prefill", np.asarray(sums))
-        self._routing_pending = []
+        for sums in self._programs.chunk_routing():
+            self._count_routing("prefill", sums)
 
     def _loop(self, epoch: int) -> None:
         try:
@@ -1618,9 +1260,7 @@ class ContinuousBatcher:
                 # has its own lock): keys prefix-cache adoption, so a publish
                 # flushes stale runs at the next admission
                 cur = self.registry.current()
-                gen = (cur.generation
-                       if self.kv == "paged" and self._prefix is not None
-                       else 0)
+                gen = cur.generation if self._prefix is not None else 0
                 # no lease is held here: an idle server, too, lets go of
                 # the copy a publish retired
                 self._params_for(cur)
@@ -1634,18 +1274,13 @@ class ContinuousBatcher:
                     if idle and self._closing:
                         return
                     if not idle:
-                        admits = self._admit_locked(gen)
-                        # dense admits are popped from the queue but not yet
-                        # in a slot: track them so a restart can still
-                        # answer them
-                        self._admitting = [r for _, r in admits]
+                        self._admit_locked(gen)
                         self._m_qdepth.set(len(self._queue))
                         jobs = list(self._jobs)
                         decoding = any(r is not None for r in self._slot_req)
                 if not idle:
                     now = time.perf_counter()
-                    plan = (self.scheduler.plan(jobs, decoding)
-                            if self.kv == "paged" else [])
+                    plan = self.scheduler.plan(jobs, decoding)
             if idle:
                 # nothing to do: sleep outside the span (waiting for work is
                 # not admission), after a second look under the lock
@@ -1659,51 +1294,29 @@ class ContinuousBatcher:
             # step's device arrays freed as _tick returns (which lends the
             # interpreter lock to the stream writers the tick just woke)
             with _trace.span(_trace.GEN_TURN):
-                if self.kv == "paged":
-                    for job in plan:
-                        if job.req.cancelled is not None:
-                            # consumer vanished mid-prefill: abort here, where
-                            # no device call holds the job's table row
-                            self._abort_job(job, job.req.cancelled)
-                            continue
-                        if job.idx == 0 and job.req.deadline is not None \
-                                and now > job.req.deadline:
-                            self._abort_job(job, DeadlineExceededError(
-                                "deadline exceeded waiting for a decode slot"))
-                            continue
-                        try:
-                            # one lease per chunk: hot-swap drains at chunk
-                            # granularity, not whole-prompt granularity
-                            with self.registry.lease(tag="gen_prefill") as snap:
-                                self._prefill_step(job, snap)
-                        except ServeError as e:
-                            self._abort_job(job, e)
-                        except Exception as e:  # slot loop must outlive any bad request  # jaxlint: disable=broad-except
-                            self._abort_job(job,
-                                            ServeError(f"{type(e).__name__}: {e}"))
-                    with self.registry.lease(tag="gen_decode") as snap:
-                        self._tick(snap, epoch)
-                else:
-                    with self.registry.lease(tag="gen_decode") as snap:
-                        for s, req in admits:
-                            if req.event.is_set():
-                                continue  # already shed by a racing restart
-                            if req.cancelled is not None:
-                                req._finish(req.cancelled)
-                                continue
-                            if req.deadline is not None and now > req.deadline:
-                                req._finish(DeadlineExceededError(
-                                    "deadline exceeded waiting for a decode slot"))
-                                continue
-                            try:
-                                self._admit_into_slot(s, req, snap)
-                            except ServeError as e:
-                                req._finish(e)
-                            except Exception as e:  # slot loop must outlive any bad request  # jaxlint: disable=broad-except
-                                req._finish(ServeError(f"{type(e).__name__}: {e}"))
-                        with self._cond:
-                            self._admitting = []
-                        self._tick(snap, epoch)
+                for job in plan:
+                    if job.req.cancelled is not None:
+                        # consumer vanished mid-prefill: abort here, where
+                        # no device call holds the job's table row
+                        self._abort_job(job, job.req.cancelled)
+                        continue
+                    if job.idx == 0 and job.req.deadline is not None \
+                            and now > job.req.deadline:
+                        self._abort_job(job, DeadlineExceededError(
+                            "deadline exceeded waiting for a decode slot"))
+                        continue
+                    try:
+                        # one lease per chunk: hot-swap drains at chunk
+                        # granularity, not whole-prompt granularity
+                        with self.registry.lease(tag="gen_prefill") as snap:
+                            self._prefill_step(job, snap)
+                    except ServeError as e:
+                        self._abort_job(job, e)
+                    except Exception as e:  # slot loop must outlive any bad request  # jaxlint: disable=broad-except
+                        self._abort_job(job,
+                                        ServeError(f"{type(e).__name__}: {e}"))
+                with self.registry.lease(tag="gen_decode") as snap:
+                    self._tick(snap, epoch)
 
     # ------------------------------------------------- watchdog + crash-only
     def heartbeat(self) -> float:
@@ -1716,11 +1329,10 @@ class ContinuousBatcher:
     def _shed_inflight_locked(self, include_queue: bool
                               ) -> List[_GenRequest]:
         """Under ``self._cond``: strip every in-flight sequence (slots,
-        prefill jobs, dense mid-admission — plus the queue when asked) out
-        of the batcher state, releasing KV pages, and return the orphaned
-        requests for the caller to finish OUTSIDE the lock."""
-        finish: List[_GenRequest] = list(self._admitting)
-        self._admitting = []
+        prefill jobs — plus the queue when asked) out of the batcher state,
+        releasing KV pages, and return the orphaned requests for the caller
+        to finish OUTSIDE the lock."""
+        finish: List[_GenRequest] = []
         if include_queue:
             finish.extend(self._queue)
             self._queue.clear()
@@ -1734,15 +1346,14 @@ class ContinuousBatcher:
             if req is not None:
                 finish.append(req)
                 self._slot_req[s] = None
-            if self.kv == "paged" and self._slot_pages[s] is not None:
+            if self._slot_pages[s] is not None:
                 self._release_pages(self._slot_pages[s])
                 self._slot_pages[s] = None
                 self._committed -= int(self._slot_worst[s])
                 self._slot_worst[s] = 0
-        if self.kv == "paged":
-            self._tables_np[:] = 0
-            self._update_kv_gauges()
-            self._m_pf_depth.set(0)
+        self._tables_np[:] = 0
+        self._update_kv_gauges()
+        self._m_pf_depth.set(0)
         self._m_qdepth.set(len(self._queue))
         self._m_active.set(0)
         return finish
@@ -1775,7 +1386,7 @@ class ContinuousBatcher:
         """Tag -> :class:`~..aot.AotFunction` for every store-backed
         generation executable ({} without a store) — how a prebuild run
         gathers the concrete keys for the coverage record."""
-        return dict(self._aot_fns)
+        return self._programs.aot_functions()
 
     # -------------------------------------------------------------- lifecycle
     @property
@@ -1789,10 +1400,8 @@ class ContinuousBatcher:
             return self._peak_active
 
     def kv_block_stats(self) -> dict:
-        """Allocator snapshot (paged mode): totals, usage, live bytes, and
-        the sharing picture (prefix cache + shared blocks + CoW/forks)."""
-        if self.kv != "paged":
-            return {}
+        """Allocator snapshot: totals, usage, live bytes, and the sharing
+        picture (prefix cache + shared blocks + CoW/forks)."""
         with self._cond:
             used = self._alloc.used
             out = {"block_size": self.block_size,
@@ -1815,7 +1424,7 @@ class ContinuousBatcher:
         """Release every cached prefix run (admin/testing: proves cached
         blocks are the only thing keeping ``blocks_used`` nonzero after a
         drain). Returns the number of entries dropped."""
-        if self.kv != "paged" or self._prefix is None:
+        if self._prefix is None:
             return 0
         with self._cond:
             n = self._prefix.flush()

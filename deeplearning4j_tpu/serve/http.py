@@ -158,7 +158,7 @@ class ModelServer(JsonHTTPServerMixin):
                  default_timeout_ms: Optional[float] = None,
                  input_dtype=np.float32, gen_slots: int = 4,
                  gen_capacity: int = 256, gen_queue_limit: int = 64,
-                 gen_kv: str = "paged", gen_block_size: int = 16,
+                 gen_block_size: int = 16,
                  gen_kv_blocks: Optional[int] = None,
                  gen_prefix_cache: bool = True,
                  gen_prefix_cache_blocks: Optional[int] = None,
@@ -223,7 +223,7 @@ class ModelServer(JsonHTTPServerMixin):
             # a replica missing executables never starts listening
             self.engine.warm(input_dtype)
         self._gen_opts = dict(slots=gen_slots, capacity=gen_capacity,
-                              queue_limit=gen_queue_limit, kv=gen_kv,
+                              queue_limit=gen_queue_limit,
                               block_size=gen_block_size,
                               kv_blocks=gen_kv_blocks,
                               prefix_cache=gen_prefix_cache,
@@ -231,11 +231,6 @@ class ModelServer(JsonHTTPServerMixin):
                               prefill_chunk=gen_prefill_chunk, seed=seed,
                               aot_store=aot_store,
                               strict_aot=self.strict_aot)
-        if gen_kv == "dense":
-            # dense batcher takes no paging knobs
-            for k in ("block_size", "kv_blocks", "prefill_chunk",
-                      "prefix_cache", "prefix_cache_blocks"):
-                self._gen_opts.pop(k)
         self._batcher: Optional[ContinuousBatcher] = None
         self._lifecycle_lock = threading.Lock()
         self._accepting = True
@@ -362,11 +357,11 @@ class ModelServer(JsonHTTPServerMixin):
                                     for g, v in server.registry.history()]}
                     if server.aot_store is not None:
                         body["aot_store"] = server.aot_store.stats()
-                    # KV sharing picture (paged batcher, once built):
+                    # KV sharing picture (once the batcher is built):
                     # block usage + prefix-cache hits/entries + CoW/forks
                     with server._lifecycle_lock:
                         b = server._batcher
-                    if b is not None and b.kv == "paged":
+                    if b is not None:
                         body["kv"] = b.kv_block_stats()
                     self.reply(200, body)
                 elif self.path == "/v1/debug/requests":

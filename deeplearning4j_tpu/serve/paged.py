@@ -150,10 +150,6 @@ class BlockAllocator:
             else:
                 self._refs[b] = c - 1
 
-    def free(self, ids) -> None:
-        """Alias of :meth:`release` (the pre-refcount name)."""
-        self.release(ids)
-
 
 def build_pools(model, num_blocks: int, block_size: int, dtype) -> Dict:
     """Zero-filled per-attention-layer block pools:

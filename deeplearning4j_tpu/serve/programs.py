@@ -1,0 +1,283 @@
+"""The device programs of the generation loop.
+
+``serve/continuous.py`` is the scheduler: requests, slots, block tables,
+admission, params generations, the tick. This module is what a served
+program IS, and everything only the programs need to know:
+
+- the three traced functions — ``_sample_dynamic``, ``_prefill_chunk_fn``,
+  ``_decode_paged_fn``; a device trace names its programs after them
+  (``jit__decode_paged_fn``), so the names are part of the measurement;
+- the KV pools they carry from call to call (donated every step), and the
+  format between the pools and ``decode_forward``'s per-layer cache
+  dictionaries (the layout contract is in ``nn/generation.py``);
+- how a model with expert layers reports what routing did: which rows are
+  live, and the three sums that ride a decode step's tokens as ``(S + 3,)``;
+- the persistent-store wrapping under the tags ``gen_sample``,
+  ``gen_prefill_chunk``, ``gen_decode_paged``;
+- each program's operand list, written down once as abstract shapes
+  (:meth:`GenPrograms.signatures`) beside the one call that passes it, so
+  that warming a params generation is one call (:meth:`GenPrograms.warm`).
+
+The scheduler hands over host values (a slot vector, a table, a prompt
+chunk) and gets device values back; what it reads back, and when, stays
+its decision.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, List, Sequence
+
+import numpy as np
+
+from .paged import build_pools
+
+
+class GenPrograms:
+    """The sampler, the prefill-chunk program and the decode step of one
+    batcher, over ``slots`` rows, block tables ``table_blocks`` wide and
+    pools of ``kv_blocks`` blocks. ``store`` (an ``AotStore``) makes every
+    executable load from disk before it traces, ``strict`` refuses to
+    trace at all; ``snapshot`` is the registry snapshot whose architecture
+    keys the store."""
+
+    def __init__(self, model, *, slots: int, table_blocks: int, vocab: int,
+                 kv_blocks: int, block_size: int,
+                 chunk_buckets: Sequence[int], metrics, compile_counter,
+                 store=None, strict: bool = False, snapshot=None):
+        import jax
+        import jax.numpy as jnp
+
+        from ..nn.generation import (cache_spec, decode_forward,
+                                     says_how_it_decodes)
+        from ..nn.model import _layer_key
+
+        self.slots = int(slots)
+        self.table_blocks = int(table_blocks)
+        self.vocab = int(vocab)
+        self.chunk_buckets = tuple(chunk_buckets)
+        S, V = self.slots, self.vocab
+        mdl = model
+
+        def _sample_dynamic(logits, key, temperature, top_k):
+            """Fully-traced sampler: temperature 0 -> greedy, top_k as a
+            dynamic scalar (top_k == V disables the restriction)."""
+            with jax.named_scope("sample"):  # its name in a device trace
+                greedy = jnp.argmax(logits, axis=-1)
+                t = jnp.maximum(temperature, 1e-6)
+                scaled = logits / t
+                srt = jnp.sort(scaled, axis=-1)  # ascending
+                k = jnp.clip(top_k, 1, V)
+                kth = jnp.take(srt, V - k, axis=-1)
+                masked = jnp.where(scaled >= kth, scaled, -1e30)
+                samp = jax.random.categorical(key, masked, axis=-1)
+                return jnp.where(temperature <= 0.0, greedy,
+                                 samp).astype(jnp.int32)
+
+        self.pools = build_pools(mdl, kv_blocks, block_size, mdl.dtype)
+        self._lks = [lk for lk, _, _ in cache_spec(mdl)]
+        lks = self._lks
+        # layers with experts (layers/olmoe.py) report what routing did
+        # to the rows marked live; the sums leave each program as three
+        # int32 (nn.layers.olmoe.ROUTING_FIELDS). A model without such
+        # layers builds the programs it always built
+        routed = [_layer_key(i, layer)
+                  for i, layer in enumerate(mdl.layers)
+                  if says_how_it_decodes(layer)
+                  and getattr(layer, "num_experts", 0)]
+        #: expert layers a program runs; 0 for a model without experts
+        self.routed = len(routed)
+        # a chunk's sums stay on the device until the scheduler reads
+        # something computed behind them (chunk_routing)
+        self._routing_pending: List[Any] = []
+
+        def _as_caches(pools, tables, live=None):
+            caches = {lk: {"k_pool": pools[lk]["k"],
+                           "v_pool": pools[lk]["v"],
+                           "tables": tables} for lk in lks}
+            for lk in routed:
+                caches[lk]["live"] = live
+            return caches
+
+        def _routing(caches):
+            return sum(caches[lk]["routing"] for lk in routed)
+
+        def _as_pools(caches):
+            return {lk: {"k": caches[lk]["k_pool"],
+                         "v": caches[lk]["v_pool"]} for lk in lks}
+
+        def _prefill_chunk_fn(params, state, ids, pools, table_row, pos,
+                              true_len):
+            """One prompt chunk for one slot. ``ids`` (1, Tb)
+            right-padded; ``pos`` (1,) chunk offset; pad garbage writes
+            past the row's blocks land in the trash block. Logits are
+            gathered at the last REAL token of the chunk."""
+            live = (jnp.arange(ids.shape[1]) < true_len)[None] \
+                if routed else None
+            lg, caches = decode_forward(
+                mdl, params, state, ids,
+                _as_caches(pools, table_row, live), pos)
+            last = jnp.take(lg, true_len - 1, axis=1)  # (1, V)
+            if routed:
+                return last, _as_pools(caches), _routing(caches)
+            return last, _as_pools(caches)
+
+        def _decode_paged_fn(params, state, toks, pools, tables, pos,
+                             keys, temps, tks):
+            """One token for every slot, batched over the slot axis
+            against the shared pools — ONE executable for the server's
+            lifetime (tables/pos are traced operands). Inactive slots
+            carry zeroed table rows, so their writes land in the trash
+            block and their sampled garbage is discarded host-side."""
+            # a live slot's first block is never the trash block
+            live = (tables[:, :1] != 0) if routed else None
+            lg, caches = decode_forward(
+                mdl, params, state, toks[:, None].astype(jnp.int32),
+                _as_caches(pools, tables, live), pos)
+
+            def one(l, key, temp, tk):
+                key, sub = jax.random.split(key)
+                return _sample_dynamic(l, sub, temp, tk), key
+
+            nxt, new_keys = jax.vmap(one)(lg[:, 0], keys, temps, tks)
+            if routed:
+                # the three sums ride the tokens' readback: (S + 3,)
+                nxt = jnp.concatenate([nxt, _routing(caches)])
+            return nxt, _as_pools(caches), new_keys
+
+        self._sample = jax.jit(_sample_dynamic)
+        # pools are the loop-carried buffers: donated every step
+        self._prefill_chunk = jax.jit(_prefill_chunk_fn, donate_argnums=(3,))
+        self._decode = jax.jit(_decode_paged_fn, donate_argnums=(3,))
+
+        self._pools_sig = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.pools)
+        self._aot_fns: Dict[str, Any] = {}
+        if store is not None:
+            from ..aot import AotFunction, arch_fingerprint
+
+            arch = arch_fingerprint(snapshot.params, snapshot.state)
+
+            def _wrap(fn, tag, donate=()):
+                wrapped = AotFunction(
+                    fn, tag=tag, store=store, metrics=metrics, arch=arch,
+                    component="generate", donate_argnums=donate,
+                    compile_counter=compile_counter, strict=strict)
+                self._aot_fns[tag] = wrapped
+                return wrapped
+
+            self._sample = _wrap(self._sample, "gen_sample")
+            self._prefill_chunk = _wrap(self._prefill_chunk,
+                                        "gen_prefill_chunk", (3,))
+            self._decode = _wrap(self._decode, "gen_decode_paged", (3,))
+
+    # ------------------------------------------------- operands, written once
+    def signatures(self, params, state) -> Dict[str, List[tuple]]:
+        """Store tag -> the positional operand list of each of its
+        executables, as abstract shapes, for one params generation
+        (``params`` as the programs read them: the compute-dtype copy).
+        The three calls below pass exactly these; a test holds the two
+        together through a strict store."""
+        import jax
+
+        S, B, V = self.slots, self.table_blocks, self.vocab
+        sds = jax.ShapeDtypeStruct
+        i32, f32, u32 = np.int32, np.float32, np.uint32
+        pools = self._pools_sig
+        return {
+            "gen_sample": [(sds((V,), f32), sds((2,), u32), sds((), f32),
+                            sds((), i32))],
+            "gen_decode_paged": [(params, state, sds((S,), i32), pools,
+                                  sds((S, B), i32), sds((S,), i32),
+                                  sds((S, 2), u32), sds((S,), f32),
+                                  sds((S,), i32))],
+            "gen_prefill_chunk": [(params, state, sds((1, b), i32), pools,
+                                   sds((1, B), i32), sds((1,), i32),
+                                   sds((), i32))
+                                  for b in self.chunk_buckets],
+        }
+
+    def warm(self, params, state) -> None:
+        """With a store, load-or-compile the full static executable set —
+        the sampler, the lifetime decode step, every prefill bucket — for
+        one params generation, from abstract shapes: nothing executes,
+        nothing is donated. Without a store there is nothing to do."""
+        if not self._aot_fns:
+            return
+        for tag, lists in self.signatures(params, state).items():
+            for operands in lists:
+                self._aot_fns[tag].warm(*operands)
+
+    def sample(self, logits, key, temperature: float, top_k: int):
+        """One token (a device scalar) from one row of logits."""
+        return self._sample(logits, key, np.float32(temperature),
+                            np.int32(top_k))
+
+    def prefill_chunk(self, params, state, tokens: np.ndarray, bucket: int,
+                      table_row: np.ndarray, off: int):
+        """Run ``tokens``, a prompt chunk at offset ``off`` right-padded to
+        ``bucket``, through the slot whose table row is ``table_row``
+        ``(1, table_blocks)``. Returns the logits (1, V) at its last real
+        token, on the device."""
+        import jax.numpy as jnp
+
+        true_len = tokens.shape[0]
+        ids = np.zeros((1, bucket), np.int32)
+        # bucket is a member of chunk_buckets: the scheduler's _plan_chunks
+        # emits no other width
+        ids[0, :true_len] = tokens  # jaxlint: shape=ids:(1, bucket(_chunk_buckets))
+        last, self.pools, *routing = self._prefill_chunk(
+            params, state, jnp.asarray(ids), self.pools,
+            jnp.asarray(table_row), np.full((1,), off, np.int32),
+            np.int32(true_len))
+        self._routing_pending += routing
+        return last
+
+    def decode(self, params, state, toks, tables, pos, keys, temps, tks):
+        """One token for every slot, from the host's slot vectors. Returns
+        the device values ``(next, keys)``: ``next`` holds the ``slots``
+        tokens (and behind them what :meth:`decode_routing` reads)."""
+        import jax.numpy as jnp
+
+        nxt, self.pools, new_keys = self._decode(
+            params, state, jnp.asarray(toks), self.pools,
+            jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(keys),
+            jnp.asarray(temps), jnp.asarray(tks))
+        return nxt, new_keys
+
+    # ------------------------------------------------------------- routing
+    def decode_routing(self, nxt: np.ndarray) -> np.ndarray:
+        """The routing sums (ROUTING_FIELDS) of the decode step whose
+        ``next`` was read back as ``nxt``; only for a model with experts."""
+        return nxt[self.slots:]
+
+    def chunk_routing(self) -> List[np.ndarray]:
+        """The sums of the prefill chunks run since the last call. Call it
+        only behind a readback of something the device computed after them
+        (a tick's tokens, a first token), so reading them waits for
+        nothing."""
+        sums = [np.asarray(s) for s in self._routing_pending]
+        self._routing_pending = []
+        return sums
+
+    # ---------------------------------------------------------------- pools
+    def copy_blocks(self, pairs: List[tuple]) -> None:
+        """Copy-on-write device work: duplicate each ``(src, dst)`` block
+        row in every layer's K/V pool. Eager indexed updates — deliberately
+        NOT a jit site, so the committed compile-surface budget (decode ==
+        one executable) is untouched; the indices ride as device operands,
+        so XLA's eager cache reuses one executable per pool shape."""
+        import jax.numpy as jnp
+
+        src = jnp.asarray(np.fromiter((p[0] for p in pairs), np.int32,
+                                      len(pairs)))
+        dst = jnp.asarray(np.fromiter((p[1] for p in pairs), np.int32,
+                                      len(pairs)))
+        for lk in self._lks:
+            pool = self.pools[lk]
+            pool["k"] = pool["k"].at[dst].set(pool["k"][src])
+            pool["v"] = pool["v"].at[dst].set(pool["v"][src])
+
+    def aot_functions(self) -> dict:
+        """Tag -> :class:`~..aot.AotFunction` for every store-backed
+        executable ({} without a store)."""
+        return dict(self._aot_fns)

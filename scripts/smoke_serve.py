@@ -309,7 +309,7 @@ def _strict_prebuilt_scenario(out_dir):
             model, port=0, input_dtype=np.dtype(config["dtype"]),
             batch_buckets=tuple(config["engine"]["batch_buckets"]),
             gen_slots=gen["slots"], gen_capacity=gen["capacity"],
-            gen_kv=gen["kv"], gen_block_size=gen["block_size"],
+            gen_block_size=gen["block_size"],
             gen_prefill_chunk=gen["prefill_chunk"], seed=gen["seed"],
             metrics=metrics, aot_store=AotStore(store_root),
             strict_aot=True, aot_manifest=manifest_path)

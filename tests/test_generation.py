@@ -13,17 +13,17 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.models import CausalLM, TextGenerationLSTM
-from deeplearning4j_tpu.nn.generation import (_decode_forward, _init_caches,
+from deeplearning4j_tpu.nn.generation import (decode_forward, init_caches,
                                               generate, sample_logits)
 
 
 def _stepwise_logits(model, prompt, capacity):
     """Feed tokens one at a time through the decode path; collect logits."""
-    caches = _init_caches(model, prompt.shape[0], capacity, model.dtype)
+    caches = init_caches(model, prompt.shape[0], capacity, model.dtype)
     outs = []
     for t in range(prompt.shape[1]):
         chunk = prompt[:, t:t + 1]
-        lg, caches = _decode_forward(model, model.params, model.state,
+        lg, caches = decode_forward(model, model.params, model.state,
                                      jnp.asarray(chunk), caches, t)
         outs.append(np.asarray(lg[:, 0]))
     return np.stack(outs, axis=1)  # (B, T, V)
@@ -43,8 +43,8 @@ class TestCausalLMDecode:
         return np.log(np.asarray(probs) + 1e-20)
 
     def test_prefill_matches_full_forward(self):
-        caches = _init_caches(self.model, 2, 16, self.model.dtype)
-        lg, _ = _decode_forward(self.model, self.model.params,
+        caches = init_caches(self.model, 2, 16, self.model.dtype)
+        lg, _ = decode_forward(self.model, self.model.params,
                                 self.model.state, jnp.asarray(self.prompt),
                                 caches, 0)
         got = np.asarray(jax.nn.log_softmax(lg, axis=-1))
@@ -107,6 +107,37 @@ class TestCausalLMDecode:
                     num_heads=l.num_heads, causal=True)
         with pytest.raises(ValueError, match="GlobalPooling"):
             generate(bert, ids, 3)
+
+    def test_one_contract_for_generate_and_the_batcher(self):
+        """``check_decodes`` is both decode loops' gate: it names the
+        context it was asked about, returns the vocabulary, and ``served``
+        adds the batcher's terms (embedding front, no carry, Output last)."""
+        from deeplearning4j_tpu.nn import layers as L
+        from deeplearning4j_tpu.nn.generation import check_decodes
+        from deeplearning4j_tpu.nn.model import NetConfig, SequentialBuilder
+
+        assert check_decodes(self.model, 16, "prompt+new tokens") == 50
+        assert check_decodes(self.model, 512, "cache capacity",
+                             served=True) == 50
+        with pytest.raises(ValueError, match="shorter than cache capacity 513"):
+            check_decodes(self.model, 513, "cache capacity", served=True)
+        rnn = (SequentialBuilder(NetConfig(seed=0)).input_shape(8)
+               .layer(L.EmbeddingSequence(n_in=50, n_out=16))
+               .layer(L.LSTM(n_out=16))
+               .layer(L.RnnOutput(n_out=50)).build())
+        assert check_decodes(rnn, 8, "prompt+new tokens") == 50
+        with pytest.raises(ValueError, match="recurrent carries"):
+            check_decodes(rnn, 8, "cache capacity", served=True)
+        chars = TextGenerationLSTM(seed=0, input_shape=(8, 20),
+                                   hidden=16).build()
+        with pytest.raises(ValueError, match="embedding-front"):
+            check_decodes(chars, 8, "cache capacity", served=True)
+        mid = (SequentialBuilder(NetConfig(seed=0)).input_shape(8)
+               .layer(L.EmbeddingSequence(n_in=50, n_out=16))
+               .layer(L.RnnOutput(n_out=16))
+               .layer(L.RnnOutput(n_out=50)).build())
+        with pytest.raises(ValueError, match="layer 1 RnnOutput does not say"):
+            check_decodes(mid, 8, "prompt+new tokens")
 
     def test_repeated_calls_reuse_compiled_program(self):
         a = generate(self.model, self.prompt, 3, temperature=0.0)
@@ -249,9 +280,9 @@ class TestGQADecode:
         np.testing.assert_allclose(got, want, atol=1e-4)
 
     def test_cache_is_kv_head_sized(self):
-        from deeplearning4j_tpu.nn.generation import _init_caches
+        from deeplearning4j_tpu.nn.generation import init_caches
         model = self._build(1)  # MQA
-        caches = _init_caches(model, 2, 16, model.dtype)
+        caches = init_caches(model, 2, 16, model.dtype)
         shapes = {tuple(c["k"].shape) for c in caches.values()
                   if isinstance(c, dict) and "k" in c}
         assert shapes == {(2, 16, 1, 8)}  # 1 kv head, hd=8 — 4x smaller

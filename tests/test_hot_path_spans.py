@@ -284,15 +284,19 @@ def test_trace_annotation_lives_behind_the_one_seam():
 
 
 # ------------------------------------------ (c) counters at the same boundaries
-@pytest.mark.parametrize("kv", ["paged", "dense"])
+@pytest.mark.parametrize("prefill_chunk", [4, None],
+                         ids=["chunks-of-4", "whole-prompt"])
 @pytest.mark.parametrize("traced", [False, True], ids=["plain", "reqtrace"])
-def test_queue_and_first_token_counted_once_per_admitted_request(kv, traced):
+def test_queue_and_first_token_counted_once_per_admitted_request(
+        prefill_chunk, traced):
+    """Both prefill modes: a prompt of up to three chunks closes its queue
+    wait at the first one only, and a whole-prompt prefill at its one."""
     from deeplearning4j_tpu.serve import ContinuousBatcher
 
     reg = MetricsRegistry()
     rt = RequestTracer(tracer=Tracer()) if traced else None
-    cb = ContinuousBatcher(_lm(), slots=2, capacity=32, seed=0, kv=kv,
-                           metrics=reg)
+    cb = ContinuousBatcher(_lm(), slots=2, capacity=32, seed=0,
+                           prefill_chunk=prefill_chunk, metrics=reg)
     rng = np.random.RandomState(1)
     try:
         ctxs = [rt.begin("generate") if rt else None for _ in range(5)]
@@ -398,11 +402,12 @@ def decode_program_text():
 
     lm = _lm()
     lm.config.compute_dtype = "bfloat16"
-    cb = ContinuousBatcher(lm, slots=2, capacity=16, seed=0, kv="paged")
+    cb = ContinuousBatcher(lm, slots=2, capacity=16, seed=0)
     try:
         snap = cb.registry.current()
-        return cb._decode.lower(
-            snap.params, snap.state, jnp.zeros((2,), jnp.int32), cb._pools,
+        return cb._programs._decode.lower(
+            snap.params, snap.state, jnp.zeros((2,), jnp.int32),
+            cb._programs.pools,
             jnp.asarray(cb._tables_np), jnp.zeros((2,), jnp.int32),
             jnp.asarray(cb._keys), jnp.asarray(cb._temps),
             jnp.asarray(cb._topks)).as_text(debug_info=True)

@@ -1268,7 +1268,7 @@ class TestAcquireRelease:
             def alloc(self, n):
                 return list(range(n))
 
-            def free(self, blocks):
+            def release(self, blocks):
                 pass
         """
 
@@ -1286,7 +1286,7 @@ class TestAcquireRelease:
                 def use(self, n):
                     blocks = self.alloc.alloc(n)
                     self.compute(n)
-                    self.alloc.free(blocks)
+                    self.alloc.release(blocks)
             """, "acquire-release")
         assert names(fs) == ["acquire-release"]
         assert "leaks if" in fs[0].message and "blocks" in fs[0].message
@@ -1305,7 +1305,7 @@ class TestAcquireRelease:
                     try:
                         self.compute(n)
                     finally:
-                        self.alloc.free(blocks)
+                        self.alloc.release(blocks)
             """, "acquire-release")
         assert fs == []
 

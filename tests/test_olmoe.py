@@ -187,9 +187,10 @@ def test_generate_and_decode_forward_dense_layout(dtype):
 
 
 def _batcher(m, **kw):
-    return ContinuousBatcher(m, slots=2, capacity=96, kv="paged",
-                             block_size=16, prefill_chunk=16,
-                             metrics=MetricsRegistry(), **kw)
+    opts = dict(slots=2, capacity=96, block_size=16, prefill_chunk=16,
+                metrics=MetricsRegistry())
+    opts.update(kw)
+    return ContinuousBatcher(m, **opts)
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
@@ -215,6 +216,89 @@ def test_batcher_paged_chunked_prefill_and_prefix_hit(dtype):
     for prompt, out in zip(prompts, outs):
         assert len(out) == 10
         served_gap(m.params, prompt, out, dtype)
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_batcher_fork_mid_decode_continues_as_the_parent(dtype):
+    """A fork shares the parent's blocks and its pending token; greedy, the
+    child's tokens are the parent's from the fork point on, through a
+    copy of the tail block both would have written."""
+    import time
+
+    from deeplearning4j_tpu.serve.errors import ServeError
+
+    m = build(dtype)
+    cb = _batcher(m, prefix_cache=False)
+    try:
+        cb.generate(tokens(5, seed=20), 2, temperature=0.0)  # compiled
+        real = cb._programs.decode
+
+        def slow(*a):       # so that the fork lands while the parent decodes
+            time.sleep(0.02)
+            return real(*a)
+
+        cb._programs.decode = slow
+        prompt = tokens(21, seed=21)
+        req = cb.submit(prompt, 14, temperature=0.0)
+        child = None
+        while child is None and not req.event.is_set():
+            try:
+                child = cb.fork(req)
+            except ServeError:
+                time.sleep(0)       # still queued or prefilling
+        out = req.wait()
+        assert child is not None, "the parent finished before a fork landed"
+        tail = child.wait()
+        stats = cb.kv_block_stats()
+    finally:
+        cb.shutdown()
+    assert 1 <= len(tail) <= 14 and stats["forks"] == 1
+    np.testing.assert_array_equal(tail, out[-len(tail):])
+    served_gap(m.params, prompt, out, dtype)
+    assert stats["blocks_used"] == 0 and stats["blocks_shared"] == 0
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_batcher_whole_prompt_prefill_equals_chunked(dtype):
+    """``prefill_chunk=None`` runs a prompt as one program padded to a
+    prompt bucket; chunked, the same prompt is three programs of 16. Both
+    serve the reference's tokens, and in f32 the same ones."""
+    m = build(dtype)
+    prompt = tokens(37, seed=22)
+    outs = []
+    for chunk in (16, None):
+        cb = _batcher(m, prefill_chunk=chunk)
+        try:
+            outs.append(cb.generate(prompt, 10, temperature=0.0))
+            chunks = _counter(cb.metrics.snapshot(),
+                              "serve_prefill_chunks_total")
+        finally:
+            cb.shutdown()
+        assert chunks == (3 if chunk else 1)
+        served_gap(m.params, prompt, outs[-1], dtype)
+    if dtype == "float32":
+        np.testing.assert_array_equal(outs[0], outs[1])
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_batcher_seeded_sampled_tokens_repeat(dtype):
+    """Sampling is the batcher's seed and the order of admission, nothing
+    else: a second batcher gives the first's tokens, another seed others."""
+    m = build(dtype)
+    prompts = [tokens(21, seed=23), tokens(9, seed=24)]
+
+    def run(seed):
+        cb = _batcher(m, seed=seed)
+        try:
+            return [cb.generate(p, 12, temperature=0.9, top_k=20)
+                    for p in prompts]
+        finally:
+            cb.shutdown()
+
+    first, again, other = run(0), run(0), run(1)
+    for a, b in zip(first, again):
+        np.testing.assert_array_equal(a, b)
+    assert any(not np.array_equal(a, b) for a, b in zip(first, other))
 
 
 def test_paged_decode_logits_match_the_reference():
@@ -336,4 +420,4 @@ def test_bf16_parameters_are_held_once():
     finally:
         cb.shutdown()
     assert _counter(snap, "serve_params_cast_total") == 0
-    assert {str(p["k"].dtype) for p in cb._pools.values()} == {"bfloat16"}
+    assert {str(p["k"].dtype) for p in cb._programs.pools.values()} == {"bfloat16"}

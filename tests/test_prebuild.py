@@ -81,7 +81,7 @@ class TestBucketParity:
         monkeypatch.setattr(ContinuousBatcher, "_warm_for",
                             lambda self, params, state: None)
         cb = ContinuousBatcher(_model(), slots=2, capacity=16,
-                               kv="paged", block_size=16, prefill_chunk=8,
+                               block_size=16, prefill_chunk=8,
                                seed=0, metrics=MetricsRegistry())
         try:
             assert chunk_buckets(cb.prompt_buckets, cb.prefill_chunk) == \
@@ -89,23 +89,6 @@ class TestBucketParity:
             tables = resolve_tables(CONFIG)
             assert tables["prompt_buckets"] == list(cb.prompt_buckets)
             assert tables["_chunk_buckets"] == list(cb._chunk_buckets)
-        finally:
-            cb.shutdown()
-
-    def test_dense_chunk_buckets_are_prompt_buckets(self, monkeypatch):
-        from deeplearning4j_tpu.serve import ContinuousBatcher
-
-        monkeypatch.setattr(ContinuousBatcher, "_warm_for",
-                            lambda self, params, state: None)
-        cb = ContinuousBatcher(_model(), slots=2, capacity=16, kv="dense",
-                               seed=0, metrics=MetricsRegistry())
-        try:
-            # dense prefill warms over the prompt buckets directly
-            dense_cfg = dict(CONFIG)
-            dense_cfg["gen"] = {**CONFIG["gen"], "kv": "dense"}
-            tables = resolve_tables(dense_cfg)
-            assert tables["_chunk_buckets"] == list(cb.prompt_buckets)
-            assert tables["prompt_buckets"] == list(cb.prompt_buckets)
         finally:
             cb.shutdown()
 
@@ -118,13 +101,11 @@ class TestBucketParity:
 _BUDGET = {"sites": {
     "deeplearning4j_tpu.serve.engine:fwd":
         {"bound": "|batch_buckets|*|length_buckets|", "why": "t"},
-    "deeplearning4j_tpu.serve.continuous:_decode_paged_fn":
+    "deeplearning4j_tpu.serve.programs:_decode_paged_fn":
         {"bound": "1", "why": "t"},
-    "deeplearning4j_tpu.serve.continuous:_prefill_chunk_fn":
+    "deeplearning4j_tpu.serve.programs:_prefill_chunk_fn":
         {"bound": "|_chunk_buckets|", "why": "t"},
-    "deeplearning4j_tpu.serve.continuous:_decode_step":
-        {"bound": "1", "why": "t"},
-    "deeplearning4j_tpu.serve.continuous:_sample_dynamic":
+    "deeplearning4j_tpu.serve.programs:_sample_dynamic":
         {"bound": "?", "why": "t"},
     "pkg.train:step": {"bound": "?", "why": "training-side"},
 }}
@@ -140,11 +121,10 @@ class TestEnumerate:
         report = _report([
             ("deeplearning4j_tpu.serve.engine:fwd",
              "|batch_buckets|*|length_buckets|"),
-            ("deeplearning4j_tpu.serve.continuous:_decode_paged_fn", "1"),
-            ("deeplearning4j_tpu.serve.continuous:_prefill_chunk_fn",
+            ("deeplearning4j_tpu.serve.programs:_decode_paged_fn", "1"),
+            ("deeplearning4j_tpu.serve.programs:_prefill_chunk_fn",
              "|_chunk_buckets|"),
-            ("deeplearning4j_tpu.serve.continuous:_decode_step", "1"),
-            ("deeplearning4j_tpu.serve.continuous:_sample_dynamic", "?"),
+            ("deeplearning4j_tpu.serve.programs:_sample_dynamic", "?"),
             ("pkg.train:step", "?"),
             ("pkg.other:helper", "1"),
         ])
@@ -162,12 +142,9 @@ class TestEnumerate:
             {"_chunk_buckets": 8}]
         assert manifest["total_signatures"] == 4 + 1 + 1
         reasons = {e["site"]: e["reason"] for e in manifest["excluded"]}
-        # dense-path site under a paged config never boots
-        assert "dense" in reasons[
-            "deeplearning4j_tpu.serve.continuous:_decode_step"]
         # a serving-tagged site whose bound the analysis could not close
         assert "not statically enumerable" in reasons[
-            "deeplearning4j_tpu.serve.continuous:_sample_dynamic"]
+            "deeplearning4j_tpu.serve.programs:_sample_dynamic"]
         assert "not a serving executable" in reasons["pkg.train:step"]
         assert "no budget entry" in reasons["pkg.other:helper"]
 
@@ -390,7 +367,7 @@ def _strict_server(store_dir, manifest=None, metrics=None):
         batch_buckets=tuple(CONFIG["engine"]["batch_buckets"]),
         input_dtype=np.dtype(CONFIG["dtype"]),
         gen_slots=gen["slots"], gen_capacity=gen["capacity"],
-        gen_kv=gen["kv"], gen_block_size=gen["block_size"],
+        gen_block_size=gen["block_size"],
         gen_prefill_chunk=gen["prefill_chunk"], seed=gen["seed"],
         metrics=metrics if metrics is not None else MetricsRegistry(),
         aot_store=AotStore(store_dir), strict_aot=True,

@@ -31,7 +31,6 @@ from deeplearning4j_tpu.serve.continuous import ContinuousBatcher
 from deeplearning4j_tpu.serve.errors import PublishError
 
 V = 50
-KVS = ["paged", "dense"]
 
 
 def _lm(compute_dtype="bfloat16", seed=0):
@@ -44,12 +43,9 @@ def _lm(compute_dtype="bfloat16", seed=0):
     return model
 
 
-def _batcher(model, kv, **kw):
-    opts = dict(slots=2, capacity=32, seed=0, kv=kv)
-    if kv == "paged":
-        opts.update(block_size=4, prefill_chunk=8, prompt_buckets=(8,))
-    else:
-        opts.update(prompt_buckets=(8, 16))
+def _batcher(model, **kw):
+    opts = dict(slots=2, capacity=32, seed=0, block_size=4, prefill_chunk=8,
+                prompt_buckets=(8,))
     opts.update(kw)
     return ContinuousBatcher(model, **opts)
 
@@ -88,13 +84,14 @@ def test_decode_params_casts_float_leaves_only(lm):
     _equal(host, copy)
 
 
-@pytest.mark.parametrize("kv", KVS)
-def test_prefill_and_decode_logits_bit_identical(lm, kv):
+@pytest.mark.parametrize("layout", ["paged", "contiguous"])
+def test_prefill_and_decode_logits_bit_identical(lm, layout):
     """Logits and caches of a prefill chunk and of six decode steps: the
-    cast copy against the f32 tree, through ``decode_forward``."""
+    cast copy against the f32 tree, through ``decode_forward``, over the
+    batcher's paged pools and over ``generate()``'s contiguous caches."""
     from deeplearning4j_tpu.serve.paged import build_pools
 
-    cb = _batcher(lm, kv)
+    cb = _batcher(lm)
     try:
         snap = cb.registry.current()
         copy = cb._params_for(snap)
@@ -102,7 +99,7 @@ def test_prefill_and_decode_logits_bit_identical(lm, kv):
     finally:
         cb.shutdown()
     B, T = 2, 8
-    if kv == "paged":
+    if layout == "paged":
         pools = build_pools(lm, 17, 4, lm.dtype)
         tables = jnp.asarray(1 + np.arange(B * 8, dtype=np.int32)
                              ).reshape(B, 8)
@@ -129,26 +126,22 @@ def test_prefill_and_decode_logits_bit_identical(lm, kv):
         _equal(c_c, c_f)
 
 
-@pytest.mark.parametrize("kv", KVS)
-def test_batcher_programs_bit_identical_to_f32_operands(lm, kv):
+def test_batcher_programs_bit_identical_to_f32_operands(lm):
     """The batcher's own compiled prefill, fed the copy, against the same
     function fed the registry's tree (the program every tick ran before)."""
-    cb = _batcher(lm, kv)
+    cb = _batcher(lm)
     try:
         snap = cb.registry.current()
+        progs = cb._programs
         ids = np.zeros((1, 8), np.int32)
         ids[0, :5] = [7, 3, 11, 2, 9]
         outs = []
         for params in (cb._params_for(snap), snap.params):
-            if kv == "paged":
-                pools = jax.tree.map(jnp.copy, cb._pools)  # donated
-                outs.append(cb._prefill_paged(
-                    params, snap.state, jnp.asarray(ids), pools,
-                    jnp.asarray([[1, 2, 0, 0, 0, 0, 0, 0]], jnp.int32),
-                    np.zeros((1,), np.int32), np.int32(5)))
-            else:
-                outs.append(cb._prefill(params, snap.state, jnp.asarray(ids),
-                                        np.int32(5)))
+            pools = jax.tree.map(jnp.copy, progs.pools)  # donated
+            outs.append(progs._prefill_chunk(
+                params, snap.state, jnp.asarray(ids), pools,
+                jnp.asarray([[1, 2, 0, 0, 0, 0, 0, 0]], jnp.int32),
+                np.zeros((1,), np.int32), np.int32(5)))
         _equal(outs[0], outs[1])
     finally:
         cb.shutdown()
@@ -158,8 +151,7 @@ REQUESTS = [dict(temperature=0.0), dict(temperature=0.8, top_k=5),
             dict(temperature=1.0)]
 
 
-@pytest.mark.parametrize("kv", KVS)
-def test_tokens_bit_identical_greedy_and_sampled(lm, kv, monkeypatch):
+def test_tokens_bit_identical_greedy_and_sampled(lm, monkeypatch):
     """Greedy and seeded sampled tokens from the batcher equal those of a
     batcher made to hand its programs the f32 tree."""
     rng = np.random.RandomState(1)
@@ -172,8 +164,8 @@ def test_tokens_bit_identical_greedy_and_sampled(lm, kv, monkeypatch):
         finally:
             cb.shutdown()
 
-    got = run(_batcher(lm, kv))
-    ref = _batcher(lm, kv)
+    got = run(_batcher(lm))
+    ref = _batcher(lm)
     monkeypatch.setattr(ref, "_params_for", lambda snap: snap.params)
     want = run(ref)
     for g, w in zip(got, want):
@@ -187,8 +179,7 @@ def _gen(cb, n=6):
                        temperature=0.0)
 
 
-@pytest.mark.parametrize("kv", KVS)
-def test_one_cast_per_generation_none_on_the_worker(lm, kv, monkeypatch):
+def test_one_cast_per_generation_none_on_the_worker(lm, monkeypatch):
     casts_on = []
     real = G.decode_params
 
@@ -198,7 +189,7 @@ def test_one_cast_per_generation_none_on_the_worker(lm, kv, monkeypatch):
 
     monkeypatch.setattr(G, "decode_params", spy)
     m = MetricsRegistry()
-    cb = _batcher(lm, kv, metrics=m, model_name="tiny")
+    cb = _batcher(lm, metrics=m, model_name="tiny")
     casts = m.counter("serve_params_cast_total", {"model": "tiny"})
     held = m.gauge("serve_params_compute_bytes", {"model": "tiny"})
     try:
@@ -229,8 +220,8 @@ def test_one_cast_per_generation_none_on_the_worker(lm, kv, monkeypatch):
         casts_on
 
     # tokens after each flip are a fresh batcher's on those parameters
-    fresh2 = _batcher(lm, kv, params=p2)
-    fresh1 = _batcher(lm, kv)
+    fresh2 = _batcher(lm, params=p2)
+    fresh1 = _batcher(lm)
     try:
         np.testing.assert_array_equal(after, _gen(fresh2))
         np.testing.assert_array_equal(back, _gen(fresh1))
@@ -241,7 +232,7 @@ def test_one_cast_per_generation_none_on_the_worker(lm, kv, monkeypatch):
 
 def test_idle_server_lets_go_of_the_retired_copy(lm):
     m = MetricsRegistry()
-    cb = _batcher(lm, "paged", metrics=m)
+    cb = _batcher(lm, metrics=m)
     held = m.gauge("serve_params_compute_bytes")
     try:
         one_copy = held.value
@@ -259,7 +250,7 @@ def test_failed_publish_does_not_pile_up_copies(lm):
     """A publish that a LATER warmer aborts leaves its copy behind only
     until the next publish; the old generation keeps serving its own."""
     m = MetricsRegistry()
-    cb = _batcher(lm, "paged", metrics=m)
+    cb = _batcher(lm, metrics=m)
     held = m.gauge("serve_params_compute_bytes")
     try:
         one_copy = held.value
@@ -288,7 +279,7 @@ def test_publish_behind_the_warmers_back_still_serves_the_cast(lm):
     """A generation this batcher's warmer never saw (published while the
     batcher was being built) is cast at its first use, once."""
     m = MetricsRegistry()
-    cb = _batcher(lm, "paged", metrics=m)
+    cb = _batcher(lm, metrics=m)
     casts = m.counter("serve_params_cast_total")
     try:
         cb.registry._warmers.clear()
@@ -300,7 +291,7 @@ def test_publish_behind_the_warmers_back_still_serves_the_cast(lm):
         assert _float_dtypes(cb._served[1]) == {"bfloat16"}
     finally:
         cb.shutdown()
-    fresh = _batcher(lm, "paged", params=p2)
+    fresh = _batcher(lm, params=p2)
     try:
         np.testing.assert_array_equal(out, _gen(fresh))
     finally:
@@ -316,7 +307,7 @@ def test_publishes_racing_ticks_keep_one_copy_per_generation(lm):
     import sys
 
     m = MetricsRegistry()
-    cb = _batcher(lm, "paged", metrics=m, slots=4, queue_limit=256)
+    cb = _batcher(lm, metrics=m, slots=4, queue_limit=256)
     casts = m.counter("serve_params_cast_total")
     held = m.gauge("serve_params_compute_bytes")
     one_copy = held.value
@@ -356,7 +347,7 @@ def test_publishes_racing_ticks_keep_one_copy_per_generation(lm):
     finally:
         sys.setswitchinterval(interval)
         cb.shutdown()
-    fresh = _batcher(lm, "paged", params=final.params)
+    fresh = _batcher(lm, params=final.params)
     try:
         np.testing.assert_array_equal(out, _gen(fresh))
     finally:
@@ -365,7 +356,7 @@ def test_publishes_racing_ticks_keep_one_copy_per_generation(lm):
 
 def test_shut_down_batcher_stops_casting(lm):
     m = MetricsRegistry()
-    cb = _batcher(lm, "paged", metrics=m)
+    cb = _batcher(lm, metrics=m)
     casts = m.counter("serve_params_cast_total")
     cb.shutdown()
     cb.registry.publish(jax.tree.map(lambda a: a * 1.5, lm.params))
@@ -373,24 +364,19 @@ def test_shut_down_batcher_stops_casting(lm):
 
 
 # ------------------------------------------------- (c) the executable set
-PARAM_TAGS = {"paged": ("gen_decode_paged", "gen_prefill_chunk"),
-              "dense": ("gen_decode_dense", "gen_prefill_dense")}
-
-
-@pytest.mark.parametrize("kv", KVS)
-def test_programs_take_compute_dtype_operands_and_warm_boot(lm, kv, tmp_path):
+def test_programs_take_compute_dtype_operands_and_warm_boot(lm, tmp_path):
     n_params = len(jax.tree.leaves(lm.params))
     outs = []
     for boot in ("cold", "warm"):
         m = MetricsRegistry()
-        cb = _batcher(lm, kv, metrics=m, aot_store=AotStore(tmp_path))
+        cb = _batcher(lm, metrics=m, aot_store=AotStore(tmp_path))
         try:
             misses = m.counter("serve_compile_misses_total",
                                {"component": "generate"})
             hits = m.counter("serve_aot_hits_total",
                              {"component": "generate"})
             at_boot = misses.value
-            for tag in PARAM_TAGS[kv]:
+            for tag in ("gen_decode_paged", "gen_prefill_chunk"):
                 exes = cb.aot_functions()[tag].executables
                 assert exes, tag
                 for exe in exes.values():
@@ -418,9 +404,9 @@ def test_programs_take_compute_dtype_operands_and_warm_boot(lm, kv, tmp_path):
 def test_strict_boot_from_a_prebuilt_store_serves(lm, tmp_path):
     """A store prebuilt by one batcher serves a strict replica: the keys it
     holds are the compute-dtype signatures the ticks ask for."""
-    _batcher(lm, "paged", aot_store=AotStore(tmp_path)).shutdown()
+    _batcher(lm, aot_store=AotStore(tmp_path)).shutdown()
     m = MetricsRegistry()
-    cb = _batcher(lm, "paged", metrics=m, aot_store=AotStore(tmp_path),
+    cb = _batcher(lm, metrics=m, aot_store=AotStore(tmp_path),
                   strict_aot=True)
     try:
         assert len(_gen(cb)) == 6
@@ -433,11 +419,10 @@ def test_strict_boot_from_a_prebuilt_store_serves(lm, tmp_path):
 
 
 # ------------------------------------------------- (d) no compute_dtype
-@pytest.mark.parametrize("kv", KVS)
-def test_model_without_compute_dtype_makes_no_copy(kv):
+def test_model_without_compute_dtype_makes_no_copy():
     model = _lm(compute_dtype=None)
     m = MetricsRegistry()
-    cb = _batcher(model, kv, metrics=m)
+    cb = _batcher(model, metrics=m)
     try:
         snap = cb.registry.current()
         assert cb._params_for(snap) is snap.params
