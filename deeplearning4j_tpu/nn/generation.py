@@ -415,6 +415,40 @@ def check_decodes(model: Sequential, context: int, what: str, *,
     return int(getattr(out_layer, "n_out", 0) or model._shapes[-1][-1])
 
 
+def top_k_threshold(scaled, top_k):
+    """Per-row ``kth`` (rows,) such that ``scaled >= kth[:, None]`` keeps
+    exactly each row's ``top_k`` largest values, ties at the k-th included:
+    the VALUE ``sort(scaled)[V - top_k]``, found exactly and without
+    sorting the vocabulary, at one cost whatever ``top_k`` is.
+
+    ``scaled`` (rows, V) floats, ``top_k`` (rows,) traced, clipped to 1..V
+    (``V`` = no restriction: the row's minimum, a mask that is all true).
+
+    A search on the threshold that counts. Floats are compared through
+    their order-preserving image in the unsigned integers (sign bit set on
+    a positive, every bit flipped on a negative; a NaN made the positive
+    quiet one first, so that it ranks last, where a sort puts it), and the
+    answer is built from the top bit down, one pass over the row a bit: the
+    largest ``t`` with at least ``k`` values ``>= t`` IS the k-th largest
+    value, whatever ties it has."""
+    k = jnp.clip(top_k, 1, scaled.shape[-1])
+    bits = 8 * scaled.dtype.itemsize
+    uint = jnp.dtype(f"uint{bits}")
+    top = uint.type(1 << (bits - 1))
+    b = lax.bitcast_convert_type(
+        jnp.where(jnp.isnan(scaled), jnp.nan, scaled), uint)
+    u = jnp.where(b >= top, ~b, b | top)
+
+    def refine(i, t):
+        cand = t | (top >> i.astype(uint))
+        enough = jnp.sum(u >= cand[:, None], axis=-1) >= k
+        return jnp.where(enough, cand, t)
+
+    t = lax.fori_loop(0, bits, refine, jnp.zeros(scaled.shape[:1], uint))
+    return lax.bitcast_convert_type(jnp.where(t >= top, t ^ top, ~t),
+                                    scaled.dtype)
+
+
 def sample_logits(logits, rng, temperature: float = 1.0,
                   top_k: Optional[int] = None):
     """Sample token ids (B,) from (B, V) logits. ``temperature=0`` = greedy;
@@ -423,8 +457,9 @@ def sample_logits(logits, rng, temperature: float = 1.0,
         return jnp.argmax(logits, axis=-1)
     logits = logits / temperature
     if top_k is not None and top_k > 0 and top_k < logits.shape[-1]:
-        kth = jnp.sort(logits, axis=-1)[:, -top_k][:, None]
-        logits = jnp.where(logits >= kth, logits, -1e30)
+        rows = logits.shape[:1]
+        kth = top_k_threshold(logits, jnp.full(rows, top_k, jnp.int32))
+        logits = jnp.where(logits >= kth[:, None], logits, -1e30)
     return jax.random.categorical(rng, logits, axis=-1)
 
 

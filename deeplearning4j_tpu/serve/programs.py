@@ -6,7 +6,8 @@ program IS, and everything only the programs need to know:
 
 - the three traced functions — ``_sample_dynamic``, ``_prefill_chunk_fn``,
   ``_decode_paged_fn``; a device trace names its programs after them
-  (``jit__decode_paged_fn``), so the names are part of the measurement;
+  (``jit__decode_paged_fn``), so the names are part of the measurement
+  (the first and the last sample through ``_sample_rows``);
 - the KV pools they carry from call to call (donated every step), and the
   format between the pools and ``decode_forward``'s per-layer cache
   dictionaries (the layout contract is in ``nn/generation.py``);
@@ -48,30 +49,37 @@ class GenPrograms:
         import jax.numpy as jnp
 
         from ..nn.generation import (cache_spec, decode_forward,
-                                     says_how_it_decodes)
+                                     says_how_it_decodes, top_k_threshold)
         from ..nn.model import _layer_key
 
         self.slots = int(slots)
         self.table_blocks = int(table_blocks)
         self.vocab = int(vocab)
         self.chunk_buckets = tuple(chunk_buckets)
-        S, V = self.slots, self.vocab
         mdl = model
 
-        def _sample_dynamic(logits, key, temperature, top_k):
-            """Fully-traced sampler: temperature 0 -> greedy, top_k as a
-            dynamic scalar (top_k == V disables the restriction)."""
+        def _sample_rows(logits, keys, temps, tks):
+            """Fully-traced sampler over rows: temperature 0 -> greedy,
+            top_k a dynamic value per row (top_k == V disables the
+            restriction). The thresholds are found for the batch at once
+            (``top_k_threshold``); the draw stays per row, each with its
+            own key."""
             with jax.named_scope("sample"):  # its name in a device trace
-                greedy = jnp.argmax(logits, axis=-1)
-                t = jnp.maximum(temperature, 1e-6)
-                scaled = logits / t
-                srt = jnp.sort(scaled, axis=-1)  # ascending
-                k = jnp.clip(top_k, 1, V)
-                kth = jnp.take(srt, V - k, axis=-1)
-                masked = jnp.where(scaled >= kth, scaled, -1e30)
-                samp = jax.random.categorical(key, masked, axis=-1)
-                return jnp.where(temperature <= 0.0, greedy,
-                                 samp).astype(jnp.int32)
+                scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+                kth = top_k_threshold(scaled, tks)
+
+                def one(l, s, key, temp, kth):
+                    masked = jnp.where(s >= kth, s, -1e30)
+                    samp = jax.random.categorical(key, masked, axis=-1)
+                    return jnp.where(temp <= 0.0, jnp.argmax(l, axis=-1),
+                                     samp).astype(jnp.int32)
+
+                return jax.vmap(one)(logits, scaled, keys, temps, kth)
+
+        def _sample_dynamic(logits, key, temperature, top_k):
+            """One row: the first token of a request."""
+            return _sample_rows(logits[None], key[None], temperature[None],
+                                top_k[None])[0]
 
         self.pools = build_pools(mdl, kv_blocks, block_size, mdl.dtype)
         self._lks = [lk for lk, _, _ in cache_spec(mdl)]
@@ -134,11 +142,9 @@ class GenPrograms:
                 mdl, params, state, toks[:, None].astype(jnp.int32),
                 _as_caches(pools, tables, live), pos)
 
-            def one(l, key, temp, tk):
-                key, sub = jax.random.split(key)
-                return _sample_dynamic(l, sub, temp, tk), key
-
-            nxt, new_keys = jax.vmap(one)(lg[:, 0], keys, temps, tks)
+            new_keys, subs = jnp.moveaxis(
+                jax.vmap(jax.random.split)(keys), 1, 0)
+            nxt = _sample_rows(lg[:, 0], subs, temps, tks)
             if routed:
                 # the three sums ride the tokens' readback: (S + 3,)
                 nxt = jnp.concatenate([nxt, _routing(caches)])

@@ -164,3 +164,147 @@ def test_a_stored_config_that_still_says_kv_boots_and_serves(kv, tmp_path):
         cb.shutdown()
     want = generate(model, prompt[None], 6, temperature=0.0)[0]
     np.testing.assert_array_equal(got, want)
+
+
+# ------------------------------------- the sampler's threshold (ISSUE 29)
+def _wide_lm():
+    """A vocabulary wide enough for a ``top_k`` in the hundreds."""
+    m = models.CausalLM(seed=0, input_shape=(32,), num_layers=1, d_model=16,
+                        num_heads=2, num_kv_heads=1, vocab=300).build()
+    m.init()
+    return m
+
+
+def _primitives(jaxpr):
+    """The name of every equation of a jaxpr and of the jaxprs nested in
+    it."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("tag", ["gen_decode_paged", "gen_sample"])
+def test_a_sampling_program_sorts_nothing(tag):
+    """No sort and no ``top_k`` of the vocabulary anywhere in the program:
+    the threshold is a loop that counts."""
+    import jax
+
+    cb = ContinuousBatcher(_wide_lm(), slots=2, capacity=16, block_size=4,
+                           seed=0)
+    try:
+        progs = cb._programs
+        snap = cb.registry.current()
+        operands = progs.signatures(cb._params_for(snap), snap.state)[tag][0]
+        fn = {"gen_decode_paged": progs._decode, "gen_sample": progs._sample}
+        found = set(_primitives(jax.make_jaxpr(fn[tag])(*operands).jaxpr))
+    finally:
+        cb.shutdown()
+    assert not found & {"sort", "top_k", "approx_top_k"}
+    assert found & {"scan", "while"}
+
+
+def test_the_batcher_serves_the_sort_formulas_tokens_at_every_top_k(
+        monkeypatch):
+    """Six requests decode side by side: greedy, and sampled at ``top_k``
+    1, 40, 257, all of the vocabulary but one, and none. Served again by a
+    batcher whose threshold is the parent's sort, every token is the same;
+    the greedy row serves ``generate()``'s."""
+    import jax.numpy as jnp
+
+    from deeplearning4j_tpu.nn import generation
+
+    model = _wide_lm()
+    rng = np.random.RandomState(7)
+    prompts = [rng.randint(1, 300, (n,)).astype(np.int32)
+               for n in (5, 3, 6, 4, 7, 2)]
+    asks = [dict(temperature=0.0, top_k=257), dict(temperature=0.9, top_k=1),
+            dict(temperature=0.8, top_k=40), dict(temperature=0.9, top_k=257),
+            dict(temperature=1.1, top_k=299), dict(temperature=0.7)]
+
+    def serve():
+        cb = ContinuousBatcher(model, slots=8, capacity=32, block_size=4,
+                               seed=0)
+        try:
+            reqs = [cb.submit(p, 10, **kw) for p, kw in zip(prompts, asks)]
+            return [r.wait() for r in reqs]
+        finally:
+            cb.shutdown()
+
+    got = serve()
+    np.testing.assert_array_equal(
+        got[0], generation.generate(model, prompts[0][None], 10,
+                                    temperature=0.0)[0])
+    sorts = []
+
+    def by_sort(scaled, top_k):
+        sorts.append(scaled.shape)
+        V = scaled.shape[-1]
+        return jnp.take_along_axis(
+            jnp.sort(scaled, axis=-1),
+            (V - jnp.clip(top_k, 1, V))[:, None], axis=-1)[:, 0]
+
+    monkeypatch.setattr(generation, "top_k_threshold", by_sort)
+    want = serve()
+    assert sorts                                # the programs traced it
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("temperature,top_k",
+                         [(0.0, 0), (0.0, 7), (0.8, 0), (0.8, 1), (0.8, 40),
+                          (1.2, 256), (0.8, 257), (0.5, "V-1")])
+def test_the_first_token_sampler_is_the_sort_formula(temperature, top_k):
+    """``gen_sample`` (one row) against the parent's formula written out."""
+    import jax
+    import jax.numpy as jnp
+
+    model = _wide_lm()
+    cb = ContinuousBatcher(model, slots=2, capacity=16, block_size=4, seed=0)
+    try:
+        V = cb.vocab
+        k = {"V-1": V - 1, 0: V}.get(top_k, top_k)
+        logits = jnp.asarray(np.random.RandomState(k).standard_normal(V) * 3,
+                             jnp.bfloat16).astype(jnp.float32)
+        key = jax.random.PRNGKey(k)
+        got = int(cb._programs.sample(logits, key, temperature, k))
+    finally:
+        cb.shutdown()
+    scaled = logits / jnp.maximum(np.float32(temperature), 1e-6)
+    kth = jnp.sort(scaled)[V - k]
+    samp = jax.random.categorical(
+        key, jnp.where(scaled >= kth, scaled, -1e30))
+    assert got == int(jnp.argmax(logits) if temperature <= 0 else samp)
+
+
+def test_signatures_and_tags_are_what_they_were():
+    """One sampler, one decode step, one chunk program a bucket. The
+    operand lists are the parent's, written out."""
+    cb = _batcher(_causal_lm(), None)
+    try:
+        progs = cb._programs
+        snap = cb.registry.current()
+        params = cb._params_for(snap)
+        sigs = progs.signatures(params, snap.state)
+    finally:
+        cb.shutdown()
+    S, B, V = 2, 8, 50
+
+    def shapes(operands):
+        return [(tuple(o.shape), np.dtype(o.dtype).name) for o in operands
+                if hasattr(o, "shape")]
+
+    assert tuple(sigs) == TAGS
+    assert shapes(sigs["gen_sample"][0]) == [
+        ((V,), "float32"), ((2,), "uint32"), ((), "float32"), ((), "int32")]
+    (decode,) = sigs["gen_decode_paged"]
+    assert decode[0] is params and decode[3] is progs._pools_sig
+    assert shapes(decode) == [
+        ((S,), "int32"), ((S, B), "int32"), ((S,), "int32"),
+        ((S, 2), "uint32"), ((S,), "float32"), ((S,), "int32")]
+    assert [shapes(c) for c in sigs["gen_prefill_chunk"]] == [
+        [((1, b), "int32"), ((1, B), "int32"), ((1,), "int32"),
+         ((), "int32")] for b in (4, 8)]
