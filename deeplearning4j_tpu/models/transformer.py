@@ -162,6 +162,60 @@ class OlmoeLM(ZooModel):
         return b.build()
 
 
+@register_model
+class Glm4MoeLiteLM(ZooModel):
+    """GLM-4.7-Flash (``zai-org/GLM-4.7-Flash``, ``glm4_moe_lite``): latent
+    attention in every layer, ``first_k_dense`` leading layers with a dense
+    SwiGLU and the rest with sigmoid-routed experts beside a shared one
+    (``nn/layers/glm4_moe_lite.py``), a final RMSNorm and an untied,
+    bias-free head. The first zoo model whose layers are not all alike. The
+    defaults are the published sizes; ``dtype`` is the dtype the parameters
+    and the cache are HELD in (``NetConfig.dtype``), as for ``OlmoeLM``."""
+
+    input_shape = (8192,)
+
+    def __init__(self, num_classes=None, seed=12345, input_shape=None, *,
+                 num_layers=47, first_k_dense=1, d_model=2048, num_heads=20,
+                 q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+                 qk_rope_head_dim=64, v_head_dim=256, dense_width=10240,
+                 num_experts=64, top_k=4, expert_width=1536,
+                 shared_experts=1, routed_scale=1.8, vocab=154880,
+                 rms_eps=1e-5, rope_base=1e6, dtype="float32", **kw):
+        super().__init__(num_classes, seed, input_shape, **kw)
+        self.num_layers, self.first_k_dense = num_layers, first_k_dense
+        self.d_model, self.vocab = d_model, vocab
+        self.num_classes = vocab
+        self.rms_eps = rms_eps
+        self.dtype = dtype
+        attention = dict(
+            num_heads=num_heads, q_lora_rank=q_lora_rank,
+            kv_lora_rank=kv_lora_rank, qk_nope_head_dim=qk_nope_head_dim,
+            qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+            eps=rms_eps, rope_base=rope_base)
+        self.dense_block = L.Glm4MoeLiteBlock(
+            num_experts=0, ffn_width=dense_width, **attention)
+        self.expert_block = L.Glm4MoeLiteBlock(
+            num_experts=num_experts, top_k=top_k, ffn_width=expert_width,
+            shared_experts=shared_experts, routed_scale=routed_scale,
+            **attention)
+
+    def build(self) -> Sequential:
+        init = "normal_0.02"   # initializer_range
+        b = (SequentialBuilder(NetConfig(
+                seed=self.seed, dtype=self.dtype,
+                updater={"type": "adamw", "learning_rate": 3e-4}))
+             .input_shape(self.input_shape[0])
+             .layer(L.EmbeddingSequence(n_in=self.vocab, n_out=self.d_model,
+                                        weight_init=init)))
+        for i in range(self.num_layers):
+            b.layer(self.dense_block if i < self.first_k_dense
+                    else self.expert_block)
+        b.layer(L.RMSNorm(eps=self.rms_eps))
+        b.layer(L.RnnOutput(n_out=self.vocab, activation="softmax",
+                            loss="mcxent", use_bias=False, weight_init=init))
+        return b.build()
+
+
 # ---------------------------------------------------------------------------
 # Fully-sharded training step: dp x tp x sp over one mesh.
 # ---------------------------------------------------------------------------

@@ -47,32 +47,37 @@ _TOKEN_LOCAL = (ActivationLayer, AlphaDropout, Dense, DropoutLayer,
 
 
 # --------------------------------------------------------------------------
-# KV-cache layout contract
+# Cache layout contract
 #
-# Attention layers decode against one of two cache layouts, both plain
-# pytrees so they trace/vmap/donate like any other operand:
+# A cached layer names the PARTS of its cache and the trailing shape one
+# token takes in each (``cache_parts``): a KV-cached attention layer says
+# ``{"k": (Hkv, hd), "v": (Hkv, hd)}``, a latent-attention layer
+# ``{"latent": (512,), "rope": (64,)}``: 576 values a token, no heads.
+# Every part lives in one of two layouts, both plain pytrees so they
+# trace/vmap/donate like any other operand:
 #
-# dense  {"k": (B, C, Hkv, hd), "v": (B, C, Hkv, hd)}
+# dense  {name: (B, C, *shape), ...}
 #     Position p of row b lives at [b, p]. C is the fixed capacity; HBM
 #     cost is O(B * C) regardless of live tokens.
 #
-# paged  {"k_pool": (N, bs, Hkv, hd), "v_pool": (N, bs, Hkv, hd),
-#         "tables": (B, maxb) int32}
+# paged  {name + "_pool": (N, bs, *shape), ..., "tables": (B, maxb) int32}
 #     Position p of row b lives at pool[tables[b, p // bs], p % bs].
-#     The pool is shared across rows; ``tables`` maps each row's logical
+#     The pools are shared across rows; ``tables`` maps each row's logical
 #     blocks to physical blocks, so HBM cost is O(allocated blocks) — the
 #     allocator (serve/paged.py) hands blocks out on demand. Physical
 #     block 0 is the TRASH block: unallocated table entries point at it,
 #     so writes past a row's live region land there harmlessly and reads
 #     of it are always causally masked. Appends whose logical block index
 #     falls past the table (right-padding overflow) are also routed to
-#     block 0.
+#     block 0. (``as_paged`` / ``paged_parts`` go between a layer's pools
+#     and this dictionary; a KV layer's comes out as ``k_pool``/``v_pool``.)
 #
-# ``cache_append`` / ``cache_read`` are the only two operations either
-# layout supports; everything above them (masking, rope, GQA) is layout-
-# agnostic. ``pos`` may be a scalar (whole batch at one offset — prefill,
-# lockstep decode) or a (B,) vector (per-row offsets — continuous-batching
-# decode, where every slot sits at its own position).
+# ``cache_write`` / ``cache_gather`` are the only two operations either
+# layout supports (``cache_append`` / ``cache_read`` are their ``k``/``v``
+# face); everything above them (masking, rope, GQA, a latent's absorbed
+# projections) is layout-agnostic. ``pos`` may be a scalar (whole batch at
+# one offset — prefill, lockstep decode) or a (B,) vector (per-row offsets —
+# continuous-batching decode, where every slot sits at its own position).
 #
 # Invariant both layouts share: position p is WRITTEN before it is ever
 # unmasked-READ (prefill writes 0..T-1 then reads causally; decode writes
@@ -86,18 +91,28 @@ def _pos_vec(pos):
     return pos if getattr(pos, "ndim", 0) == 1 else None
 
 
-def cache_append(cache, k, v, pos):
-    """Write a chunk's keys/values at absolute offset ``pos``.
+def as_paged(pools, tables):
+    """One layer's paged cache dictionary from its pools ``{name: (N, bs,
+    *shape)}`` and the block tables."""
+    return {**{f"{n}_pool": a for n, a in pools.items()}, "tables": tables}
 
-    ``k``/``v``: (B, Tq, Hkv, hd); ``pos``: scalar or (B,) vector. Returns
-    the updated cache (same layout, same shapes — never shape-changing, so
-    appends inside jit never trigger a recompile)."""
-    if "k_pool" in cache:  # paged
-        kp, vp, tables = cache["k_pool"], cache["v_pool"], cache["tables"]
-        B, Tq = k.shape[:2]
-        bs = kp.shape[1]
+
+def paged_parts(cache, names):
+    """The pools ``{name: ...}`` back out of a paged cache dictionary."""
+    return {n: cache[f"{n}_pool"] for n in names}
+
+
+def cache_write(cache, parts, pos):
+    """Write a chunk's ``parts`` ``{name: (B, Tq, *shape)}`` at absolute
+    offset ``pos`` (scalar or (B,) vector). Returns the updated cache (same
+    layout, same shapes — never shape-changing, so writes inside jit never
+    trigger a recompile), holding the parts written and, paged, the tables."""
+    B, Tq = next(iter(parts.values())).shape[:2]
+    pv = _pos_vec(pos)
+    if "tables" in cache:  # paged
+        tables = cache["tables"]
+        bs = cache[f"{next(iter(parts))}_pool"].shape[1]
         maxb = tables.shape[1]
-        pv = _pos_vec(pos)
         p = pv if pv is not None else jnp.broadcast_to(
             jnp.asarray(pos, jnp.int32), (B,))
         wpos = p[:, None] + jnp.arange(Tq, dtype=jnp.int32)[None]  # (B, Tq)
@@ -106,39 +121,52 @@ def cache_append(cache, k, v, pos):
         # logical blocks past the table (right-padded garbage) -> trash 0
         phys = jnp.where(blk < maxb,
                          tables[rows, jnp.minimum(blk, maxb - 1)], 0)
-        kp = kp.at[phys, off].set(k.astype(kp.dtype))
-        vp = vp.at[phys, off].set(v.astype(vp.dtype))
-        return {"k_pool": kp, "v_pool": vp, "tables": tables}
-    pv = _pos_vec(pos)
+        out = {}
+        for n, a in parts.items():
+            pool = cache[f"{n}_pool"]
+            out[f"{n}_pool"] = pool.at[phys, off].set(a.astype(pool.dtype))
+        return {**out, "tables": tables}
     if pv is None:
-        ck = lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                      (0, pos, 0, 0))
-        cv = lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                      (0, pos, 0, 0))
-    else:
-        B, Tq = k.shape[:2]
-        rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-        wpos = pv[:, None] + jnp.arange(Tq, dtype=jnp.int32)[None]
-        ck = cache["k"].at[rows, wpos].set(k.astype(cache["k"].dtype))
-        cv = cache["v"].at[rows, wpos].set(v.astype(cache["v"].dtype))
-    return {"k": ck, "v": cv}
+        return {n: lax.dynamic_update_slice(
+                    cache[n], a.astype(cache[n].dtype),
+                    (0, pos) + (0,) * (a.ndim - 2))
+                for n, a in parts.items()}
+    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+    wpos = pv[:, None] + jnp.arange(Tq, dtype=jnp.int32)[None]
+    return {n: cache[n].at[rows, wpos].set(a.astype(cache[n].dtype))
+            for n, a in parts.items()}
+
+
+def cache_gather(cache, names):
+    """Materialize the parts ``names`` of the cache, each (B, L, *shape) in
+    logical position order. Dense: the buffers themselves (L = C, no copy).
+    Paged: a block-table gather (L = maxb * bs); entries past a row's live
+    length are garbage the caller MUST mask causally (cache_write's
+    invariant guarantees every position <= the current offset holds real
+    data)."""
+    if "tables" not in cache:
+        return tuple(cache[n] for n in names)
+    tables = cache["tables"]
+    B, maxb = tables.shape
+
+    def gather(pool):
+        return pool[tables].reshape(
+            (B, maxb * pool.shape[1]) + pool.shape[2:])
+
+    with jax.named_scope("cache_read"):  # the gather's name in a trace
+        return tuple(gather(cache[f"{n}_pool"]) for n in names)
+
+
+def cache_append(cache, k, v, pos):
+    """:func:`cache_write` of a KV layer's two parts: ``k``/``v``
+    (B, Tq, Hkv, hd)."""
+    return cache_write(cache, {"k": k, "v": v}, pos)
 
 
 def cache_read(cache):
-    """Materialize the cache as (K, V), each (B, L, Hkv, hd) in logical
-    position order. Dense: the buffers themselves (L = C, no copy). Paged:
-    a block-table gather (L = maxb * bs); entries past a row's live length
-    are garbage the caller MUST mask causally (cache_append's invariant
-    guarantees every position <= the current offset holds real data)."""
-    if "k_pool" in cache:
-        kp, tables = cache["k_pool"], cache["tables"]
-        B, maxb = tables.shape
-        bs, Hkv, hd = kp.shape[1:]
-        with jax.named_scope("cache_read"):  # the gather's name in a trace
-            ck = kp[tables].reshape(B, maxb * bs, Hkv, hd)
-            cv = cache["v_pool"][tables].reshape(B, maxb * bs, Hkv, hd)
-        return ck, cv
-    return cache["k"], cache["v"]
+    """:func:`cache_gather` of a KV layer's two parts: (K, V), each
+    (B, L, Hkv, hd)."""
+    return cache_gather(cache, ("k", "v"))
 
 
 def _mha_decode(num_heads: int, params, x, cache, pos, *, rope=False,
@@ -178,6 +206,27 @@ def _mha_decode(num_heads: int, params, x, cache, pos, *, rope=False,
     return y, cache
 
 
+def causal_valid(pos, Tq: int, C: int, window=None):
+    """Which of a cache's ``C`` slots each of a chunk's ``Tq`` queries at
+    offset ``pos`` may see: slots 0..pos+t. (Tq, C) for a scalar ``pos``,
+    (B, Tq, C) for a (B,) vector."""
+    pv = _pos_vec(pos)
+    if pv is None:
+        qpos = pos + jnp.arange(Tq)[:, None]
+        valid = jnp.arange(C)[None, :] <= qpos  # (Tq, C)
+        if window is not None:
+            # sliding window: only the last `window` cache slots are visible
+            # (cache stays full-capacity; the band mask honors the training
+            # semantics — a ring-buffer cache is a future memory optimization)
+            valid = valid & (qpos - jnp.arange(C)[None, :] < window)
+        return valid
+    qpos = pv[:, None, None] + jnp.arange(Tq)[None, :, None]  # (B,Tq,1)
+    valid = jnp.arange(C)[None, None, :] <= qpos  # (B, Tq, C)
+    if window is not None:
+        valid = valid & (qpos - jnp.arange(C)[None, None, :] < window)
+    return valid
+
+
 def attend_cached(q, k, v, cache, pos, *, window=None):
     """The layout-agnostic half of a cached attention: append the chunk's
     ``k``/``v`` (B, Tq, Hkv, hd; already rotated) at ``pos``, read the cache
@@ -192,20 +241,10 @@ def attend_cached(q, k, v, cache, pos, *, window=None):
     ck, cv = cache_read(cache)
     C = ck.shape[1]
     scale = 1.0 / np.sqrt(hd)
+    valid = causal_valid(pos, Tq, C, window)
     if pv is None:
-        qpos = pos + jnp.arange(Tq)[:, None]
-        valid = jnp.arange(C)[None, :] <= qpos  # (Tq, C)
-        if window is not None:
-            # sliding window: only the last `window` cache slots are visible
-            # (cache stays full-capacity; the band mask honors the training
-            # semantics — a ring-buffer cache is a future memory optimization)
-            valid = valid & (qpos - jnp.arange(C)[None, :] < window)
         vmask, vmask_g = valid[None, None], valid[None, None, None]
     else:
-        qpos = pv[:, None, None] + jnp.arange(Tq)[None, :, None]  # (B,Tq,1)
-        valid = jnp.arange(C)[None, None, :] <= qpos  # (B, Tq, C)
-        if window is not None:
-            valid = valid & (qpos - jnp.arange(C)[None, None, :] < window)
         vmask, vmask_g = valid[:, None], valid[:, None, None]
     if Hkv != H:
         # grouped einsum: query heads fold into (Hkv, G) so the cache is
@@ -228,50 +267,78 @@ def attend_cached(q, k, v, cache, pos, *, window=None):
     return y, cache
 
 
-def cache_spec(model: Sequential):
-    """The KV-cached attention layers of ``model`` as
-    ``[(layer_key, kv_heads, head_dim), ...]`` — everything a cache
+def cache_parts(model: Sequential):
+    """The cached layers of ``model`` as ``[(layer_key, {part: shape}), ...]``:
+    for each the named parts of its cache and the trailing shape ONE token
+    takes in each — ``{"k": (kv_heads, head_dim), "v": (kv_heads,
+    head_dim)}`` for KV-cached attention, ``{"latent": (512,), "rope":
+    (64,)}`` for a layer that caches a latent vector and one rope key a token
+    and no heads. It is everything a cache
     builder (serve/paged.py block pools, external runtimes) needs without
-    walking layer internals. Recurrent carries are NOT listed: they are
-    opaque layer-owned state with no append/read contract."""
+    walking layer internals; a window ring or a recurrent state would be
+    further part names with shapes of their own. Recurrent carries are NOT
+    listed: they are opaque layer-owned state with no write/gather
+    contract."""
     spec = []
     for i, layer in enumerate(model.layers):
-        kv = _kv_shape(layer, model._shapes[i])
-        if kv is not None:
-            spec.append((_layer_key(i, layer),) + kv)
+        parts = _layer_parts(layer, model._shapes[i])
+        if parts is not None:
+            spec.append((_layer_key(i, layer), parts))
+    return spec
+
+
+def cache_spec(model: Sequential):
+    """:func:`cache_parts` for a model whose cached layers all keep keys and
+    values: ``[(layer_key, kv_heads, head_dim), ...]``. A layer that names
+    other parts (a latent) has no such triple, and asking for one is an
+    error: build from :func:`cache_parts`."""
+    spec = []
+    for lk, parts in cache_parts(model):
+        if set(parts) != {"k", "v"} or parts["k"] != parts["v"]:
+            raise ValueError(
+                f"{lk} caches {parts}, not k and v of one (kv_heads, "
+                f"head_dim): build its cache from cache_parts(model)")
+        spec.append((lk,) + parts["k"])
     return spec
 
 
 def says_how_it_decodes(layer) -> bool:
     """The one hook a stateful layer is reached through (ROADMAP D1): a
     layer that has ``decode(params, x, cache, pos) -> (y, cache)`` and
-    ``cache_spec(input_shape) -> (kv_heads, head_dim)`` is asked before any
-    ``isinstance`` ladder here or in the batcher is walked. What is left on
-    the ladders is the dense block and the bare attention layer."""
+    ``cache_spec(input_shape)`` is asked before any ``isinstance`` ladder
+    here or in the batcher is walked. ``cache_spec`` answers either
+    ``(kv_heads, head_dim)`` — keys and values, parts ``k`` and ``v`` of
+    that shape — or ``{part: trailing shape}`` for a cache of other parts
+    (``{"latent": (512,), "rope": (64,)}``); ``decode`` finds the cache it
+    is handed in the layout contract above under those names. What is left
+    on the ladders is the dense block and the bare attention layer."""
     return hasattr(layer, "decode") and hasattr(layer, "cache_spec")
 
 
-def _kv_shape(layer, input_shape):
-    """(kv_heads, head_dim) of the KV cache ``layer`` decodes against, or
-    None for a layer that keeps none."""
+def _layer_parts(layer, input_shape):
+    """``{part: trailing shape}`` of the cache ``layer`` decodes against,
+    or None for a layer that keeps none."""
+    kv = None
     if says_how_it_decodes(layer):
-        return tuple(layer.cache_spec(input_shape))
-    if isinstance(layer, (TransformerEncoderBlock, MultiHeadAttention)):
+        kv = layer.cache_spec(input_shape)
+        if isinstance(kv, dict):
+            return {n: tuple(shape) for n, shape in kv.items()}
+    elif isinstance(layer, (TransformerEncoderBlock, MultiHeadAttention)):
         hd = input_shape[-1] // layer.num_heads
-        return (layer.num_kv_heads or layer.num_heads), hd  # GQA: smaller
-    return None
+        kv = (layer.num_kv_heads or layer.num_heads), hd  # GQA: smaller
+    return None if kv is None else {"k": tuple(kv), "v": tuple(kv)}
 
 
 def init_caches(model: Sequential, batch: int, capacity: int, dtype):
-    """Dense-layout caches for every attention layer (+ recurrent carries).
-    For the paged layout, build pools from :func:`cache_spec` instead."""
+    """Dense-layout caches for every cached layer (+ recurrent carries).
+    For the paged layout, build pools from :func:`cache_parts` instead."""
     caches: Dict[str, Any] = {}
     for i, layer in enumerate(model.layers):
         k = _layer_key(i, layer)
-        kv = _kv_shape(layer, model._shapes[i])
-        if kv is not None:
-            z = jnp.zeros((batch, capacity) + kv, dtype)
-            caches[k] = {"k": z, "v": z}
+        parts = _layer_parts(layer, model._shapes[i])
+        if parts is not None:
+            caches[k] = {n: jnp.zeros((batch, capacity) + shape, dtype)
+                         for n, shape in parts.items()}
         elif isinstance(layer, RecurrentLayer):
             caches[k] = layer.init_carry(batch, model._shapes[i], dtype)
     return caches
@@ -405,9 +472,9 @@ def check_decodes(model: Sequential, context: int, what: str, *,
                 f"layer {i} {type(layer).__name__} does not say how it "
                 f"decodes: it is not token-local, and has no "
                 f"decode(params, x, cache, pos) -> (y, cache) with "
-                f"cache_spec(input_shape) -> (kv_heads, head_dim) "
-                f"(nn.generation.says_how_it_decodes). Decoding it one "
-                f"token at a time without a cache would disagree with "
+                f"cache_spec(input_shape) -> (kv_heads, head_dim) or "
+                f"{{part: shape}} (nn.generation.says_how_it_decodes). "
+                f"Decoding it one token at a time without a cache would disagree with "
                 f"its full forward pass")
     out_layer = model.layers[last]
     if served and not isinstance(out_layer, Output):

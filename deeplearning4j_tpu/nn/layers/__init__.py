@@ -16,6 +16,7 @@ from .core import (ActivationLayer, AlphaDropout, CenterLossOutput,
                    RnnOutput)
 from .custom import CustomLayer, Lambda, resolve_function
 from .moe import MoE, MoETransformerBlock
+from .glm4_moe_lite import Glm4MoeLiteBlock
 from .norm import LRN, BatchNorm, LayerNorm, RMSNorm
 from .olmoe import OlmoeBlock
 from .pooling import Flatten, GlobalPooling, Reshape
@@ -31,7 +32,7 @@ __all__ = [
     "CustomLayer", "Deconv2D", "Dense", "DepthwiseConv2D", "DropoutLayer",
     "ElementWiseMultiplication", "Embedding", "EmbeddingSequence",
     "GaussianDropout", "GaussianNoise", "Flatten",
-    "Frozen", "GRU", "GlobalPooling", "GravesLSTM", "LRN", "LSTM", "Lambda",
+    "Frozen", "GRU", "Glm4MoeLiteBlock", "GlobalPooling", "GravesLSTM", "LRN", "LSTM", "Lambda",
     "LastTimeStep",
     "LayerNorm", "LossLayer", "MoE", "MoETransformerBlock",
     "MultiHeadAttention", "OlmoeBlock", "Output", "PReLU",

@@ -14,12 +14,9 @@ Published as ``allenai/OLMoE-1B-7B`` (Muennighoff et al. 2024). For hidden
 
 Every token goes to its k experts whatever the others chose: there is no
 capacity and nothing is dropped (``moe.MoE`` is the GShard capacity layer, a
-different thing). Shapes are static: every expert runs on every row and the
-rows an expert was not chosen for are weighted 0, which makes the down
-projection ONE matmul contracting over (expert, width). At serving shapes
-(32-64 rows) the layer is bound by streaming the experts' weights, which this
-reads once; PERF.md section 6 (PR 25) has the measurement against the sorted
-``ragged_dot`` form.
+different thing). The router is this block's; what follows the choice (one
+weight an expert, the routing sums, the experts' three matmuls) is
+``layers/experts.py``'s, shared with the other dropless block.
 
 The block is the first to say itself how it decodes (ROADMAP D1):
 ``cache_spec`` and ``decode`` are what ``nn.generation`` and the batcher ask a
@@ -44,6 +41,7 @@ import jax.numpy as jnp
 from ...ops import initializers
 from ..api import Layer, Shape, register_layer
 from .attention import dot_product_attention, rope_rotate
+from .experts import assign, swiglu_experts
 from .norm import rms_norm
 
 INIT = "normal_0.02"   # ``initializer_range`` 0.02
@@ -165,34 +163,8 @@ class OlmoeBlock(Layer):
                              preferred_element_type=jnp.float32)
             gate, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
                                       self.top_k)            # (N, k)
-            chosen = jax.nn.one_hot(idx, self.num_experts,
-                                    dtype=jnp.int32)          # (N, k, E)
-            # sums, not matmuls: a TPU's default matmul would round the
-            # gates to bf16 on the way
-            weight = jnp.sum(gate[:, :, None] * chosen, axis=1)   # (N, E)
-            routing = None
-            if live is not None:
-                rows = jnp.broadcast_to(live, shape[:-1]).reshape(-1)
-                load = jnp.sum(chosen * rows[:, None, None], axis=(0, 1))
-                routing = jnp.stack([jnp.sum(load), jnp.sum(load > 0),
-                                     jnp.max(load)]).astype(jnp.int32)
+            weight, routing = assign(gate, idx, self.num_experts, live,
+                                     shape[:-1])
         with jax.named_scope("moe_experts"):
-            f32 = jnp.float32
-            g = jnp.einsum("nd,edf->nef", h, p["w_gate"],
-                           preferred_element_type=f32)
-            u = jnp.einsum("nd,edf->nef", h, p["w_up"],
-                           preferred_element_type=f32)
-            a = (jax.nn.silu(g) * u * weight[:, :, None]).astype(h.dtype)
-            y = jnp.einsum("nef,efd->nd", a, p["w_down"],
-                           preferred_element_type=f32).astype(h.dtype)
+            y = swiglu_experts(h, p["w_gate"], p["w_up"], p["w_down"], weight)
         return y.reshape(shape), routing
-
-
-# what ``decode`` reports under "routing": three int32 sums over the rows
-# marked live, in this order (the batcher's serve_moe_<field>_total counters)
-ROUTING_FIELDS = {
-    "assignments": "token-expert pairs of real tokens",
-    "experts_touched": "experts with at least one real token, summed over "
-                       "layers",
-    "max_load": "rows of the fullest expert, summed over layers",
-}

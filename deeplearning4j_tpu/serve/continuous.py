@@ -431,6 +431,10 @@ class ContinuousBatcher:
         self._m_kv_bytes = m.gauge(
             "serve_kv_live_bytes", self._lbl(),
             help="bytes of KV pool backing live tokens (all layers)")
+        m.gauge("serve_kv_token_bytes", self._lbl(),
+                help="bytes of cache one token takes in the pools that were "
+                     "built, all layers and all parts"
+                ).set(self._block_bytes // self.block_size)
         self._m_pf_depth = m.gauge(
             "serve_prefill_queue_depth", self._lbl(),
             help="prompts mid-prefill (chunked jobs in flight)")
@@ -496,7 +500,7 @@ class ContinuousBatcher:
         # what routing did, per program kind; nothing for a model without
         # experts
         if self._programs.routed:
-            from ..nn.layers.olmoe import ROUTING_FIELDS
+            from ..nn.layers.experts import ROUTING_FIELDS
 
             self._m_routing = {
                 prog: [m.counter(f"serve_moe_{f}_total",

@@ -7,9 +7,11 @@ The paged layout (vLLM's PagedAttention scheme, adapted to the fixed-shape
 XLA contract) carves KV memory into fixed-size **token blocks** in one
 shared pool per attention layer:
 
-- ``k_pool`` / ``v_pool``: ``(num_blocks, block_size, Hkv, hd)`` device
-  arrays, donated through every decode tick / prefill chunk (loop-carried,
-  never copied);
+- one pool for each part a layer's cache spec names (``build_pools``):
+  ``k_pool`` / ``v_pool`` ``(num_blocks, block_size, Hkv, hd)`` for keys
+  and values, ``latent_pool`` and ``rope_pool`` ``(num_blocks, block_size,
+  width)`` for a latent-attention layer; device arrays, donated through
+  every decode tick / prefill chunk (loop-carried, never copied);
 - a per-slot **block table** ``(slots, max_blocks)`` int32 mapping logical
   block ``p // block_size`` to a physical block — a *traced operand* of
   the one compiled decode step, so growing/retiring sequences never
@@ -37,7 +39,7 @@ CoW block copy on its own thread.
 
 The device-side layout contract (how positions map into pools, the trash
 block, append/read semantics) lives in ``nn/generation.py`` next to
-``cache_append`` / ``cache_read``; this module only decides *which*
+``cache_write`` / ``cache_gather``; this module only decides *which*
 physical blocks a slot owns.
 """
 
@@ -152,28 +154,34 @@ class BlockAllocator:
 
 
 def build_pools(model, num_blocks: int, block_size: int, dtype) -> Dict:
-    """Zero-filled per-attention-layer block pools:
-    ``{layer_key: {"k": (N, bs, Hkv, hd), "v": ...}}`` (device arrays)."""
+    """Zero-filled block pools (device arrays) for every cached layer, one
+    per part the layer's spec names (``nn.generation.cache_parts``):
+    ``{layer_key: {part: (N, bs, *shape)}}`` — ``{"k": (N, bs, Hkv, hd),
+    "v": ...}`` for KV-cached attention, ``{"latent": (N, bs, 512), "rope":
+    (N, bs, 64)}`` for a layer that caches a latent and a rope key a token."""
     import jax.numpy as jnp
 
-    from ..nn.generation import cache_spec
+    from ..nn.generation import cache_parts
 
-    spec = cache_spec(model)
+    spec = cache_parts(model)
     if not spec:
         raise ValueError("model has no attention layers to page")
-    return {lk: {"k": jnp.zeros((num_blocks, block_size, hkv, hd), dtype),
-                 "v": jnp.zeros((num_blocks, block_size, hkv, hd), dtype)}
-            for lk, hkv, hd in spec}
+    return {lk: {n: jnp.zeros((num_blocks, block_size) + shape, dtype)
+                 for n, shape in parts.items()}
+            for lk, parts in spec}
 
 
 def block_bytes(model, block_size: int, dtype) -> int:
-    """Bytes of KV one block holds across ALL attention layers (k + v) —
-    the unit the live-KV-bytes gauge counts in."""
-    from ..nn.generation import cache_spec
+    """Bytes one block holds across ALL cached layers and all the parts
+    their specs name (k + v; a latent and its rope key) — the unit the
+    live-KV-bytes gauge counts in, and ``block_size`` times what one token
+    costs."""
+    from ..nn.generation import cache_parts
 
     itemsize = np.dtype(dtype).itemsize
-    return sum(2 * block_size * hkv * hd * itemsize
-               for _, hkv, hd in cache_spec(model))
+    return sum(block_size * int(np.prod(shape)) * itemsize
+               for _, parts in cache_parts(model)
+               for shape in parts.values())
 
 
 def blocks_needed(tokens: int, block_size: int) -> int:

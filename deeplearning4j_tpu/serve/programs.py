@@ -8,9 +8,10 @@ program IS, and everything only the programs need to know:
   ``_decode_paged_fn``; a device trace names its programs after them
   (``jit__decode_paged_fn``), so the names are part of the measurement
   (the first and the last sample through ``_sample_rows``);
-- the KV pools they carry from call to call (donated every step), and the
+- the cache pools they carry from call to call (donated every step): what
+  each layer's spec names, ``k`` and ``v``, or ``latent`` and ``rope``; the
   format between the pools and ``decode_forward``'s per-layer cache
-  dictionaries (the layout contract is in ``nn/generation.py``);
+  dictionaries is the layout contract's (``nn/generation.py``: ``as_paged``);
 - how a model with expert layers reports what routing did: which rows are
   live, and the three sums that ride a decode step's tokens as ``(S + 3,)``;
 - the persistent-store wrapping under the tags ``gen_sample``,
@@ -48,7 +49,7 @@ class GenPrograms:
         import jax
         import jax.numpy as jnp
 
-        from ..nn.generation import (cache_spec, decode_forward,
+        from ..nn.generation import (as_paged, decode_forward, paged_parts,
                                      says_how_it_decodes, top_k_threshold)
         from ..nn.model import _layer_key
 
@@ -82,11 +83,11 @@ class GenPrograms:
                                 top_k[None])[0]
 
         self.pools = build_pools(mdl, kv_blocks, block_size, mdl.dtype)
-        self._lks = [lk for lk, _, _ in cache_spec(mdl)]
-        lks = self._lks
-        # layers with experts (layers/olmoe.py) report what routing did
+        # layer key -> the parts its pools hold (k and v; latent and rope)
+        names = {lk: tuple(pool) for lk, pool in self.pools.items()}
+        # layers with experts (layers/experts.py) report what routing did
         # to the rows marked live; the sums leave each program as three
-        # int32 (nn.layers.olmoe.ROUTING_FIELDS). A model without such
+        # int32 (nn.layers.experts.ROUTING_FIELDS). A model without such
         # layers builds the programs it always built
         routed = [_layer_key(i, layer)
                   for i, layer in enumerate(mdl.layers)
@@ -99,9 +100,7 @@ class GenPrograms:
         self._routing_pending: List[Any] = []
 
         def _as_caches(pools, tables, live=None):
-            caches = {lk: {"k_pool": pools[lk]["k"],
-                           "v_pool": pools[lk]["v"],
-                           "tables": tables} for lk in lks}
+            caches = {lk: as_paged(pools[lk], tables) for lk in names}
             for lk in routed:
                 caches[lk]["live"] = live
             return caches
@@ -110,8 +109,8 @@ class GenPrograms:
             return sum(caches[lk]["routing"] for lk in routed)
 
         def _as_pools(caches):
-            return {lk: {"k": caches[lk]["k_pool"],
-                         "v": caches[lk]["v_pool"]} for lk in lks}
+            return {lk: paged_parts(caches[lk], parts)
+                    for lk, parts in names.items()}
 
         def _prefill_chunk_fn(params, state, ids, pools, table_row, pos,
                               true_len):
@@ -268,7 +267,7 @@ class GenPrograms:
     # ---------------------------------------------------------------- pools
     def copy_blocks(self, pairs: List[tuple]) -> None:
         """Copy-on-write device work: duplicate each ``(src, dst)`` block
-        row in every layer's K/V pool. Eager indexed updates — deliberately
+        row in every pool of every layer. Eager indexed updates — deliberately
         NOT a jit site, so the committed compile-surface budget (decode ==
         one executable) is untouched; the indices ride as device operands,
         so XLA's eager cache reuses one executable per pool shape."""
@@ -278,10 +277,9 @@ class GenPrograms:
                                       len(pairs)))
         dst = jnp.asarray(np.fromiter((p[1] for p in pairs), np.int32,
                                       len(pairs)))
-        for lk in self._lks:
-            pool = self.pools[lk]
-            pool["k"] = pool["k"].at[dst].set(pool["k"][src])
-            pool["v"] = pool["v"].at[dst].set(pool["v"][src])
+        for pool in self.pools.values():
+            for n in pool:
+                pool[n] = pool[n].at[dst].set(pool[n][src])
 
     def aot_functions(self) -> dict:
         """Tag -> :class:`~..aot.AotFunction` for every store-backed
