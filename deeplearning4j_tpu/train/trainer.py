@@ -357,10 +357,15 @@ class Trainer:
             return _mesh_ctx(None), {}
         from jax.sharding import NamedSharding, PartitionSpec as P
 
+        from ..parallel.sharding import collective_overlap_options
+
         jit_kw = {"out_shardings": (
             jax.tree.map(lambda a: a.sharding, self.params),
             jax.tree.map(lambda a: a.sharding, self.opt_state),
             *([None] * n_unpinned_outputs), NamedSharding(self.mesh, P()))}
+        options = collective_overlap_options(self.mesh)
+        if options:
+            jit_kw["compiler_options"] = options
         return _mesh_ctx(self.mesh), jit_kw
 
     # --- the jitted train step ---
