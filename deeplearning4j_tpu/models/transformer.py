@@ -216,6 +216,84 @@ class Glm4MoeLiteLM(ZooModel):
         return b.build()
 
 
+@register_model
+class LagunaLM(ZooModel):
+    """Laguna (``poolside/Laguna-S-2.1``, ``laguna``): decoder layers of two
+    kinds, full attention (YaRN on half of each head) at every ``period``-th
+    layer from the first and sliding-window attention (plain rope) between,
+    with DIFFERENT numbers of query heads and one output gate a head;
+    ``first_k_dense`` leading layers with a dense SwiGLU and the rest with
+    softmax-routed experts beside a shared one (``nn/layers/laguna.py``), a
+    final RMSNorm and an untied, bias-free head. The defaults are the
+    published sizes. ``experts_held = (first, count)`` builds one chip's share
+    of an expert-parallel deployment: every expert layer holds ``count`` of
+    the ``num_experts`` its router chooses among. ``dtype`` is the dtype the
+    parameters and the cache are HELD in, as for ``OlmoeLM``."""
+
+    input_shape = (8192,)
+
+    def __init__(self, num_classes=None, seed=12345, input_shape=None, *,
+                 num_layers=48, first_k_dense=1, period=4, d_model=3072,
+                 full_heads=48, sliding_heads=72, num_kv_heads=8,
+                 head_dim=128, window=512, dense_width=12288,
+                 num_experts=256, top_k=10, expert_width=1024,
+                 shared_width=1024, routed_scale=2.5, experts_held=None,
+                 router_score="softmax", gate_act="sigmoid",
+                 full_rope_base=5e5, full_rotary_dim=64, yarn_factor=128.0,
+                 yarn_original=8192, yarn_beta_fast=32.0, yarn_beta_slow=1.0,
+                 attention_factor=1.4852030263919618, sliding_rope_base=1e4,
+                 vocab=100352, rms_eps=1e-6, dtype="float32", **kw):
+        super().__init__(num_classes, seed, input_shape, **kw)
+        self.num_layers, self.first_k_dense = num_layers, first_k_dense
+        self.period = period
+        self.d_model, self.vocab = d_model, vocab
+        self.num_classes = vocab
+        self.rms_eps = rms_eps
+        self.dtype = dtype
+        kinds = {
+            "full": dict(
+                num_heads=full_heads, window=None, rope_base=full_rope_base,
+                rotary_dim=full_rotary_dim, yarn_factor=yarn_factor,
+                yarn_original=yarn_original, yarn_beta_fast=yarn_beta_fast,
+                yarn_beta_slow=yarn_beta_slow,
+                attention_factor=attention_factor),
+            "sliding": dict(num_heads=sliding_heads, window=window,
+                            rope_base=sliding_rope_base)}
+        ffns = {
+            "dense": dict(num_experts=0, ffn_width=dense_width),
+            "experts": dict(
+                num_experts=num_experts, top_k=top_k, ffn_width=expert_width,
+                shared_width=shared_width, routed_scale=routed_scale,
+                experts_held=(None if experts_held is None
+                              else tuple(experts_held)),
+                router_score=router_score)}
+        self.blocks = {
+            (kind, ffn): L.LagunaBlock(
+                num_kv_heads=num_kv_heads, head_dim=head_dim,
+                gate_act=gate_act, eps=rms_eps, **kinds[kind], **ffns[ffn])
+            for kind in kinds for ffn in ffns}
+
+    def layer_kind(self, i: int):
+        """(attention kind, feed-forward kind) of layer ``i``."""
+        return ("full" if i % self.period == 0 else "sliding",
+                "dense" if i < self.first_k_dense else "experts")
+
+    def build(self) -> Sequential:
+        init = "normal_0.02"   # initializer_range
+        b = (SequentialBuilder(NetConfig(
+                seed=self.seed, dtype=self.dtype,
+                updater={"type": "adamw", "learning_rate": 3e-4}))
+             .input_shape(self.input_shape[0])
+             .layer(L.EmbeddingSequence(n_in=self.vocab, n_out=self.d_model,
+                                        weight_init=init)))
+        for i in range(self.num_layers):
+            b.layer(self.blocks[self.layer_kind(i)])
+        b.layer(L.RMSNorm(eps=self.rms_eps))
+        b.layer(L.RnnOutput(n_out=self.vocab, activation="softmax",
+                            loss="mcxent", use_bias=False, weight_init=init))
+        return b.build()
+
+
 # ---------------------------------------------------------------------------
 # Fully-sharded training step: dp x tp x sp over one mesh.
 # ---------------------------------------------------------------------------

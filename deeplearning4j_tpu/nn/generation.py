@@ -72,6 +72,24 @@ _TOKEN_LOCAL = (ActivationLayer, AlphaDropout, Dense, DropoutLayer,
 #     block 0. (``as_paged`` / ``paged_parts`` go between a layer's pools
 #     and this dictionary; a KV layer's comes out as ``k_pool``/``v_pool``.)
 #
+# A layer may also state a WINDOW for its cache (``cache_window`` on the
+# layer; ``cache_parts`` carries it as ``parts.window``): no query ever
+# reads a position more than ``window - 1`` behind it. The dense layout
+# keeps such a layer at full capacity and masks the band; the paged layout
+# gives it a RING: its ``tables`` are ``(B, R)`` with ``R = ring_blocks(
+# window, largest chunk, bs)`` columns, logical block ``b`` lives in column
+# ``b % R``, and the pool behind it is sized to the rings, not to the
+# capacity. Writes go through ``cache_write(..., ring=True)``, the gather
+# is the same gather (``R * bs`` positions a row), and ``ring_positions``
+# says which absolute position each gathered column holds, from which
+# ``causal_valid(..., kpos=)`` masks: written before read, not older than
+# the window, not from the ring's previous lap. The slack in ``R`` is what
+# a right-padded chunk's garbage lands in: never on a position a real query
+# of that chunk can see. Which physical block a column points at is the
+# allocator's business (serve/paged.py: a block behind every live window
+# is released and its column zeroed, so the column's next block is a newly
+# allocated one and a block shared with another holder is never written).
+#
 # ``cache_write`` / ``cache_gather`` are the only two operations either
 # layout supports (``cache_append`` / ``cache_read`` are their ``k``/``v``
 # face); everything above them (masking, rope, GQA, a latent's absorbed
@@ -102,11 +120,37 @@ def paged_parts(cache, names):
     return {n: cache[f"{n}_pool"] for n in names}
 
 
-def cache_write(cache, parts, pos):
+def ring_blocks(window: int, chunk: int, block_size: int) -> int:
+    """Columns of a window layer's ring table: the blocks that hold the
+    ``window - 1`` positions behind a chunk's first query and the chunk
+    itself (``chunk``: the widest a program writes at once, padding
+    included), plus one because neither end is block-aligned."""
+    return -(-(int(window) - 1 + int(chunk)) // int(block_size)) + 1
+
+
+def ring_positions(tables, bs: int, pos, Tq: int):
+    """The absolute position each column of a RING cache's gather holds,
+    (B, R * bs) int32, for ring ``tables`` (B, R) over blocks of ``bs``
+    and a chunk of ``Tq`` queries at offset ``pos`` (scalar or (B,)):
+    column ``c`` holds the newest logical block ``b <= (pos + Tq - 1) //
+    bs`` with ``b % R == c``. Negative where the ring has not come round
+    to the column yet."""
+    B, R = tables.shape
+    p = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    newest = (p + (Tq - 1)) // bs                                   # (B,)
+    cols = jnp.arange(R, dtype=jnp.int32)[None]
+    blk = newest[:, None] - (newest[:, None] - cols) % R            # (B, R)
+    kpos = blk[:, :, None] * bs + jnp.arange(bs, dtype=jnp.int32)
+    return kpos.reshape(B, R * bs)
+
+
+def cache_write(cache, parts, pos, *, ring: bool = False):
     """Write a chunk's ``parts`` ``{name: (B, Tq, *shape)}`` at absolute
     offset ``pos`` (scalar or (B,) vector). Returns the updated cache (same
     layout, same shapes — never shape-changing, so writes inside jit never
-    trigger a recompile), holding the parts written and, paged, the tables."""
+    trigger a recompile), holding the parts written and, paged, the tables.
+    ``ring``: a paged cache's tables are a window layer's ring (logical
+    block ``b`` in column ``b % R``); a dense cache takes no notice."""
     B, Tq = next(iter(parts.values())).shape[:2]
     pv = _pos_vec(pos)
     if "tables" in cache:  # paged
@@ -118,9 +162,12 @@ def cache_write(cache, parts, pos):
         wpos = p[:, None] + jnp.arange(Tq, dtype=jnp.int32)[None]  # (B, Tq)
         blk, off = wpos // bs, wpos % bs
         rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-        # logical blocks past the table (right-padded garbage) -> trash 0
-        phys = jnp.where(blk < maxb,
-                         tables[rows, jnp.minimum(blk, maxb - 1)], 0)
+        if ring:
+            phys = tables[rows, blk % maxb]
+        else:
+            # logical blocks past the table (right-padded garbage) -> trash 0
+            phys = jnp.where(blk < maxb,
+                             tables[rows, jnp.minimum(blk, maxb - 1)], 0)
         out = {}
         for n, a in parts.items():
             pool = cache[f"{n}_pool"]
@@ -143,7 +190,8 @@ def cache_gather(cache, names):
     Paged: a block-table gather (L = maxb * bs); entries past a row's live
     length are garbage the caller MUST mask causally (cache_write's
     invariant guarantees every position <= the current offset holds real
-    data)."""
+    data). A ring table gathers the same way, ``R * bs`` positions a row in
+    COLUMN order: :func:`ring_positions` says what each is."""
     if "tables" not in cache:
         return tuple(cache[n] for n in names)
     tables = cache["tables"]
@@ -206,18 +254,28 @@ def _mha_decode(num_heads: int, params, x, cache, pos, *, rope=False,
     return y, cache
 
 
-def causal_valid(pos, Tq: int, C: int, window=None):
+def causal_valid(pos, Tq: int, C: int, window=None, kpos=None):
     """Which of a cache's ``C`` slots each of a chunk's ``Tq`` queries at
-    offset ``pos`` may see: slots 0..pos+t. (Tq, C) for a scalar ``pos``,
-    (B, Tq, C) for a (B,) vector."""
+    offset ``pos`` may see: slots 0..pos+t, and with ``window`` only the
+    last ``window`` of them (the band mask of a sliding-window layer whose
+    cache is kept at capacity: the dense layout, or a paged layer that
+    states no ``cache_window``). (Tq, C) for a scalar ``pos``, (B, Tq, C)
+    for a (B,) vector.
+
+    ``kpos`` (B, C): the slots are a ring's gathered columns and hold these
+    absolute positions (:func:`ring_positions`); a query sees a slot whose
+    position is not after its own, not older than ``window``, and not
+    negative (a column the ring has not reached). Always (B, Tq, C)."""
     pv = _pos_vec(pos)
+    if kpos is not None:
+        p = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), kpos.shape[:1])
+        qpos = p[:, None, None] + jnp.arange(Tq)[None, :, None]  # (B,Tq,1)
+        k = kpos[:, None, :]
+        return (k <= qpos) & (k >= 0) & (qpos - k < window)
     if pv is None:
         qpos = pos + jnp.arange(Tq)[:, None]
         valid = jnp.arange(C)[None, :] <= qpos  # (Tq, C)
         if window is not None:
-            # sliding window: only the last `window` cache slots are visible
-            # (cache stays full-capacity; the band mask honors the training
-            # semantics — a ring-buffer cache is a future memory optimization)
             valid = valid & (qpos - jnp.arange(C)[None, :] < window)
         return valid
     qpos = pv[:, None, None] + jnp.arange(Tq)[None, :, None]  # (B,Tq,1)
@@ -267,16 +325,26 @@ def attend_cached(q, k, v, cache, pos, *, window=None):
     return y, cache
 
 
+class Parts(dict):
+    """``{part: trailing shape}`` of one layer's cache, and the ``window``
+    the layer states for it (None: every position is kept)."""
+
+    window: Optional[int] = None
+
+
 def cache_parts(model: Sequential):
     """The cached layers of ``model`` as ``[(layer_key, {part: shape}), ...]``:
     for each the named parts of its cache and the trailing shape ONE token
     takes in each — ``{"k": (kv_heads, head_dim), "v": (kv_heads,
     head_dim)}`` for KV-cached attention, ``{"latent": (512,), "rope":
     (64,)}`` for a layer that caches a latent vector and one rope key a token
-    and no heads. It is everything a cache
-    builder (serve/paged.py block pools, external runtimes) needs without
-    walking layer internals; a window ring or a recurrent state would be
-    further part names with shapes of their own. Recurrent carries are NOT
+    and no heads. Each is a :class:`Parts`: ``parts.window`` is the cache
+    LENGTH a sliding-window layer states (512: the paged layout gives it a
+    ring of that reach and pools to match, ``serve/paged.py`` groups layers
+    by it), None for a layer that keeps every position. It is everything a
+    cache builder (serve/paged.py block pools, external runtimes) needs
+    without walking layer internals; a recurrent state would be further
+    part names with shapes of their own. Recurrent carries are NOT
     listed: they are opaque layer-owned state with no write/gather
     contract."""
     spec = []
@@ -310,23 +378,33 @@ def says_how_it_decodes(layer) -> bool:
     ``(kv_heads, head_dim)`` — keys and values, parts ``k`` and ``v`` of
     that shape — or ``{part: trailing shape}`` for a cache of other parts
     (``{"latent": (512,), "rope": (64,)}``); ``decode`` finds the cache it
-    is handed in the layout contract above under those names. What is left
+    is handed in the layout contract above under those names. A layer with
+    an attribute ``cache_window`` (an int) states that its cache need not
+    reach further back, and is handed a ring when paged. What is left
     on the ladders is the dense block and the bare attention layer."""
     return hasattr(layer, "decode") and hasattr(layer, "cache_spec")
 
 
 def _layer_parts(layer, input_shape):
-    """``{part: trailing shape}`` of the cache ``layer`` decodes against,
-    or None for a layer that keeps none."""
+    """``{part: trailing shape}`` (:class:`Parts`) of the cache ``layer``
+    decodes against, or None for a layer that keeps none."""
     kv = None
     if says_how_it_decodes(layer):
         kv = layer.cache_spec(input_shape)
-        if isinstance(kv, dict):
-            return {n: tuple(shape) for n, shape in kv.items()}
     elif isinstance(layer, (TransformerEncoderBlock, MultiHeadAttention)):
         hd = input_shape[-1] // layer.num_heads
         kv = (layer.num_kv_heads or layer.num_heads), hd  # GQA: smaller
-    return None if kv is None else {"k": tuple(kv), "v": tuple(kv)}
+    if kv is None:
+        return None
+    if isinstance(kv, dict):
+        parts = Parts((n, tuple(shape)) for n, shape in kv.items())
+    else:
+        parts = Parts(k=tuple(kv), v=tuple(kv))
+    # only a layer that says how it decodes can state a cache window: the
+    # ``window=`` of the ladder's attention layers is a band mask over a
+    # cache kept at capacity
+    parts.window = getattr(layer, "cache_window", None)
+    return parts
 
 
 def init_caches(model: Sequential, batch: int, capacity: int, dtype):

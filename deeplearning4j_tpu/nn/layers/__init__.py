@@ -17,6 +17,7 @@ from .core import (ActivationLayer, AlphaDropout, CenterLossOutput,
 from .custom import CustomLayer, Lambda, resolve_function
 from .moe import MoE, MoETransformerBlock
 from .glm4_moe_lite import Glm4MoeLiteBlock
+from .laguna import LagunaBlock
 from .norm import LRN, BatchNorm, LayerNorm, RMSNorm
 from .olmoe import OlmoeBlock
 from .pooling import Flatten, GlobalPooling, Reshape
@@ -33,7 +34,7 @@ __all__ = [
     "ElementWiseMultiplication", "Embedding", "EmbeddingSequence",
     "GaussianDropout", "GaussianNoise", "Flatten",
     "Frozen", "GRU", "Glm4MoeLiteBlock", "GlobalPooling", "GravesLSTM", "LRN", "LSTM", "Lambda",
-    "LastTimeStep",
+    "LagunaBlock", "LastTimeStep",
     "LayerNorm", "LossLayer", "MoE", "MoETransformerBlock",
     "MultiHeadAttention", "OlmoeBlock", "Output", "PReLU",
     "PositionalEmbedding", "RMSNorm", "RecurrentLayer", "Reshape", "RnnLossLayer", "RnnOutput",

@@ -19,6 +19,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...ops import activations, initializers
 from ..api import Array, Layer, Shape, apply_input_dropout, register_layer
@@ -69,6 +70,53 @@ def rope_rotate(x, positions, base: float = 10000.0):
     x2 = x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin,
                             x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+
+
+def rope_inv_freq(dim: int, base: float):
+    """The ``dim // 2`` inverse frequencies ``base ** (-2i / dim)`` of a
+    plain rope over ``dim`` rotated dimensions (numpy, float32)."""
+    return (base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+            ).astype(np.float32)
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float, original: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """The ``dim // 2`` inverse frequencies of a YaRN-scaled rope over
+    ``dim`` rotated dimensions (numpy, float32): each plain frequency
+    ``base ** (-2i / dim)`` blended with itself divided by ``factor``, by
+    a linear ramp over the correction range of (``beta_fast``,
+    ``beta_slow``): dimensions that turn more than ``beta_fast`` times
+    within the ``original`` context keep their frequency, those that turn
+    less than ``beta_slow`` times are interpolated, the ramp between."""
+    freq = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction_dim(rotations):
+        return (dim * np.log(original / (rotations * 2 * np.pi))
+                / (2 * np.log(base)))
+
+    low = max(np.floor(correction_dim(beta_fast)), 0)
+    high = min(np.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return (freq / factor * ramp + freq * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_rotate_freqs(x, positions, inv_freq, scale: float = 1.0):
+    """:func:`rope_rotate` at given inverse frequencies, on the FIRST
+    ``2 * len(inv_freq)`` dimensions of each head (pairs ``i`` with ``i +
+    len(inv_freq)``); the dimensions after them pass unrotated (a partial
+    rotary factor). ``scale`` multiplies cos and sin (YaRN's
+    ``attention_factor``). f32 angles, the result in ``x``'s dtype."""
+    half = len(inv_freq)
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
+    lead = None if ang.ndim == 2 else slice(None)
+    cos = (jnp.cos(ang) * scale)[lead, :, None, :]
+    sin = (jnp.sin(ang) * scale)[lead, :, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:2 * half].astype(jnp.float32)
+    rot = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return jnp.concatenate([rot.astype(x.dtype), x[..., 2 * half:]], axis=-1)
 
 
 def _flash_attend(q, k, v, *, causal, lengths, key_mask, window):

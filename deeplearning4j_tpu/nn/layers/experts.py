@@ -29,6 +29,13 @@ ROUTING_FIELDS = {
                        "layers",
     "max_load": "rows of the fullest expert, summed over layers",
 }
+# a layer that HOLDS a share of its experts (``held``: the others live on
+# other chips) counts the three sums above over the held experts only, and
+# reports a fourth
+ELSEWHERE_FIELDS = {
+    "assignments_elsewhere": "token-expert pairs of real tokens whose expert "
+                             "this layer does not hold",
+}
 
 
 def wide_einsum(spec: str, a, w):
@@ -42,13 +49,24 @@ def wide_einsum(spec: str, a, w):
                       preferred_element_type=jnp.float32)
 
 
-def assign(gate, idx, num_experts: int, live, rows_shape):
+def assign(gate, idx, num_experts: int, live, rows_shape, held=None):
     """``gate``/``idx`` (N, k): each token's gates and the experts they
     belong to. Returns the weight of every expert for every token (N, E),
     0 where it was not chosen, and what routing did to the rows ``live``
     marks (broadcastable to ``rows_shape``, the token axes before they were
-    flattened to N; None: nobody asked) as ROUTING_FIELDS' three sums."""
-    chosen = jax.nn.one_hot(idx, num_experts, dtype=jnp.int32)   # (N, k, E)
+    flattened to N; None: nobody asked) as ROUTING_FIELDS' three sums.
+
+    ``held = (first, count)``: the layer holds experts ``first .. first +
+    count - 1`` of the ``num_experts`` the router chose among. The weights
+    come back (N, count), a pair whose expert lives elsewhere weighs
+    nothing here, the three sums count the held experts, and a fourth
+    (ELSEWHERE_FIELDS) the pairs that left."""
+    if held is None:
+        chosen = jax.nn.one_hot(idx, num_experts, dtype=jnp.int32)  # (N, k, E)
+    else:
+        first, count = held
+        # an index outside 0..count-1 is a row of zeros
+        chosen = jax.nn.one_hot(idx - first, count, dtype=jnp.int32)
     # sums, not matmuls: a TPU's default matmul would round the gates to
     # bf16 on the way
     weight = jnp.sum(gate[:, :, None] * chosen, axis=1)          # (N, E)
@@ -56,8 +74,10 @@ def assign(gate, idx, num_experts: int, live, rows_shape):
     if live is not None:
         rows = jnp.broadcast_to(live, rows_shape).reshape(-1)
         load = jnp.sum(chosen * rows[:, None, None], axis=(0, 1))
-        routing = jnp.stack([jnp.sum(load), jnp.sum(load > 0),
-                             jnp.max(load)]).astype(jnp.int32)
+        sums = [jnp.sum(load), jnp.sum(load > 0), jnp.max(load)]
+        if held is not None:
+            sums.append(jnp.sum(rows) * idx.shape[1] - jnp.sum(load))
+        routing = jnp.stack(sums).astype(jnp.int32)
     return weight, routing
 
 

@@ -46,6 +46,9 @@ GEN_TICK_PUBLISH = "gen.tick.publish"    # metrics, bookkeeping, pushes, finishe
 GEN_TURN = "gen.turn"                    # the worker's turn after admission: the
 #   chunks and the tick nest in it; its own time is what lies between them
 #   (leases, the step's arrays freed, the interpreter lock lent to writers)
+GEN_KV_RELEASE = "gen.kv_release"        # inside gen.tick.prepare, only for a
+#   model with a window block group: blocks behind the windows released, the
+#   rings grown (not in SPAN_NAMES: a model without the group never opens it)
 HTTP_STREAM_WRITE = "http.stream_write"  # serve/http.py: one SSE event written
 GC_PAUSE = "gc.pause"                    # one collection; generation=0|1|2
 SPAN_NAMES = (GEN_ADMIT, GEN_PREFILL_CHUNK, GEN_FIRST_TOKEN, GEN_TICK,

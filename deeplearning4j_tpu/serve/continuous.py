@@ -57,6 +57,17 @@ block — one int32 row copy, never KV bytes. All sharing is host-side
 bookkeeping: the decode step stays ONE executable for the server lifetime,
 enforced by the committed compile-surface budget.
 
+A model whose layers state a cache window (sliding-window attention:
+``nn.generation.cache_parts``) gets a second block GROUP beside the one
+above (``serve/paged.py``): pools sized to the windows, an allocator and a
+ring table ``(slots, R)`` of its own. Admission commits in both groups; each
+tick and each prefill chunk first releases the ring blocks that lie wholly
+behind the window, then grows the ring; the prefix cache keeps window blocks
+under the same hashes and a hit is shortened to what the window's tail
+still covers; copy-on-write and forks work per group. Nothing selects this
+but the model's cache spec, and a model with one group is served exactly as
+before.
+
 The bit-exact baseline for all of it is whole-batch
 ``nn.generation.generate`` over its contiguous caches.
 
@@ -81,8 +92,9 @@ from .engine import PrefillScheduler
 from .errors import (CapacityError, DeadlineExceededError, DrainTimeoutError,
                      ServeError, ServerClosingError, ShedError,
                      WorkerStallError)
-from .paged import (BlockAllocator, PrefixCache, SlotPages, block_bytes,
-                    blocks_needed, prefix_hashes)
+from .paged import (FULL, WINDOW, BlockAllocator, PrefixCache, RingPages,
+                    SlotPages, WindowGroup, block_bytes, blocks_needed,
+                    cache_groups, prefix_hashes)
 from .programs import GenPrograms
 from .registry import ModelRegistry
 
@@ -235,14 +247,16 @@ class _PrefillJob:
     """One prompt mid-prefill: its slot, block pages, and chunk cursor."""
 
     __slots__ = ("req", "slot", "pages", "chunks", "idx", "worst", "last",
-                 "shared", "hashes", "gens")
+                 "shared", "hashes", "gens", "ring")
 
     def __init__(self, req: _GenRequest, slot: int, pages: SlotPages,
                  chunks: List[tuple], worst: int, shared: int = 0,
-                 hashes: Optional[List[bytes]] = None):
+                 hashes: Optional[List[bytes]] = None,
+                 ring: Optional[RingPages] = None):
         self.req = req
         self.slot = slot
         self.pages = pages
+        self.ring = ring        # its blocks in the window group, if any
         self.chunks = chunks    # [(offset, true_len, padded_bucket), ...]
         self.idx = 0
         self.worst = worst      # committed worst-case blocks (non-shared)
@@ -344,12 +358,35 @@ class ContinuousBatcher:
         else:
             self._chunk_buckets = self.prompt_buckets
         self._alloc = BlockAllocator(self.kv_blocks)
+        # block groups (serve/paged.py): everything above and below that
+        # bears no group's name is the FULL group's; layers that state a
+        # cache window share a second one, sized to the windows
+        groups = {g.name: g for g in cache_groups(model)}
+        if FULL not in groups:
+            raise ValueError("every cached layer states a cache window: the "
+                             "batcher needs a layer that keeps every position")
+        self._block_bytes = block_bytes(model, self.block_size, model.dtype,
+                                        groups[FULL].layers)
+        self._win: Optional[WindowGroup] = None
+        if WINDOW in groups:
+            self._win = WindowGroup(
+                groups[WINDOW], slots=S, block_size=self.block_size,
+                chunk=self._chunk_buckets[-1],
+                block_bytes=block_bytes(model, self.block_size, model.dtype,
+                                        groups[WINDOW].layers),
+                cached_tails=S if prefix_cache else 0)
         self._prefix: Optional[PrefixCache] = None
         if prefix_cache:
-            self._prefix = PrefixCache(self._alloc, self.block_size,
-                                       prefix_cache_blocks)
+            win = self._win
+            self._prefix = PrefixCache(
+                self._alloc, self.block_size, prefix_cache_blocks,
+                **({} if win is None else dict(
+                    window_allocator=win.alloc, window_tail=win.tail,
+                    window_max_blocks=win.cache_blocks)))
             # cached-but-idle runs are reclaimed before anyone sheds
             self._alloc.set_reclaimer(self._prefix.reclaim)
+            if win is not None:
+                win.alloc.set_reclaimer(self._prefix.reclaim_window)
         # distinct physical blocks slots hold via retain (adopted prefix
         # runs, fork rows) — these sit OUTSIDE every worst-case
         # commitment, so admission subtracts them from the pool
@@ -363,7 +400,9 @@ class ContinuousBatcher:
         self._slot_pages: List[Optional[SlotPages]] = [None] * S
         self._slot_worst = np.zeros(S, np.int64)
         self._committed = 0
-        self._block_bytes = block_bytes(model, self.block_size, model.dtype)
+        # the window group's twin of _slot_pages (a ring carries its own
+        # commitment)
+        self._slot_ring: List[Optional[RingPages]] = [None] * S
 
         self._base_key = jax.random.PRNGKey(seed)
 
@@ -430,11 +469,36 @@ class ContinuousBatcher:
             help="allocated / allocatable KV blocks")
         self._m_kv_bytes = m.gauge(
             "serve_kv_live_bytes", self._lbl(),
-            help="bytes of KV pool backing live tokens (all layers)")
+            help="bytes of KV pool backing live tokens (all layers, all "
+                 "block groups)")
         m.gauge("serve_kv_token_bytes", self._lbl(),
                 help="bytes of cache one token takes in the pools that were "
-                     "built, all layers and all parts"
-                ).set(self._block_bytes // self.block_size)
+                     "built, all layers (of every block group) and all parts"
+                ).set((self._block_bytes + (self._win.block_bytes
+                                            if self._win else 0))
+                      // self.block_size)
+        self._m_group_used = {
+            g: m.gauge("serve_kv_group_blocks_used", self._lbl({"group": g}),
+                       help="KV blocks currently allocated, by block group")
+            for g in groups}
+        self._m_group_bytes = {
+            g: m.gauge("serve_kv_group_live_bytes", self._lbl({"group": g}),
+                       help="bytes of KV pool backing live tokens, by block "
+                            "group (they add up to serve_kv_live_bytes)")
+            for g in groups}
+        if self._win is not None:
+            self._m_win_released = m.counter(
+                "serve_kv_window_released_total", self._lbl(),
+                help="window-group blocks released because they lay wholly "
+                     "behind their slot's window")
+            self._m_win_alloc = m.counter(
+                "serve_kv_window_allocated_total", self._lbl(),
+                help="window-group blocks allocated to slots' rings")
+            self._m_px_short = m.counter(
+                "serve_prefix_hits_shortened_total", self._lbl(),
+                help="admissions whose cached prefix run was cut short (or "
+                     "to nothing) because the window group no longer held "
+                     "the window's tail behind it")
         self._m_pf_depth = m.gauge(
             "serve_prefill_queue_depth", self._lbl(),
             help="prompts mid-prefill (chunked jobs in flight)")
@@ -491,21 +555,24 @@ class ContinuousBatcher:
         # never traces in the request path after boot ---
         self._aot = aot_store
         snap0 = self.registry.current()
+        win = self._win
         self._programs = GenPrograms(
-            model, slots=S, table_blocks=self._maxb, vocab=V,
-            kv_blocks=self.kv_blocks, block_size=self.block_size,
+            model, slots=S, vocab=V,
+            table_blocks=self._maxb if win is None
+            else {FULL: self._maxb, WINDOW: win.columns},
+            kv_blocks=self.kv_blocks if win is None
+            else {FULL: self.kv_blocks, WINDOW: win.alloc.num_blocks},
+            block_size=self.block_size,
             chunk_buckets=self._chunk_buckets, metrics=m,
             compile_counter=self._m_compiles, store=aot_store,
             strict=self.strict_aot, snapshot=snap0)
         # what routing did, per program kind; nothing for a model without
         # experts
         if self._programs.routed:
-            from ..nn.layers.experts import ROUTING_FIELDS
-
             self._m_routing = {
                 prog: [m.counter(f"serve_moe_{f}_total",
                                  self._lbl({"program": prog}), help=what)
-                       for f, what in ROUTING_FIELDS.items()]
+                       for f, what in self._programs.routing_fields.items()]
                 + [m.counter("serve_moe_layer_programs_total",
                              self._lbl({"program": prog}),
                              help="expert layers run: layers x decode steps "
@@ -810,11 +877,24 @@ class ContinuousBatcher:
                 raise ShedError(
                     f"fork(): insufficient KV block headroom (need {worst} "
                     f"committed + {fresh} shared)")
+            if self._win is not None and not self._win.fits(pos + max_new):
+                self._shed_counter("fork_capacity").inc()
+                raise ShedError("fork(): insufficient KV block headroom in "
+                                "the window group")
             child = _GenRequest(req.prompt, max_new,
                                 float(temperature if temperature is not None
                                       else req.temperature),
                                 top_k if top_k is not None else req.top_k,
                                 req.eos_id, req.deadline)
+            if self._win is not None:
+                # the parent's ring, block for block: a held range of
+                # logical blocks, shared until one of the two writes
+                held = [b for _, b in sorted(self._slot_ring[s].blocks.items())]
+                ring = self._win.open(pos + max_new)
+                self._win.alloc.retain(held)
+                ring.adopt(self._slot_ring[s].first, held)
+                self._slot_ring[t] = ring
+                self._win.tables_np[t] = self._win.tables_np[s]
             self._alloc.retain(blocks)
             pages = SlotPages(self._alloc, self.block_size)
             pages.adopt(blocks)
@@ -873,9 +953,17 @@ class ContinuousBatcher:
 
     def _update_kv_gauges(self) -> None:
         used = self._alloc.used
+        live = used * self._block_bytes
         self._m_kv_used.set(used)
         self._m_kv_util.set(used / self._alloc.usable)
-        self._m_kv_bytes.set(used * self._block_bytes)
+        self._m_group_used[FULL].set(used)
+        self._m_group_bytes[FULL].set(live)
+        if self._win is not None:
+            wused = self._win.alloc.used
+            self._m_group_used[WINDOW].set(wused)
+            self._m_group_bytes[WINDOW].set(wused * self._win.block_bytes)
+            live += wused * self._win.block_bytes
+        self._m_kv_bytes.set(live)
         self._m_px_shared.set(len(self._shared_ledger))
 
     # --- shared-block ledger: blocks held via retain (adoption/forks) sit
@@ -905,6 +993,35 @@ class ContinuousBatcher:
         row[:len(blocks)] = blocks
         self._tables_np[s] = row
 
+    # --- the window group's half of a slot (no-ops without the group) ---
+    def _ring_step(self, s: int, ring: RingPages, first_q: int,
+                   upto: int) -> None:
+        """Before a step whose first query sits at ``first_q`` and which
+        writes positions below ``upto``: release the ring's blocks wholly
+        behind that query's window, THEN map the blocks the step writes
+        (newly allocated: a column never keeps the block of a lap ago),
+        and write the slot's ring row."""
+        released = ring.release_behind(first_q)
+        new = ring.ensure(upto)
+        if released:
+            self._m_win_released.inc(released)
+        if new:
+            self._m_win_alloc.inc(len(new))
+        if released or new:       # one step in block_size moves a decode's
+            self._win.tables_np[s] = ring.row()
+
+    def _release_ring(self, s: int, ring: Optional[RingPages]) -> None:
+        if ring is not None:
+            self._win.close(s, ring)
+
+    def _table_rows(self, s: int):
+        """Slot ``s``'s table row as the prefill program takes it: one
+        ``(1, blocks)`` array, or one a group."""
+        row = self._tables_np[s:s + 1].copy()
+        if self._win is None:
+            return row
+        return {FULL: row, WINDOW: self._win.tables_np[s:s + 1].copy()}
+
     # --- admission: commit worst-case blocks, start a prefill job ---
     def _admit_locked(self, generation: int = 0) -> None:
         """Under ``self._cond``: hand free slots to queued requests as
@@ -933,6 +1050,16 @@ class ContinuousBatcher:
                 # must prefill so the first sample has logits to read
                 run = self._prefix.match(hashes, generation,
                                          (tp - 1) // self.block_size)
+            win, ring, ring_run = self._win, None, []
+            matched = len(run)
+            if win is not None:
+                if run:
+                    # usable only as far as the window's tail behind the
+                    # hit is still held in the window group
+                    n, ring_run = self._prefix.match_window(hashes, matched)
+                    run = run[:n]
+                if not win.fits(tp + req.max_new):
+                    break
             shared = len(run)
             worst = blocks_needed(tp + req.max_new, self.block_size) - shared
             fresh = sum(1 for b in run if b not in self._shared_ledger)
@@ -942,8 +1069,14 @@ class ContinuousBatcher:
             self._queue.pop(0)
             self._committed += worst
             pages = SlotPages(self._alloc, self.block_size)
+            if win is not None:
+                ring = win.open(tp + req.max_new)
+                # the hit's tail: logical blocks shared - len .. shared - 1
+                ring.adopt(shared - len(ring_run), ring_run)
+                if shared < matched:
+                    self._m_px_short.inc()
             if shared:
-                self._prefix.adopt(hashes, run)
+                self._prefix.adopt(hashes, run, ring_run)
                 pages.adopt(run)
                 self._ledger_add(run)
                 self._px_hits += 1
@@ -955,7 +1088,7 @@ class ContinuousBatcher:
             job = _PrefillJob(
                 req, s, pages,
                 self._plan_chunks(tp, shared * self.block_size), worst,
-                shared=shared, hashes=hashes)
+                shared=shared, hashes=hashes, ring=ring)
             self._slot_job[s] = job
             self._jobs.append(job)
         self._m_pf_depth.set(len(self._jobs))
@@ -966,6 +1099,7 @@ class ContinuousBatcher:
                 self._jobs.remove(job)
             self._slot_job[job.slot] = None
             self._release_pages(job.pages)
+            self._release_ring(job.slot, job.ring)
             self._committed -= job.worst
             self._write_table_row(job.slot, [])
             self._update_kv_gauges()
@@ -981,7 +1115,9 @@ class ContinuousBatcher:
                     return  # aborted (forced shutdown) since the tick was planned
                 job.pages.ensure(off + true_len)
                 self._write_table_row(job.slot, job.pages.blocks)
-                table_row = self._tables_np[job.slot:job.slot + 1].copy()
+                if job.ring is not None:
+                    self._ring_step(job.slot, job.ring, off, off + true_len)
+                table_row = self._table_rows(job.slot)
                 self._update_kv_gauges()
             if _prof.ACTIVE is not None:
                 # live prompt tokens vs the chunk bucket they padded to
@@ -1054,8 +1190,14 @@ class ContinuousBatcher:
                 # mid-prefill — that KV mixes generations and must retire
                 # with its slot, never be adopted
                 nfull = req.prompt.shape[0] // self.block_size
+                # ...and of what the ring still holds of them, the window's
+                # tail behind the prompt's end: what a hit will need
+                held = None if job.ring is None else {
+                    b: blk for b, blk in job.ring.blocks.items()
+                    if nfull - self._win.tail <= b < nfull}
                 self._prefix.insert(job.hashes[:nfull],
-                                    job.pages.blocks[:nfull], gen_now)
+                                    job.pages.blocks[:nfull], gen_now, held)
+                self._update_kv_gauges()
         if req.ctx is not None:
             # decode starts with the token-0 sample, not the first tick — a
             # request wedged before any tick completes still shows the stage
@@ -1072,6 +1214,7 @@ class ContinuousBatcher:
             self._slot_job[s] = None
             self._slot_pages[s] = job.pages
             self._slot_worst[s] = job.worst
+            self._slot_ring[s] = job.ring
             self._m_pf_depth.set(len(self._jobs))
             req.slot = s
             req.key = None
@@ -1110,6 +1253,8 @@ class ContinuousBatcher:
                 self._committed -= int(self._slot_worst[s])
                 self._slot_worst[s] = 0
                 self._write_table_row(s, [])
+                self._release_ring(s, self._slot_ring[s])
+                self._slot_ring[s] = None
                 self._update_kv_gauges()
             self._m_completed.inc()
             self._m_active.set(sum(1 for r in self._slot_req if r is not None))
@@ -1156,12 +1301,16 @@ class ContinuousBatcher:
                             self._cow_copies += 1
                             self._m_cow.inc()
                         self._write_table_row(s, pages.blocks)
+                    ring_cow = self._tick_rings(active)
                     self._update_kv_gauges()
                     mask = np.zeros(self.slots, bool)
                     mask[active] = True
                     # inactive rows: zero tables (writes -> trash),
                     # position 0
                     tables = np.where(mask[:, None], self._tables_np, 0)
+                    if self._win is not None:
+                        tables = {FULL: tables, WINDOW: np.where(
+                            mask[:, None], self._win.tables_np, 0)}
                     pos = np.where(mask, self._pos, 0).astype(np.int32)
                     toks = np.array(self._next_tok)
                     temps = np.array(self._temps)
@@ -1180,6 +1329,8 @@ class ContinuousBatcher:
                     # only ever touched by this worker thread), before the
                     # decode dispatch
                     self._programs.copy_blocks(cow)
+                if ring_cow:
+                    self._programs.copy_blocks(ring_cow, WINDOW)
                 nxt, new_keys = self._programs.decode(
                     params, snap.state, toks, tables, pos, keys, temps, topks)
             with _trace.span(_trace.GEN_TICK_READBACK):
@@ -1222,6 +1373,32 @@ class ContinuousBatcher:
                     req._push(tok)
                 for s in active:
                     self._maybe_finish(s)
+
+    def _tick_rings(self, active: List[int]) -> List[tuple]:
+        """Under ``self._cond``, inside the tick's prepare phase: the window
+        group's share of it. For every decoding slot, release the ring's
+        blocks behind the window of the token this tick writes, map its
+        block, and swap in a private copy where a fork peer still holds it
+        (the prefix cache never does: a ring's column always gets a newly
+        allocated block). Returns the ``(src, dst)`` copies to make."""
+        cow: List[tuple] = []
+        if self._win is None:
+            return cow
+        alloc = self._win.alloc
+        with _trace.span(_trace.GEN_KV_RELEASE):
+            for s in active:
+                ring, pos = self._slot_ring[s], int(self._pos[s])
+                self._ring_step(s, ring, pos, pos + 1)
+                wb = pos // self.block_size
+                blk = ring.blocks[wb]
+                if alloc.refcount(blk) > 1:
+                    new = alloc.alloc(1)[0]
+                    ring.swap(wb, new)
+                    cow.append((blk, new))
+                    self._cow_copies += 1
+                    self._m_cow.inc()
+                    self._win.tables_np[s] = ring.row()
+        return cow
 
     def _count_routing(self, program: str, sums) -> None:
         """One program's routing sums (ROUTING_FIELDS) into the counters."""
@@ -1342,6 +1519,7 @@ class ContinuousBatcher:
             self._queue.clear()
         for job in list(self._jobs):
             self._release_pages(job.pages)
+            self._release_ring(job.slot, job.ring)
             self._slot_job[job.slot] = None
             self._committed -= job.worst
             finish.append(job.req)
@@ -1355,6 +1533,8 @@ class ContinuousBatcher:
                 self._slot_pages[s] = None
                 self._committed -= int(self._slot_worst[s])
                 self._slot_worst[s] = 0
+            self._release_ring(s, self._slot_ring[s])
+            self._slot_ring[s] = None
         self._tables_np[:] = 0
         self._update_kv_gauges()
         self._m_pf_depth.set(0)
@@ -1416,6 +1596,15 @@ class ContinuousBatcher:
                    "blocks_shared": len(self._shared_ledger),
                    "cow_copies": self._cow_copies,
                    "forks": self._forks}
+            if self._win is not None:
+                w = self._win
+                out["live_bytes"] += w.alloc.used * w.block_bytes
+                out["window_group"] = {
+                    "window": w.window, "ring_blocks": w.columns,
+                    "blocks_total": w.alloc.usable,
+                    "blocks_used": w.alloc.used,
+                    "blocks_committed": w.committed,
+                    "live_bytes": w.alloc.used * w.block_bytes}
             if self._prefix is not None:
                 px = self._prefix.stats()
                 px["hits"] = self._px_hits

@@ -37,9 +37,23 @@ exactly when a slot must write into a block someone else still references
 lists and hash maps, no device work here — the batcher performs the one
 CoW block copy on its own thread.
 
+**Two block groups.** A layer may state a window for its cache
+(``nn.generation.cache_parts``: a sliding-window attention layer never reads
+further back). Layers group by it (:func:`cache_groups`): the ``full`` group
+is everything above; the ``window`` group has pools, an allocator and tables
+of its own, sized to the windows and not to the capacity. A slot's table row
+there is a RING of ``R`` columns (:class:`RingPages`: logical block ``b`` in
+column ``b % R``); blocks that lie wholly behind the window are released as
+the sequence advances and their column zeroed, so the column's next block
+is a newly allocated one and a block shared with the prefix cache or a fork
+is never written. The prefix cache keeps window-group blocks under the same
+rolling hashes, and a hit is usable only as far as the window's tail behind
+it is still held (:meth:`PrefixCache.match_window`). A model whose layers
+state no window has one group and everything here is as it was.
+
 The device-side layout contract (how positions map into pools, the trash
-block, append/read semantics) lives in ``nn/generation.py`` next to
-``cache_write`` / ``cache_gather``; this module only decides *which*
+block, append/read semantics, the ring) lives in ``nn/generation.py`` next
+to ``cache_write`` / ``cache_gather``; this module only decides *which*
 physical blocks a slot owns.
 """
 
@@ -47,13 +61,15 @@ from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
 from .errors import CapacityError
 
 TRASH_BLOCK = 0  # physical block 0 is never allocated; see module docstring
+FULL, WINDOW = "full", "window"   # the block groups' names (metric labels)
 
 
 class BlockAllocator:
@@ -153,12 +169,44 @@ class BlockAllocator:
                 self._refs[b] = c - 1
 
 
-def build_pools(model, num_blocks: int, block_size: int, dtype) -> Dict:
+class CacheGroup(NamedTuple):
+    """The cached layers that share pools' length, an allocator and tables:
+    ``full`` (no window stated) or ``window`` (the window they state)."""
+
+    name: str
+    window: Optional[int]
+    layers: Tuple[str, ...]
+
+
+def cache_groups(model) -> List[CacheGroup]:
+    """``model``'s cached layers grouped by the cache window they state
+    (``nn.generation.cache_parts``), the full group first. One window value
+    a model: two different ones would be two rings of different reach."""
+    from ..nn.generation import cache_parts
+
+    spec = cache_parts(model)
+    if not spec:
+        raise ValueError("model has no attention layers to page")
+    windows = sorted({p.window for _, p in spec if p.window is not None})
+    if len(windows) > 1:
+        raise ValueError(f"cached layers state different windows {windows}: "
+                         f"one window group a model")
+    groups = [CacheGroup(FULL, None, tuple(
+        lk for lk, p in spec if p.window is None))]
+    if windows:
+        groups.append(CacheGroup(WINDOW, windows[0], tuple(
+            lk for lk, p in spec if p.window is not None)))
+    return [g for g in groups if g.layers]
+
+
+def build_pools(model, num_blocks, block_size: int, dtype) -> Dict:
     """Zero-filled block pools (device arrays) for every cached layer, one
     per part the layer's spec names (``nn.generation.cache_parts``):
     ``{layer_key: {part: (N, bs, *shape)}}`` — ``{"k": (N, bs, Hkv, hd),
     "v": ...}`` for KV-cached attention, ``{"latent": (N, bs, 512), "rope":
-    (N, bs, 64)}`` for a layer that caches a latent and a rope key a token."""
+    (N, bs, 64)}`` for a layer that caches a latent and a rope key a token.
+    ``num_blocks``: one number for every layer, or ``{group name: blocks}``
+    (:func:`cache_groups`) where the groups' pools differ in length."""
     import jax.numpy as jnp
 
     from ..nn.generation import cache_parts
@@ -166,21 +214,29 @@ def build_pools(model, num_blocks: int, block_size: int, dtype) -> Dict:
     spec = cache_parts(model)
     if not spec:
         raise ValueError("model has no attention layers to page")
-    return {lk: {n: jnp.zeros((num_blocks, block_size) + shape, dtype)
+
+    def n_of(parts):
+        if not isinstance(num_blocks, dict):
+            return num_blocks
+        return num_blocks[FULL if parts.window is None else WINDOW]
+
+    return {lk: {n: jnp.zeros((n_of(parts), block_size) + shape, dtype)
                  for n, shape in parts.items()}
             for lk, parts in spec}
 
 
-def block_bytes(model, block_size: int, dtype) -> int:
-    """Bytes one block holds across ALL cached layers and all the parts
-    their specs name (k + v; a latent and its rope key) — the unit the
-    live-KV-bytes gauge counts in, and ``block_size`` times what one token
-    costs."""
+def block_bytes(model, block_size: int, dtype,
+                layers: Optional[Sequence[str]] = None) -> int:
+    """Bytes one block holds across ALL cached layers (or those of
+    ``layers``: one group's) and all the parts their specs name (k + v; a
+    latent and its rope key) — the unit the live-KV-bytes gauge counts in,
+    and ``block_size`` times what one token costs."""
     from ..nn.generation import cache_parts
 
     itemsize = np.dtype(dtype).itemsize
     return sum(block_size * int(np.prod(shape)) * itemsize
-               for _, parts in cache_parts(model)
+               for lk, parts in cache_parts(model)
+               if layers is None or lk in layers
                for shape in parts.values())
 
 
@@ -219,12 +275,25 @@ class PrefixCache:
     adopted, because every entry of the old generation is released before
     the first new-generation lookup returns.
 
+    With a window group (``window_allocator``): a run's blocks of THAT
+    group are cached under the same hashes, in an LRU of their own of at
+    most ``window_max_blocks``, each entry one reference in the window
+    allocator. Only a run's tail is ever there (the ``window_tail`` blocks a
+    slot's ring still held behind the prompt's end when its prefill
+    finished; a longer prompt's tail replaces it), and a hit of ``n`` blocks
+    is usable only if the ``window_tail`` blocks behind it are
+    (:meth:`match_window`). An entry evicted from the full group takes its
+    window block with it.
+
     Not thread-safe by itself — the batcher serializes calls under its
     own lock, same as :class:`BlockAllocator`.
     """
 
     def __init__(self, allocator: BlockAllocator, block_size: int,
-                 max_blocks: Optional[int] = None):
+                 max_blocks: Optional[int] = None, *,
+                 window_allocator: Optional[BlockAllocator] = None,
+                 window_tail: int = 0,
+                 window_max_blocks: Optional[int] = None):
         self._alloc = allocator
         self.block_size = int(block_size)
         # hard size bound (entries == blocks); None = bounded only by the
@@ -234,6 +303,11 @@ class PrefixCache:
         self._runs: "OrderedDict[bytes, int]" = OrderedDict()
         self.evictions = 0
         self.flushes = 0
+        # the window group's blocks of cached runs, by the same hashes
+        self._walloc = window_allocator
+        self.window_tail = int(window_tail)
+        self.window_max_blocks = window_max_blocks
+        self._wruns: "OrderedDict[bytes, int]" = OrderedDict()
 
     def __len__(self) -> int:
         return len(self._runs)
@@ -256,6 +330,9 @@ class PrefixCache:
             self._alloc.release(list(self._runs.values()))
             self._runs.clear()
             self.flushes += 1
+        if self._wruns:
+            self._walloc.release(list(self._wruns.values()))
+            self._wruns.clear()
         return n
 
     def match(self, hashes: Sequence[bytes], generation: int,
@@ -272,23 +349,80 @@ class PrefixCache:
             run.append(b)
         return run
 
-    def adopt(self, hashes: Sequence[bytes], run: List[int]) -> None:
+    def match_window(self, hashes: Sequence[bytes],
+                     n: int) -> Tuple[int, List[int]]:
+        """The longest hit ``m <= n`` (in blocks) whose window tail — the
+        ``window_tail`` blocks behind it, or all ``m`` if fewer — the window
+        group still holds, and that tail's physical ids in logical order
+        (logical blocks ``m - len(tail) .. m - 1``). ``(0, [])`` where no
+        hit is usable. Pure lookup, like :meth:`match`."""
+        held = 0          # consecutive blocks held, ending at block i
+        best = 0
+        for i, h in enumerate(hashes[:max(0, int(n))]):
+            held = held + 1 if h in self._wruns else 0
+            if held >= min(self.window_tail, i + 1):
+                best = i + 1
+        tail = hashes[max(0, best - self.window_tail):best]
+        return best, [self._wruns[h] for h in tail]
+
+    def adopt(self, hashes: Sequence[bytes], run: List[int],
+              window_run: Sequence[int] = ()) -> None:
         """Take one reference per matched block and mark the run
         recently-used. ``run`` must be a fresh :meth:`match` result under
-        the same lock."""
+        the same lock (and ``window_run`` the tail :meth:`match_window`
+        gave for it)."""
         if not run:
             return
         self._alloc.retain(run)
         for h in hashes[:len(run)]:
             self._runs.move_to_end(h)
+        if window_run:
+            self._walloc.retain(window_run)
+            for h in hashes[len(run) - len(window_run):len(run)]:
+                self._wruns.move_to_end(h)
 
     def insert(self, hashes: Sequence[bytes], blocks: Sequence[int],
-               generation: int) -> int:
+               generation: int,
+               window_blocks: Optional[Dict[int, int]] = None) -> int:
         """Cache a slot's full prompt blocks (the cache takes its own
         reference per newly inserted block). Entries already present keep
         their existing physical block — the newcomer's copy stays private
-        and retires with its slot. Returns the number inserted."""
+        and retires with its slot. ``window_blocks`` ``{logical block:
+        physical id}``: the run's TAIL in the window group (what the slot's
+        ring still holds behind the prompt's end), cached beside the
+        full-group entries that exist; what the window group held of this
+        run before that tail is released at once: a later request that
+        extends the run needs the new tail and no other (one that leaves the
+        run earlier finds its hit shortened). Returns the number inserted
+        (full group)."""
         self._ensure_generation(generation)
+        ins = self._insert_full(hashes, blocks)
+        if window_blocks:
+            for h in hashes[:min(window_blocks)]:
+                self._drop_window(h)
+            for i, b in sorted(window_blocks.items()):
+                if i < len(hashes) and hashes[i] in self._runs:
+                    self._insert_window(hashes[i], b)
+        return ins
+
+    def _insert_window(self, h: bytes, block: int) -> None:
+        if h in self._wruns:
+            self._wruns.move_to_end(h)
+            return
+        while self.window_max_blocks is not None \
+                and len(self._wruns) >= self.window_max_blocks:
+            _, old = self._wruns.popitem(last=False)
+            self._walloc.release([old])
+        self._walloc.retain([block])
+        self._wruns[h] = block
+
+    def _drop_window(self, h: bytes) -> None:
+        b = self._wruns.pop(h, None)
+        if b is not None:
+            self._walloc.release([b])
+
+    def _insert_full(self, hashes: Sequence[bytes],
+                     blocks: Sequence[int]) -> int:
         ins = 0
         for h, b in zip(hashes, blocks):
             if h in self._runs:
@@ -309,8 +443,9 @@ class PrefixCache:
         still references it."""
         if not self._runs:
             return False
-        _, b = self._runs.popitem(last=False)
+        h, b = self._runs.popitem(last=False)
         self._alloc.release([b])
+        self._drop_window(h)
         self.evictions += 1
         return True
 
@@ -331,16 +466,33 @@ class PrefixCache:
             if self._alloc.refcount(b) == 1:
                 del self._runs[h]
                 self._alloc.release([b])
+                self._drop_window(h)
                 self.evictions += 1
                 freed += 1
         return freed
 
+    def reclaim_window(self, need: int) -> int:
+        """:meth:`reclaim` for the window group's allocator: drop the
+        least-recently-used cached window blocks nobody else holds. The
+        full-group entries stay: a hit on them is shortened or missed."""
+        freed = 0
+        for h in list(self._wruns.keys()):
+            if freed >= need:
+                break
+            if self._walloc.refcount(self._wruns[h]) == 1:
+                self._drop_window(h)
+                freed += 1
+        return freed
+
     def stats(self) -> dict:
-        return {"entries": len(self._runs),
-                "max_blocks": self.max_blocks,
-                "evictions": self.evictions,
-                "flushes": self.flushes,
-                "generation": self.generation}
+        out = {"entries": len(self._runs),
+               "max_blocks": self.max_blocks,
+               "evictions": self.evictions,
+               "flushes": self.flushes,
+               "generation": self.generation}
+        if self._walloc is not None:
+            out["window_entries"] = len(self._wruns)
+        return out
 
 
 class SlotPages:
@@ -397,3 +549,145 @@ class SlotPages:
             self._alloc.release(self.blocks)
             self.blocks = []
             self.shared.clear()
+
+
+class RingPages:
+    """One slot's blocks in the WINDOW group: a ring of ``columns`` table
+    columns over logical blocks, of which only those a query can still see
+    are held: the contiguous range ``first .. next - 1``.
+
+    ``release_behind(pos)`` drops the blocks that lie wholly behind the
+    window of a query at ``pos`` (the first query of the step about to run);
+    ``ensure(tokens)`` then maps the blocks up to ``tokens`` positions, each
+    a newly allocated one — so a column is never re-used with the block it
+    held a lap ago, and a block someone else still references (the prefix
+    cache, a fork) is never written by the ring coming round. ``row()`` is
+    the slot's table row: logical block ``b`` in column ``b % columns``,
+    zero (the trash block) where nothing is held. The batcher calls release
+    before ensure, every step; with ``columns = ring_blocks(window, largest
+    chunk, block_size)`` the held blocks then never collide in a column.
+    """
+
+    def __init__(self, allocator: BlockAllocator, block_size: int,
+                 window: int, columns: int):
+        self._alloc = allocator
+        self.block_size = int(block_size)
+        self.window = int(window)
+        self.columns = int(columns)
+        self.blocks: Dict[int, int] = {}   # logical block -> physical id
+        self.shared: set = set()           # physical ids held by retain
+        self.first = 0                     # lowest logical block held
+        self.next = 0                      # first logical block never mapped
+        self.committed = 0                 # blocks admission charged for it
+
+    def adopt(self, first: int, blocks: Sequence[int]) -> None:
+        """Hold already-retained shared blocks as logical blocks ``first,
+        first + 1, ...`` (a prefix hit's window tail, a fork parent's
+        ring); the next block to be allocated follows them."""
+        if self.blocks:
+            raise ValueError("adopt() must precede any allocation")
+        self.blocks = {int(first) + i: int(b) for i, b in enumerate(blocks)}
+        self.shared.update(self.blocks.values())
+        self.first = int(first)
+        self.next = self.first + len(self.blocks)
+
+    def release_behind(self, pos: int) -> int:
+        """Release every held block whose last position is more than
+        ``window - 1`` behind ``pos``. Returns how many."""
+        oldest = int(pos) - self.window + 1          # oldest visible position
+        dead = 0
+        while self.first < self.next \
+                and (self.first + 1) * self.block_size <= oldest:
+            phys = self.blocks.pop(self.first)
+            self.shared.discard(phys)
+            self._alloc.release([phys])
+            self.first += 1
+            dead += 1
+        return dead
+
+    def ensure(self, tokens: int) -> List[int]:
+        """Map the blocks covering positions up to ``tokens`` (exclusive)
+        that never were; returns the newly allocated ids."""
+        need = blocks_needed(tokens, self.block_size) - self.next
+        if need <= 0:
+            return []
+        new = self._alloc.alloc(need)
+        for b in new:
+            self.blocks[self.next] = b
+            self.next += 1
+        return new
+
+    def swap(self, logical: int, new_block: int) -> int:
+        """Copy-on-write bookkeeping, as :meth:`SlotPages.swap`."""
+        old = self.blocks[logical]
+        self.blocks[logical] = int(new_block)
+        self.shared.discard(old)
+        self._alloc.release([old])
+        return old
+
+    def row(self) -> np.ndarray:
+        if self.next - self.first > self.columns:
+            raise ValueError(
+                f"ring of {self.columns} columns holds logical blocks "
+                f"{self.first}..{self.next - 1}: two in one column")
+        row = np.zeros(self.columns, np.int32)
+        for b, phys in self.blocks.items():
+            row[b % self.columns] = phys
+        return row
+
+    def release(self) -> None:
+        if self.blocks:
+            self._alloc.release(list(self.blocks.values()))
+            self.blocks = {}
+            self.shared.clear()
+            self.first = self.next
+
+
+class WindowGroup:
+    """Host state of the window block group of one batcher: its allocator,
+    the slots' ring tables ``(slots, columns)``, and the sizes they follow
+    from. The pool holds every slot's ring (``slots x columns``: a slot
+    never holds more, shared blocks included) plus ``cache_blocks`` for the
+    window tails the prefix cache keeps, plus the trash block."""
+
+    def __init__(self, group: CacheGroup, *, slots: int, block_size: int,
+                 chunk: int, block_bytes: int, cached_tails: int):
+        from ..nn.generation import ring_blocks
+
+        self.window = int(group.window)
+        self.layers = group.layers
+        self.block_size = int(block_size)
+        #: columns of a slot's ring table
+        self.columns = ring_blocks(self.window, chunk, block_size)
+        #: blocks behind a prefix hit that its first query still sees
+        self.tail = blocks_needed(self.window - 1, block_size)
+        self.cache_blocks = int(cached_tails) * self.tail
+        self.alloc = BlockAllocator(
+            slots * self.columns + self.cache_blocks + 1)
+        self.tables_np = np.zeros((slots, self.columns), np.int32)
+        self.block_bytes = int(block_bytes)
+        self.committed = 0      # sum of the admitted requests' commitments
+
+    def commitment(self, tokens: int) -> int:
+        """Blocks a request of ``tokens`` positions can hold at once."""
+        return min(self.columns, blocks_needed(tokens, self.block_size))
+
+    def fits(self, tokens: int) -> bool:
+        """Whether the pool can take one more request of ``tokens``."""
+        return self.committed + self.commitment(tokens) <= self.alloc.usable
+
+    def open(self, tokens: int) -> RingPages:
+        """A new slot's ring, its commitment charged."""
+        ring = RingPages(self.alloc, self.block_size, self.window,
+                         self.columns)
+        ring.committed = self.commitment(tokens)
+        self.committed += ring.committed
+        return ring
+
+    def close(self, s: int, ring: RingPages) -> None:
+        """Retire slot ``s``'s ring: drop its references, give back its
+        commitment, zero its row."""
+        ring.release()
+        self.committed -= ring.committed
+        ring.committed = 0
+        self.tables_np[s] = 0

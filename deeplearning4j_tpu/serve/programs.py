@@ -12,8 +12,14 @@ program IS, and everything only the programs need to know:
   each layer's spec names, ``k`` and ``v``, or ``latent`` and ``rope``; the
   format between the pools and ``decode_forward``'s per-layer cache
   dictionaries is the layout contract's (``nn/generation.py``: ``as_paged``);
+- the block GROUPS of a model whose layers differ in how far back their
+  cache reaches (``serve/paged.py:cache_groups``): each group has pools of
+  its own length and tables of its own width, and the programs take the
+  tables as ``{group: array}``; a model with one group takes one array, as
+  it always did;
 - how a model with expert layers reports what routing did: which rows are
-  live, and the three sums that ride a decode step's tokens as ``(S + 3,)``;
+  live, and the three sums that ride a decode step's tokens as ``(S + 3,)``
+  (four where the layers hold a share of their experts);
 - the persistent-store wrapping under the tags ``gen_sample``,
   ``gen_prefill_chunk``, ``gen_decode_paged``;
 - each program's operand list, written down once as abstract shapes
@@ -31,13 +37,15 @@ from typing import Any, Dict, List, Sequence
 
 import numpy as np
 
-from .paged import build_pools
+from .paged import FULL, build_pools, cache_groups
 
 
 class GenPrograms:
     """The sampler, the prefill-chunk program and the decode step of one
     batcher, over ``slots`` rows, block tables ``table_blocks`` wide and
-    pools of ``kv_blocks`` blocks. ``store`` (an ``AotStore``) makes every
+    pools of ``kv_blocks`` blocks (each one number, or ``{group: number}``
+    for a model with more than one block group: then every ``tables`` /
+    ``table_row`` below is ``{group: array}`` too). ``store`` (an ``AotStore``) makes every
     executable load from disk before it traces, ``strict`` refuses to
     trace at all; ``snapshot`` is the registry snapshot whose architecture
     keys the store."""
@@ -54,7 +62,7 @@ class GenPrograms:
         from ..nn.model import _layer_key
 
         self.slots = int(slots)
-        self.table_blocks = int(table_blocks)
+        self.table_blocks = table_blocks
         self.vocab = int(vocab)
         self.chunk_buckets = tuple(chunk_buckets)
         mdl = model
@@ -85,6 +93,12 @@ class GenPrograms:
         self.pools = build_pools(mdl, kv_blocks, block_size, mdl.dtype)
         # layer key -> the parts its pools hold (k and v; latent and rope)
         names = {lk: tuple(pool) for lk, pool in self.pools.items()}
+        #: block group -> its layers; more than one group makes every
+        #: tables operand a dict
+        self.group_layers = {g.name: g.layers for g in cache_groups(mdl)}
+        grouped = isinstance(table_blocks, dict)
+        group_of = {lk: g for g, layers in self.group_layers.items()
+                    for lk in layers}
         # layers with experts (layers/experts.py) report what routing did
         # to the rows marked live; the sums leave each program as three
         # int32 (nn.layers.experts.ROUTING_FIELDS). A model without such
@@ -95,12 +109,20 @@ class GenPrograms:
                   and getattr(layer, "num_experts", 0)]
         #: expert layers a program runs; 0 for a model without experts
         self.routed = len(routed)
+        from ..nn.layers.experts import ELSEWHERE_FIELDS, ROUTING_FIELDS
+
+        #: what the sums a program reports count, in order: a fourth where
+        #: the expert layers hold a share of their experts
+        self.routing_fields = dict(ROUTING_FIELDS)
+        if any(getattr(layer, "held", None) for layer in mdl.layers):
+            self.routing_fields.update(ELSEWHERE_FIELDS)
         # a chunk's sums stay on the device until the scheduler reads
         # something computed behind them (chunk_routing)
         self._routing_pending: List[Any] = []
 
         def _as_caches(pools, tables, live=None):
-            caches = {lk: as_paged(pools[lk], tables) for lk in names}
+            caches = {lk: as_paged(pools[lk], tables[group_of[lk]]
+                                   if grouped else tables) for lk in names}
             for lk in routed:
                 caches[lk]["live"] = live
             return caches
@@ -135,8 +157,10 @@ class GenPrograms:
             lifetime (tables/pos are traced operands). Inactive slots
             carry zeroed table rows, so their writes land in the trash
             block and their sampled garbage is discarded host-side."""
-            # a live slot's first block is never the trash block
-            live = (tables[:, :1] != 0) if routed else None
+            # a live slot's first block is never the trash block (a ring's
+            # first column may be: the full group's table says who is live)
+            full = tables[FULL] if grouped else tables
+            live = (full[:, :1] != 0) if routed else None
             lg, caches = decode_forward(
                 mdl, params, state, toks[:, None].astype(jnp.int32),
                 _as_caches(pools, tables, live), pos)
@@ -188,15 +212,21 @@ class GenPrograms:
         sds = jax.ShapeDtypeStruct
         i32, f32, u32 = np.int32, np.float32, np.uint32
         pools = self._pools_sig
+
+        def tables(rows):
+            if isinstance(B, dict):
+                return {g: sds((rows, b), i32) for g, b in B.items()}
+            return sds((rows, B), i32)
+
         return {
             "gen_sample": [(sds((V,), f32), sds((2,), u32), sds((), f32),
                             sds((), i32))],
             "gen_decode_paged": [(params, state, sds((S,), i32), pools,
-                                  sds((S, B), i32), sds((S,), i32),
+                                  tables(S), sds((S,), i32),
                                   sds((S, 2), u32), sds((S,), f32),
                                   sds((S,), i32))],
             "gen_prefill_chunk": [(params, state, sds((1, b), i32), pools,
-                                   sds((1, B), i32), sds((1,), i32),
+                                   tables(1), sds((1,), i32),
                                    sds((), i32))
                                   for b in self.chunk_buckets],
         }
@@ -218,11 +248,12 @@ class GenPrograms:
                             np.int32(top_k))
 
     def prefill_chunk(self, params, state, tokens: np.ndarray, bucket: int,
-                      table_row: np.ndarray, off: int):
+                      table_row, off: int):
         """Run ``tokens``, a prompt chunk at offset ``off`` right-padded to
         ``bucket``, through the slot whose table row is ``table_row``
-        ``(1, table_blocks)``. Returns the logits (1, V) at its last real
-        token, on the device."""
+        ``(1, table_blocks)`` (``{group: row}`` with more than one group).
+        Returns the logits (1, V) at its last real token, on the device."""
+        import jax
         import jax.numpy as jnp
 
         true_len = tokens.shape[0]
@@ -232,7 +263,8 @@ class GenPrograms:
         ids[0, :true_len] = tokens  # jaxlint: shape=ids:(1, bucket(_chunk_buckets))
         last, self.pools, *routing = self._prefill_chunk(
             params, state, jnp.asarray(ids), self.pools,
-            jnp.asarray(table_row), np.full((1,), off, np.int32),
+            jax.tree.map(jnp.asarray, table_row),
+            np.full((1,), off, np.int32),
             np.int32(true_len))
         self._routing_pending += routing
         return last
@@ -241,11 +273,13 @@ class GenPrograms:
         """One token for every slot, from the host's slot vectors. Returns
         the device values ``(next, keys)``: ``next`` holds the ``slots``
         tokens (and behind them what :meth:`decode_routing` reads)."""
+        import jax
         import jax.numpy as jnp
 
         nxt, self.pools, new_keys = self._decode(
             params, state, jnp.asarray(toks), self.pools,
-            jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(keys),
+            jax.tree.map(jnp.asarray, tables), jnp.asarray(pos),
+            jnp.asarray(keys),
             jnp.asarray(temps), jnp.asarray(tks))
         return nxt, new_keys
 
@@ -265,9 +299,10 @@ class GenPrograms:
         return sums
 
     # ---------------------------------------------------------------- pools
-    def copy_blocks(self, pairs: List[tuple]) -> None:
+    def copy_blocks(self, pairs: List[tuple], group: str = FULL) -> None:
         """Copy-on-write device work: duplicate each ``(src, dst)`` block
-        row in every pool of every layer. Eager indexed updates — deliberately
+        row in every pool of every layer of ``group`` (block ids are a
+        group's own). Eager indexed updates — deliberately
         NOT a jit site, so the committed compile-surface budget (decode ==
         one executable) is untouched; the indices ride as device operands,
         so XLA's eager cache reuses one executable per pool shape."""
@@ -277,7 +312,8 @@ class GenPrograms:
                                       len(pairs)))
         dst = jnp.asarray(np.fromiter((p[1] for p in pairs), np.int32,
                                       len(pairs)))
-        for pool in self.pools.values():
+        for lk in self.group_layers[group]:
+            pool = self.pools[lk]
             for n in pool:
                 pool[n] = pool[n].at[dst].set(pool[n][src])
 

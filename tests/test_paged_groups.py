@@ -1,0 +1,425 @@
+"""The paged cache's two block groups (``serve/paged.py``, the layout
+contract in ``nn/generation.py``): layers that state a cache window share a
+ring table, pools of the ring's length and an allocator of their own beside
+the full group's. A small Laguna (window 16, blocks of 4, chunks of 8: a ring
+of 7 columns) through the units and through the batcher; and a model whose
+layers state no window is laid out exactly as before."""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu import models
+from deeplearning4j_tpu.nn.generation import (Parts, cache_parts,
+                                              causal_valid, generate,
+                                              ring_blocks, ring_positions)
+from deeplearning4j_tpu.obs.metrics import MetricsRegistry
+from deeplearning4j_tpu.serve.continuous import ContinuousBatcher
+from deeplearning4j_tpu.serve.paged import (FULL, WINDOW, BlockAllocator,
+                                            PrefixCache, RingPages,
+                                            block_bytes, build_pools,
+                                            cache_groups, prefix_hashes)
+
+W, BS, CHUNK = 16, 4, 8
+
+
+def laguna(**kw):
+    args = dict(seed=3, input_shape=(128,), num_layers=5, d_model=32,
+                full_heads=4, sliding_heads=6, num_kv_heads=2, head_dim=8,
+                window=W, dense_width=48, num_experts=8, top_k=2,
+                expert_width=16, shared_width=16, full_rotary_dim=4,
+                yarn_factor=4.0, yarn_original=32, vocab=64)
+    args.update(kw)
+    m = models.LagunaLM(**args).build()
+    m.init()
+    return m
+
+
+def dense():
+    m = models.CausalLM(seed=0, input_shape=(64,), num_layers=2, d_model=32,
+                        num_heads=4, num_kv_heads=1, vocab=64,
+                        window=8).build()
+    m.init()
+    return m
+
+
+def tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(1, 64, n).astype(np.int32)
+
+
+def batcher(m, **kw):
+    opts = dict(slots=2, capacity=128, block_size=BS, prefill_chunk=CHUNK,
+                metrics=MetricsRegistry())
+    opts.update(kw)
+    return ContinuousBatcher(m, **opts)
+
+
+def counter(cb, name, **labels):
+    return sum(s["value"] for s in cb.metrics.snapshot()[name]["series"]
+               if all(s["labels"].get(k) == v for k, v in labels.items()))
+
+
+# ------------------------------------------------------------------ the spec
+def test_layers_group_by_the_window_they_state():
+    groups = cache_groups(laguna())
+    assert [(g.name, g.window, g.layers) for g in groups] == [
+        (FULL, None, ("layer_1", "layer_5")),
+        (WINDOW, W, ("layer_2", "layer_3", "layer_4"))]
+    # the ladder's band-masked attention states none: one group, as before
+    m = dense()
+    assert [(g.name, g.window) for g in cache_groups(m)] == [(FULL, None)]
+    assert all(isinstance(p, Parts) and p.window is None
+               for _, p in cache_parts(m))
+    # pools follow the group's length, bytes are counted a group
+    lm = laguna()
+    pools = build_pools(lm, {FULL: 65, WINDOW: 15}, BS, lm.dtype)
+    assert {lk: pool["k"].shape for lk, pool in pools.items()} == {
+        "layer_1": (65, BS, 2, 8), "layer_5": (65, BS, 2, 8),
+        "layer_2": (15, BS, 2, 8), "layer_3": (15, BS, 2, 8),
+        "layer_4": (15, BS, 2, 8)}
+    one_layer = BS * 2 * 2 * 8 * 4
+    assert block_bytes(lm, BS, lm.dtype) == 5 * one_layer
+    assert block_bytes(lm, BS, lm.dtype, groups[1].layers) == 3 * one_layer
+
+
+def test_two_different_windows_are_refused():
+    m = laguna()
+    odd = m.layers[3].__class__(**{**m.layers[3].__dict__, "window": 8})
+    m.layers[3] = odd
+    with pytest.raises(ValueError, match="different windows"):
+        cache_groups(m)
+
+
+# ------------------------------------------------------------- the device
+def test_ring_columns_and_positions():
+    assert ring_blocks(512, 64, 16) == 37      # the published sizes
+    assert ring_blocks(W, CHUNK, BS) == 7
+    tables = jnp.zeros((2, 7), jnp.int32)
+    # a decode step at positions 3 and 45: newest blocks 0 and 11
+    kpos = np.asarray(ring_positions(tables, BS, jnp.asarray([3, 45]), 1))
+    assert kpos.shape == (2, 28)
+    blocks = kpos.reshape(2, 7, BS)[:, :, 0] // BS
+    # column c holds the newest block b <= newest with b % 7 == c
+    assert blocks[0].tolist() == [0, -6, -5, -4, -3, -2, -1]
+    assert blocks[1].tolist() == [7, 8, 9, 10, 11, 5, 6]
+    assert (kpos.reshape(2, 7, BS)[1, 4] == [44, 45, 46, 47]).all()
+    # a chunk's newest block is that of its LAST (padded) position
+    kpos = np.asarray(ring_positions(tables, BS, jnp.asarray([40]), 8))
+    assert (kpos[0].reshape(7, BS)[:, 0] // BS).tolist() \
+        == [7, 8, 9, 10, 11, 5, 6]
+
+
+@pytest.mark.parametrize("pos,Tq", [(0, 1), (3, 1), (15, 1), (16, 1), (45, 1),
+                                    (0, 8), (24, 8), (40, 5), (123, 1)])
+def test_the_ring_mask_is_the_band_mask(pos, Tq):
+    """What a query may see through the ring's columns is what the band mask
+    over a cache kept at capacity lets it see: causal, within the window,
+    and nothing of the previous lap."""
+    kpos = ring_positions(jnp.zeros((1, 7), jnp.int32), BS,
+                          jnp.asarray([pos]), Tq)
+    ring = np.asarray(causal_valid(jnp.asarray([pos]), Tq, 28, W, kpos))[0]
+    band = np.asarray(causal_valid(pos, Tq, 160, W))
+    kpos = np.asarray(kpos)[0]
+    for t in range(Tq):
+        seen = sorted(int(kpos[c]) for c in range(28) if ring[t, c])
+        assert seen == np.flatnonzero(band[t]).tolist()
+
+
+# --------------------------------------------------------------- the host
+def test_ring_pages_release_behind_the_window_and_map_by_column():
+    alloc = BlockAllocator(12)
+    ring = RingPages(alloc, BS, W, 7)
+    ring.release_behind(0)
+    new = ring.ensure(8)                     # a chunk of 8 at offset 0
+    assert len(new) == 2 and ring.row().tolist() == new + [0] * 5
+    for off in range(8, 64, 8):              # seven more chunks: 64 tokens
+        released = ring.release_behind(off)
+        ring.ensure(off + 8)
+        # what lies wholly behind off - 15 is gone, nothing else
+        assert sorted(ring.blocks) == list(range(max(0, (off - 15) // BS),
+                                                 (off + 8) // BS))
+        assert released == (2 if off >= 24 else 0)
+        row = ring.row()
+        for b, phys in ring.blocks.items():
+            assert row[b % 7] == phys
+        assert np.count_nonzero(row) == len(ring.blocks) <= 7
+    assert alloc.used == len(ring.blocks) == 6
+    # single steps: a block is released by the step whose window its LAST
+    # position has left (position 70 still sees 55, the last of block 13)
+    for pos in range(64, 71):
+        ring.release_behind(pos)
+        ring.ensure(pos + 1)
+    assert min(ring.blocks) == 13 and max(ring.blocks) == 17
+    assert ring.release_behind(71) == 1 and min(ring.blocks) == 14
+    ring.release()
+    assert alloc.used == 0 and alloc.available == 11
+
+
+def test_a_ring_that_is_too_short_is_an_error_not_a_corruption():
+    ring = RingPages(BlockAllocator(20), BS, W, 4)     # 4 < 7 columns
+    with pytest.raises(ValueError, match="two in one column"):
+        ring.ensure(24)
+        ring.row()
+
+
+def _cache_with_window(tail=4, cap=8):
+    full, win = BlockAllocator(40), BlockAllocator(20)
+    return full, win, PrefixCache(full, BS, window_allocator=win,
+                                  window_tail=tail, window_max_blocks=cap)
+
+
+def test_a_hit_is_whole_with_the_tail_and_shortened_without():
+    full, win, cache = _cache_with_window()
+    hashes = prefix_hashes(tokens(40, seed=1), BS)           # 10 blocks
+    blocks = full.alloc(10)
+    held = dict(zip(range(6, 10), win.alloc(4)))             # the ring's tail
+    assert cache.insert(hashes, blocks, 1, held) == 10
+    assert cache.stats()["window_entries"] == 4 and win.used == 4
+    # the whole run: its tail [6, 10) is there
+    run = cache.match(hashes, 1, 10)
+    assert cache.match_window(hashes, len(run)) == (10, list(held.values()))
+    # a prompt that leaves the run after 8 blocks needs [4, 8): not held
+    assert cache.match_window(hashes, 8) == (0, [])
+    # a later insert of an earlier tail makes a shorter hit usable
+    early = dict(zip(range(1, 5), win.alloc(4)))
+    cache.insert(hashes, blocks, 1, early)
+    assert cache.match_window(hashes, 8) == (5, [early[b] for b in (1, 2, 3, 4)])
+    assert cache.match_window(hashes, 4) == (0, [])    # block 0 never cached
+    # adoption takes a reference in BOTH allocators
+    n, tail = cache.match_window(hashes, 10)
+    cache.adopt(hashes, run[:n], tail)
+    # (the writer's own, the cache's, and now the adopter's)
+    assert all(full.refcount(b) == 3 for b in run)
+    assert all(win.refcount(b) == 3 for b in tail)
+    assert all(win.refcount(b) == 2 for b in early.values())
+    # a longer prompt's tail replaces the run's older one at once
+    longer = prefix_hashes(np.concatenate([tokens(40, seed=1),
+                                           tokens(24, seed=9)]), BS)
+    assert longer[:10] == hashes
+    more = full.alloc(6)
+    late = dict(zip(range(12, 16), win.alloc(4)))
+    cache.insert(longer, blocks + more, 1, late)
+    assert cache.match_window(longer, 16) == (16, list(late.values()))
+    assert cache.match_window(longer, 10) == (0, [])      # [6, 10) went
+    # the cache's references on the older tails are gone (the writer's and
+    # the adopter's stay)
+    assert all(win.refcount(b) == 1 for b in early.values())
+    assert all(win.refcount(b) == 2 for b in held.values())
+    assert cache.stats()["window_entries"] == 4
+    # fewer blocks than the tail: all of them must be there
+    short = prefix_hashes(tokens(12, seed=2), BS)
+    b3, w3 = full.alloc(3), win.alloc(3)
+    cache.insert(short, b3, 1, dict(enumerate(w3)))
+    assert cache.match_window(short, 3) == (3, w3)
+    assert cache.match_window(short, 2) == (2, w3[:2])
+
+
+def test_cached_window_blocks_are_bounded_reclaimed_and_flushed():
+    full, win, cache = _cache_with_window(tail=2, cap=3)
+    hashes = prefix_hashes(tokens(24, seed=3), BS)           # 6 blocks
+    blocks, wblocks = full.alloc(6), win.alloc(6)
+    cache.insert(hashes, blocks, 1, dict(enumerate(wblocks)))
+    assert cache.stats()["window_entries"] == 3              # the LRU's bound
+    win.release(wblocks)                    # the slot retires: the cache's
+    assert win.used == 3                    # references keep the last three
+    assert cache.reclaim_window(2) == 2 and win.used == 1
+    assert cache.match(hashes, 1, 6) == blocks       # full entries stayed
+    assert cache.match_window(hashes, 6) == (0, [])
+    # an entry evicted from the full group takes its window block along
+    full.release(blocks)
+    assert cache.reclaim(6) == 6 and win.used == 0
+    # a generation flip drops both
+    cache.insert(hashes, full.alloc(6), 2, {5: win.alloc(1)[0]})
+    cache.match(hashes, 3, 6)
+    assert cache.stats()["window_entries"] == 0 and len(cache) == 0
+
+
+# ------------------------------------------------------------- the batcher
+def test_a_one_group_model_is_laid_out_as_before():
+    """Pools of ``kv_blocks`` for every layer, ONE table array ``(slots,
+    max_blocks)``, and ``signatures()`` with arrays where a grouped model has
+    dictionaries (the lowered text is pinned in test_programs_unchanged)."""
+    cb = batcher(dense(), capacity=64)
+    try:
+        assert cb._win is None and cb._programs.table_blocks == 16
+        assert {a.shape[0] for pool in cb._programs.pools.values()
+                for a in pool.values()} == {2 * 16 + 1}
+        snap = cb.registry.current()
+        sigs = cb._programs.signatures(cb._params_for(snap), snap.state)
+        (decode,) = sigs["gen_decode_paged"]
+        assert decode[4].shape == (2, 16) and decode[4].dtype == np.int32
+        assert [ops[4].shape for ops in sigs["gen_prefill_chunk"]] \
+            == [(1, 16)] * len(cb._chunk_buckets)
+        assert set(cb.metrics.snapshot()["serve_kv_group_blocks_used"]
+                   ["series"][0]["labels"].values()) == {FULL}
+        assert "serve_kv_window_released_total" not in cb.metrics.snapshot()
+        out = cb.generate(tokens(20), 12, temperature=0.0)
+        np.testing.assert_array_equal(
+            out, generate(cb.model, tokens(20)[None], 12, temperature=0.0)[0])
+        assert "window_group" not in cb.kv_block_stats()
+    finally:
+        cb.shutdown()
+
+
+def test_a_grouped_model_has_a_table_a_group_and_pools_of_the_rings_length():
+    cb = batcher(laguna())
+    try:
+        win = cb._win
+        assert (win.columns, win.tail, win.window) == (7, 4, W)
+        # 2 slots x 7 columns + 2 cached tails of 4 + the trash block
+        assert win.alloc.num_blocks == 2 * 7 + 2 * 4 + 1
+        shapes = {lk: pool["k"].shape[0]
+                  for lk, pool in cb._programs.pools.items()}
+        assert shapes == {"layer_1": 65, "layer_5": 65, "layer_2": 23,
+                          "layer_3": 23, "layer_4": 23}
+        snap = cb.registry.current()
+        (decode,) = cb._programs.signatures(cb._params_for(snap),
+                                            snap.state)["gen_decode_paged"]
+        assert {g: t.shape for g, t in decode[4].items()} \
+            == {FULL: (2, 32), WINDOW: (2, 7)}
+        # the gauges: one a group, and the unlabelled ones their sum
+        bytes_a_block = BS * 2 * 2 * 8 * 4
+        assert counter(cb, "serve_kv_token_bytes") == 5 * bytes_a_block // BS
+    finally:
+        cb.shutdown()
+
+
+def test_window_blocks_are_released_and_both_allocators_drain():
+    """Contexts several windows long on both slots: the window group never
+    holds more than a ring a slot plus the cached tails while the full group
+    grows with the context; everything returns when the slots retire and the
+    prefix cache is flushed."""
+    m = laguna()
+    cb = batcher(m)
+    try:
+        prompts = [tokens(70, seed=1), tokens(45, seed=2), tokens(9, seed=3)]
+        reqs = [cb.submit(p, 40, temperature=0.0) for p in prompts]
+        peak_win = peak_full = 0
+        while not all(r.event.is_set() for r in reqs):
+            st = cb.kv_block_stats()
+            peak_win = max(peak_win, st["window_group"]["blocks_used"])
+            peak_full = max(peak_full, st["blocks_used"])
+        for p, r in zip(prompts, reqs):
+            np.testing.assert_array_equal(
+                r.wait(), generate(m, p[None], 40, temperature=0.0)[0])
+        assert 0 < peak_win <= 2 * 7 + 2 * 4
+        assert peak_full > 2 * 7 + 2 * 4        # (70 + 40 + 45 + 40) / 4
+        released = counter(cb, "serve_kv_window_released_total")
+        allocated = counter(cb, "serve_kv_window_allocated_total")
+        assert 0 < released < allocated
+        assert counter(cb, "serve_kv_live_bytes") == (
+            counter(cb, "serve_kv_group_live_bytes", group=FULL)
+            + counter(cb, "serve_kv_group_live_bytes", group=WINDOW))
+        st = cb.kv_block_stats()
+        assert st["blocks_committed"] == 0
+        assert st["window_group"]["blocks_committed"] == 0
+        # what is still used is the prefix cache's, in both groups
+        assert st["blocks_used"] == st["blocks_cached"] > 0
+        assert st["window_group"]["blocks_used"] \
+            == st["prefix_cache"]["window_entries"] > 0
+        cb.flush_prefix_cache()
+        st = cb.kv_block_stats()
+        assert st["blocks_used"] == 0 == st["window_group"]["blocks_used"]
+        assert cb._alloc.available == cb._alloc.usable
+        assert cb._win.alloc.available == cb._win.alloc.usable
+        assert (cb._tables_np == 0).all() and (cb._win.tables_np == 0).all()
+    finally:
+        cb.shutdown()
+
+
+def test_a_shared_window_block_is_never_written_when_the_ring_laps():
+    """A request adopts a cached prefix with its window tail and decodes far
+    enough for its ring to lap several times (7 columns x 4 = 28 positions;
+    60 tokens): the cached blocks' contents are bit for bit what they were,
+    and a second request over the same prefix still reads them right."""
+    m = laguna()
+    cb = batcher(m)
+    try:
+        base = tokens(41, seed=4)
+        cb.generate(base, 2, temperature=0.0)            # caches 10 blocks
+        cached = sorted(cb._prefix._wruns.values())
+        assert len(cached) == 4
+
+        def contents():
+            return {lk: {n: np.asarray(a[np.asarray(cached)])
+                         for n, a in cb._programs.pools[lk].items()}
+                    for lk in cb._win.layers}
+
+        before = contents()
+        longer = np.concatenate([base, tokens(6, seed=5)])
+        out = cb.generate(longer, 60, temperature=0.0)
+        assert counter(cb, "serve_prefix_cache_hits_total") == 1
+        np.testing.assert_array_equal(
+            out, generate(m, longer[None], 60, temperature=0.0)[0])
+        after = contents()
+        # (the longer prompt's tail replaced the run's older one in the
+        # cache: the first of the four went back to the pool, three stayed)
+        still = [i for i, b in enumerate(cached)
+                 if b in cb._prefix._wruns.values()]
+        assert len(still) == 3
+        for lk in before:
+            for n in before[lk]:
+                np.testing.assert_array_equal(before[lk][n][still],
+                                              after[lk][n][still])
+        again = np.concatenate([longer, tokens(3, seed=6)])
+        np.testing.assert_array_equal(
+            cb.generate(again, 8, temperature=0.0),
+            generate(m, again[None], 8, temperature=0.0)[0])
+        assert counter(cb, "serve_prefix_cache_hits_total") == 2
+        assert counter(cb, "serve_prefix_hits_shortened_total") == 0
+    finally:
+        cb.shutdown()
+
+
+def test_a_hit_whose_tail_is_gone_is_shortened_and_still_right():
+    m = laguna()
+    cb = batcher(m)
+    try:
+        base = tokens(41, seed=7)
+        cb.generate(base, 2, temperature=0.0)
+        # leaves the cached run after 24 tokens: blocks [2, 6) would be
+        # needed and the ring had let go of them
+        fork = np.concatenate([base[:24], tokens(9, seed=8)])
+        np.testing.assert_array_equal(
+            cb.generate(fork, 10, temperature=0.0),
+            generate(m, fork[None], 10, temperature=0.0)[0])
+        assert counter(cb, "serve_prefix_hits_shortened_total") == 1
+        assert counter(cb, "serve_prefix_cache_hits_total") == 0
+        assert counter(cb, "serve_prefill_tokens_saved_total") == 0
+    finally:
+        cb.shutdown()
+
+
+def test_a_fork_shares_the_ring_and_copies_on_write_a_group():
+    import time
+
+    m = laguna()
+    cb = batcher(m)
+    try:
+        real = cb._programs.decode
+
+        def slow(*a, **k):             # or the parent finishes before a fork
+            time.sleep(0.02)
+            return real(*a, **k)
+
+        cb._programs.decode = slow
+        prompt = tokens(30, seed=9)
+        parent = cb.submit(prompt, 40, temperature=0.0)
+        while len(parent.out) < 5:
+            time.sleep(0.005)
+        child = cb.fork(parent)
+        at = len(parent.out)
+        got_parent, got_child = parent.wait(), child.wait()
+        want = generate(m, prompt[None], 40, temperature=0.0)[0]
+        np.testing.assert_array_equal(got_parent, want)
+        # the child resumes from the parent's state: the same greedy chain
+        n = len(got_child)
+        assert n >= 40 - at - 1
+        np.testing.assert_array_equal(got_child, want[40 - n:][:n]
+                                      if n <= 40 else want)
+        assert counter(cb, "serve_kv_cow_copies_total") >= 2   # one a group
+        cb.flush_prefix_cache()
+        assert cb._win.alloc.used == 0 and cb._alloc.used == 0
+        assert cb._win.committed == 0
+    finally:
+        cb.shutdown()

@@ -1,4 +1,4 @@
-"""The served programs of the models the benchmark already had, pinned.
+"""The served programs of the models the benchmark has, pinned.
 
 PR 31 taught the cache spec to name a layer's parts (a latent cache is one
 array, not ``k`` and ``v``) and moved the experts' einsums and the routing
@@ -7,6 +7,11 @@ model is served by: the lowered text of the sampler, every prefill-chunk
 bucket and the decode step, at a small size, hashes to what the parent commit
 (31e153b) gave. A hash says nothing about speed; it says the compiler is
 handed the same program, so the benchmark's old cells cannot move.
+
+PR 33 gave the cache contract a second block group (a window layer's ring)
+and the routing sums a held share; the three pins above it stand as they
+were, GLM's programs are pinned from PR 33's parent, and Laguna's from the
+commit that brought them.
 
 A change that is MEANT to alter these programs re-takes the hashes (run this
 file with ``-s`` and copy what it prints) and says so in ``PERF.md``.
@@ -34,6 +39,19 @@ PARENT = {
         "gen_decode_paged": "2a57acd7ac55ca28dab5b7a43b6f98e5ce8e31d9f783f620767f918148deeb2f",
         "gen_prefill_chunk": "1024b3e649c2900012e645a75ebc0fb4f18852f8583051d01eb38f6c58b1d026",
     },
+    # taken at 531c641 (PR 33's parent), when PR 33 touched the cache
+    # contract and the experts' routing sums it shares
+    "glm": {
+        "gen_sample": "5735026fd23a417e6ff6cd8d38548127448acbbb804e224ea67331c92424dc92",
+        "gen_decode_paged": "9238b8af6a7f01a783e38ea31850da433f5fbaec80128fca0f9b82ec4641e03b",
+        "gen_prefill_chunk": "9d64b447c7c2161e6eb1d371ed37589bae546c1341d3c7e88e490b9f685c4c85",
+    },
+    # taken at PR 33, the commit that brought the model
+    "laguna": {
+        "gen_sample": "5735026fd23a417e6ff6cd8d38548127448acbbb804e224ea67331c92424dc92",
+        "gen_decode_paged": "cb2665f5f59f495df376ca87f9fec90671b65871b6800948ed3c06b4586071c9",
+        "gen_prefill_chunk": "6647156ad2ac696815543b0566d60f6b244e5dddf5017cce5888993901fbefe2",
+    },
 }
 
 
@@ -52,6 +70,33 @@ def _olmoe():
     m = models.OlmoeLM(seed=0, input_shape=(64,), num_layers=2, d_model=64,
                        num_heads=4, num_experts=8, top_k=2, expert_width=32,
                        vocab=128, dtype="bfloat16").build()
+    m.init()
+    return m
+
+
+def _glm():
+    """``glm-4.7-flash``'s: a latent cache, a dense layer and expert layers,
+    parameters held once in bf16."""
+    m = models.Glm4MoeLiteLM(
+        seed=3, input_shape=(96,), num_layers=3, first_k_dense=1, d_model=64,
+        num_heads=4, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=12,
+        qk_rope_head_dim=8, v_head_dim=16, dense_width=96, num_experts=8,
+        top_k=2, expert_width=32, vocab=128, dtype="bfloat16").build()
+    m.init()
+    return m
+
+
+def _laguna():
+    """``laguna-s-2.1``'s (PR 33): two block groups, a share of the experts
+    held, parameters held once in bf16. Pinned from its first commit on, so
+    that a later change to the shared stack shows here too."""
+    m = models.LagunaLM(seed=0, input_shape=(64,), num_layers=5, d_model=64,
+                        full_heads=4, sliding_heads=6, num_kv_heads=2,
+                        head_dim=16, window=16, dense_width=96,
+                        num_experts=16, top_k=3, expert_width=32,
+                        shared_width=32, experts_held=(4, 8),
+                        full_rotary_dim=8, yarn_factor=4.0, yarn_original=32,
+                        vocab=128, dtype="bfloat16").build()
     m.init()
     return m
 
@@ -79,7 +124,8 @@ def lowered_hashes(model):
                     reason="the hashes are of jax 0.9.0's lowering")
 @pytest.mark.parametrize("tag", ["gen_sample", "gen_decode_paged",
                                  "gen_prefill_chunk"])
-@pytest.mark.parametrize("name,build", [("dense", _dense), ("olmoe", _olmoe)])
+@pytest.mark.parametrize("name,build", [("dense", _dense), ("olmoe", _olmoe),
+                                        ("glm", _glm), ("laguna", _laguna)])
 def test_lowered_text_is_the_parents(name, build, tag):
     got = lowered_hashes(build())
     print(name, got)
