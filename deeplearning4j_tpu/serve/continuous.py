@@ -39,12 +39,17 @@ step, interleaved with decode ticks under a priority-aware
 :class:`~.engine.PrefillScheduler`, so a prompt burst cannot stall
 in-flight decodes for its whole prefill.
 
-KV is shared across requests (``prefix_cache=True``): whole prompt blocks
-are inserted into a :class:`~.paged.PrefixCache` keyed on ``(params
-generation, rolling sha256 of block token runs)`` as prefills complete,
-and admission adopts the longest cached run — refcount++ on the shared
-physical blocks, prefill computes only the non-shared suffix, and the
-worst-case commitment charges only non-shared blocks. Cached-but-idle runs
+KV is shared across requests (``prefix_cache=True``): whole blocks are
+inserted into a :class:`~.paged.PrefixCache` keyed on ``(params
+generation, rolling sha256 of block token runs)`` — a prompt's as its
+prefill completes, and the whole run's, the blocks its decode steps filled
+included, as the request finishes (so the next turn of a session that
+sends the answer back prefills its fresh tokens and nothing else; only a
+request served under one params generation from first chunk to last tick,
+and never one that was aborted or shed) — and admission adopts the longest
+cached run: refcount++ on the shared physical blocks, prefill computes only
+the non-shared suffix, and the worst-case commitment charges only
+non-shared blocks. Cached-but-idle runs
 form an LRU the allocator reclaims under capacity pressure before anything
 sheds; a registry generation flip invalidates the cache wholesale so
 stale-params KV is never adopted. Decode writes always land in a slot's
@@ -79,9 +84,10 @@ at all; both families stay on whole-batch ``nn.generation.generate``.
 
 from __future__ import annotations
 
+import hashlib
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, Sequence
+from typing import Dict, Iterator, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -135,13 +141,22 @@ def gen_opts_from_config(config: Optional[dict]) -> dict:
     return opts
 
 
+class _CachedRun(NamedTuple):
+    """What a decoding request keeps of its prompt's place in the prefix
+    cache, to cache the rest of its run when it finishes."""
+
+    hashes: List[bytes]   # the prompt's whole blocks' rolling hashes
+    state: object         # the rolling sha256 after the last of them
+    generation: int       # the params generation every chunk ran under
+
+
 class _GenRequest:
     """One queued/in-flight generation."""
 
     __slots__ = ("prompt", "max_new", "temperature", "top_k", "eos_id",
                  "deadline", "enq_t", "disp_t", "first_t", "event", "result",
                  "error", "out", "key", "slot", "ctx", "on_done", "cancelled",
-                 "_cv")
+                 "cached_run", "_cv")
 
     def __init__(self, prompt: np.ndarray, max_new: int, temperature: float,
                  top_k: Optional[int], eos_id: Optional[int],
@@ -172,6 +187,10 @@ class _GenRequest:
         # set by ContinuousBatcher.cancel(): the typed error the worker
         # finishes this request with at its next safe point
         self.cancelled: Optional[ServeError] = None
+        # set when its prompt's blocks entered the prefix cache: the key to
+        # cache the answer's under (None for a fork's child, whose tokens
+        # before the fork are not its own to hash)
+        self.cached_run: Optional[_CachedRun] = None
         self._cv = threading.Condition()
 
     # --- token-at-a-time surface (SSE streaming rides on this) ---
@@ -247,11 +266,11 @@ class _PrefillJob:
     """One prompt mid-prefill: its slot, block pages, and chunk cursor."""
 
     __slots__ = ("req", "slot", "pages", "chunks", "idx", "worst", "last",
-                 "shared", "hashes", "gens", "ring")
+                 "shared", "hashes", "hash_state", "gens", "ring")
 
     def __init__(self, req: _GenRequest, slot: int, pages: SlotPages,
                  chunks: List[tuple], worst: int, shared: int = 0,
-                 hashes: Optional[List[bytes]] = None,
+                 hashes: Optional[List[bytes]] = None, hash_state=None,
                  ring: Optional[RingPages] = None):
         self.req = req
         self.slot = slot
@@ -263,6 +282,7 @@ class _PrefillJob:
         self.last = None        # logits at the last REAL token so far
         self.shared = shared    # prefix blocks adopted from the cache
         self.hashes = hashes or []  # rolling block-run hashes of the prompt
+        self.hash_state = hash_state  # the rolling sha256 behind the last
         self.gens: set = set()  # params generations its chunks ran under
 
     @property
@@ -514,6 +534,10 @@ class ContinuousBatcher:
         self._m_px_saved = m.counter(
             "serve_prefill_tokens_saved_total", self._lbl(),
             help="prompt tokens skipped by adopting cached prefix blocks")
+        self._m_px_answer = m.counter(
+            "serve_prefix_answer_tokens_cached_total", self._lbl(),
+            help="tokens in the blocks finishing requests added to the "
+                 "prefix cache (the blocks their decode steps filled)")
         self._m_px_shared = m.gauge(
             "serve_prefix_blocks_shared", self._lbl(),
             help="distinct KV blocks slots hold via sharing "
@@ -940,15 +964,31 @@ class ContinuousBatcher:
         adopted cache blocks. Full chunks run at exactly ``prefill_chunk``;
         the tail pads to the smallest chunk bucket that covers it.
         ``prefill_chunk=None`` is one whole-prompt chunk (the un-chunked
-        baseline)."""
-        if self.prefill_chunk is None:
-            return [(start, tp - start, self._bucket(tp - start))]
+        baseline).
+
+        A chunk's padding never reaches past ``capacity``: a learned
+        position table has no row there, ``decode_forward``'s ``jnp.take``
+        answers NaN for it, the NaN keys and values land in the trash
+        block, and every slot with an unallocated table entry gathers that
+        block (weight 0 x NaN): a run of token 0, cached for the prompts
+        that share the prefix. Behind a prefix hit a tail starts off the
+        chunk grid, so its bucket may not fit; it is then cut into whole
+        buckets that do."""
         chunks, off = [], start
-        while tp - off > self.prefill_chunk:
-            chunks.append((off, self.prefill_chunk, self.prefill_chunk))
-            off += self.prefill_chunk
-        tail = tp - off
-        chunks.append((off, tail, self._chunk_bucket(tail)))
+        while off < tp:
+            take = tp - off
+            if self.prefill_chunk is None:
+                bucket = self._bucket(take)
+            elif take > self.prefill_chunk:
+                take = bucket = self.prefill_chunk
+            else:
+                bucket = self._chunk_bucket(take)
+            if off + bucket > self.capacity:
+                whole = [b for b in self._chunk_buckets if b <= take]
+                if whole:   # else: narrower than the narrowest bucket
+                    take = bucket = whole[-1]
+            chunks.append((off, take, bucket))
+            off += take
         return chunks
 
     def _update_kv_gauges(self) -> None:
@@ -1043,9 +1083,12 @@ class ContinuousBatcher:
             req = self._queue[0]
             tp = req.prompt.shape[0]
             hashes: List[bytes] = []
+            hash_state = None
             run: List[int] = []
             if self._prefix is not None:
-                hashes = prefix_hashes(req.prompt, self.block_size)
+                hash_state = hashlib.sha256()
+                hashes = prefix_hashes(req.prompt, self.block_size,
+                                       hash_state)
                 # never adopt the whole prompt: at least one real token
                 # must prefill so the first sample has logits to read
                 run = self._prefix.match(hashes, generation,
@@ -1088,7 +1131,8 @@ class ContinuousBatcher:
             job = _PrefillJob(
                 req, s, pages,
                 self._plan_chunks(tp, shared * self.block_size), worst,
-                shared=shared, hashes=hashes, ring=ring)
+                shared=shared, hashes=hashes, hash_state=hash_state,
+                ring=ring)
             self._slot_job[s] = job
             self._jobs.append(job)
         self._m_pf_depth.set(len(self._jobs))
@@ -1190,13 +1234,13 @@ class ContinuousBatcher:
                 # mid-prefill — that KV mixes generations and must retire
                 # with its slot, never be adopted
                 nfull = req.prompt.shape[0] // self.block_size
-                # ...and of what the ring still holds of them, the window's
-                # tail behind the prompt's end: what a hit will need
-                held = None if job.ring is None else {
-                    b: blk for b, blk in job.ring.blocks.items()
-                    if nfull - self._win.tail <= b < nfull}
                 self._prefix.insert(job.hashes[:nfull],
-                                    job.pages.blocks[:nfull], gen_now, held)
+                                    job.pages.blocks[:nfull], gen_now,
+                                    self._ring_tail(job.ring, nfull))
+                # the answer's blocks follow under the same run's hashes
+                # when the request finishes (_cache_answer)
+                req.cached_run = _CachedRun(job.hashes, job.hash_state,
+                                            gen_now)
                 self._update_kv_gauges()
         if req.ctx is not None:
             # decode starts with the token-0 sample, not the first tick — a
@@ -1232,7 +1276,48 @@ class ContinuousBatcher:
         # a 1-token request (or instant EOS) finishes without ever decoding
         self._maybe_finish(s)
 
-    def _maybe_finish(self, s: int) -> None:
+    def _ring_tail(self, ring: Optional[RingPages],
+                   n: int) -> Optional[Dict[int, int]]:
+        """What ``ring`` still holds of the window's tail behind a run of
+        ``n`` whole blocks, ``{logical block: physical id}``: what a hit on
+        that run will need (None without a window group)."""
+        if ring is None:
+            return None
+        return {b: blk for b, blk in ring.blocks.items()
+                if n - self._win.tail <= b < n}
+
+    def _cache_answer(self, s: int, req: _GenRequest, generation) -> None:
+        """Under ``self._cond``, as slot ``s`` retires normally and before
+        its pages go: cache the whole blocks of the request's run, the ones
+        its decode steps filled included, so that a prompt which sends the
+        answer back adopts them. The cache holds KV for ``prompt ++
+        out[:-1]`` (the last sampled token was pushed, never fed). Only
+        where every chunk, the tick that finished it (``generation``: its
+        lease's) and the cache's entries are of one params generation;
+        generations only grow, so the first and the last equal means all."""
+        run, req.cached_run = req.cached_run, None
+        if run is None \
+                or not run.generation == generation == self._prefix.generation:
+            return
+        bs = self.block_size
+        nfull = len(run.hashes)
+        n_end = (req.prompt.shape[0] + len(req.out) - 1) // bs
+        if n_end <= nfull:
+            return
+        # hashed on from the prompt's last whole block, not from its start
+        hashes = run.hashes + prefix_hashes(
+            np.concatenate([req.prompt[nfull * bs:],
+                            np.asarray(req.out[:-1], np.int32)]),
+            bs, run.state)
+        added = self._prefix.insert(
+            hashes, self._slot_pages[s].blocks[:n_end], generation,
+            self._ring_tail(self._slot_ring[s], n_end))
+        self._m_px_answer.inc(added * bs)
+
+    def _maybe_finish(self, s: int, generation=None) -> None:
+        """Retire slot ``s`` if its request is done. ``generation``: that of
+        the lease of the decode tick that just ran (None before any has:
+        there is then no block behind the prompt's to cache)."""
         with self._cond:
             req = self._slot_req[s]
             if req is None:
@@ -1245,6 +1330,7 @@ class ContinuousBatcher:
                 return
             self._slot_req[s] = None
             if self._slot_pages[s] is not None:
+                self._cache_answer(s, req, generation)
                 # copy-free retirement: blocks drop one reference (cached/
                 # shared ones survive in their other holders) and the table
                 # row zeroes (points at trash) — no device work
@@ -1372,7 +1458,7 @@ class ContinuousBatcher:
                 for req, tok in pushes:
                     req._push(tok)
                 for s in active:
-                    self._maybe_finish(s)
+                    self._maybe_finish(s, snap.generation)
 
     def _tick_rings(self, active: List[int]) -> List[tuple]:
         """Under ``self._cond``, inside the tick's prepare phase: the window

@@ -245,17 +245,22 @@ def blocks_needed(tokens: int, block_size: int) -> int:
     return -(-int(tokens) // int(block_size))
 
 
-def prefix_hashes(tokens, block_size: int) -> List[bytes]:
+def prefix_hashes(tokens, block_size: int, state=None) -> List[bytes]:
     """Rolling sha256 over whole-block token runs.
 
     ``hashes[i]`` commits to tokens ``[0, (i+1)*block_size)`` — the entire
     run, not just block ``i`` — so two prompts share a cache entry only
     when every block before it matches too. Partial tail tokens are never
     hashed: only whole blocks are shareable.
+
+    ``state``: a ``hashlib.sha256()`` to continue, advanced in place over
+    the whole blocks of ``tokens`` and left after the last of them — so a
+    run that grew (a prompt, then the answer decoded behind it) is hashed
+    on from where its last whole block ended, never from its start.
     """
     toks = np.ascontiguousarray(np.asarray(tokens, np.int32))
     out: List[bytes] = []
-    h = hashlib.sha256()
+    h = hashlib.sha256() if state is None else state
     for i in range(toks.shape[0] // int(block_size)):
         h.update(toks[i * block_size:(i + 1) * block_size].tobytes())
         out.append(h.digest())
@@ -279,8 +284,8 @@ class PrefixCache:
     group are cached under the same hashes, in an LRU of their own of at
     most ``window_max_blocks``, each entry one reference in the window
     allocator. Only a run's tail is ever there (the ``window_tail`` blocks a
-    slot's ring still held behind the prompt's end when its prefill
-    finished; a longer prompt's tail replaces it), and a hit of ``n`` blocks
+    slot's ring still held behind the run's end when it was inserted; a
+    longer run's tail replaces it), and a hit of ``n`` blocks
     is usable only if the ``window_tail`` blocks behind it are
     (:meth:`match_window`). An entry evicted from the full group takes its
     window block with it.
@@ -384,12 +389,14 @@ class PrefixCache:
     def insert(self, hashes: Sequence[bytes], blocks: Sequence[int],
                generation: int,
                window_blocks: Optional[Dict[int, int]] = None) -> int:
-        """Cache a slot's full prompt blocks (the cache takes its own
+        """Cache a slot's whole blocks: a prompt's when its prefill ends,
+        and the run's as it stands — the blocks the decode steps filled
+        included — when the request finishes (the cache takes its own
         reference per newly inserted block). Entries already present keep
         their existing physical block — the newcomer's copy stays private
         and retires with its slot. ``window_blocks`` ``{logical block:
         physical id}``: the run's TAIL in the window group (what the slot's
-        ring still holds behind the prompt's end), cached beside the
+        ring still holds behind the run's end), cached beside the
         full-group entries that exist; what the window group held of this
         run before that tail is released at once: a later request that
         extends the run needs the new tail and no other (one that leaves the

@@ -510,11 +510,13 @@ def test_the_contract_check_accepts_the_model():
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_batcher_serves_what_generate_gives(dtype):
     """Four requests on two slots through both block groups, chunked
-    prefill. The third extends the first's whole prompt and adopts its ten
-    cached blocks with the window's tail behind them; the fourth leaves the
-    first's prompt after 32 tokens, where the window group no longer holds
-    the tail (the ring had moved on), so its hit is cut to nothing. The
-    reference's tokens, and in f32 ``generate()``'s, token for token."""
+    prefill. The third sends the first's prompt and answer back with five
+    tokens more (a session's next turn) and adopts the seventeen whole blocks
+    of that run, the ones decode steps filled included, with the window's
+    tail behind them; the fourth leaves the first's prompt after 32 tokens,
+    where the window group no longer holds the tail (the ring had moved on),
+    so its hit is cut to nothing. The reference's tokens, and in f32
+    ``generate()``'s, token for token."""
     m = build(dtype, experts_held=(4, 8))
     cfg = cfg_of(experts_held=[4, 8], cache_dtype=jnp.dtype(m.dtype).name)
     cb = _batcher(m)
@@ -522,9 +524,9 @@ def test_batcher_serves_what_generate_gives(dtype):
         shared = tokens(32, seed=5)
         prompts = [np.concatenate([shared, tokens(9, seed=6)]),
                    tokens(21, seed=7)]
-        prompts.append(np.concatenate([prompts[0], tokens(5, seed=8)]))
-        prompts.append(np.concatenate([shared, tokens(7, seed=20)]))
         first = cb.generate(prompts[0], 30, temperature=0.0)
+        prompts.append(np.concatenate([prompts[0], first, tokens(5, seed=8)]))
+        prompts.append(np.concatenate([shared, tokens(7, seed=20)]))
         reqs = [cb.submit(p, 30, temperature=0.0) for p in prompts[1:]]
         outs = [first] + [r.wait() for r in reqs]
         snap = cb.metrics.snapshot()
@@ -532,7 +534,9 @@ def test_batcher_serves_what_generate_gives(dtype):
     finally:
         cb.shutdown()
     assert _counter(snap, "serve_prefix_cache_hits_total") == 1
-    assert _counter(snap, "serve_prefill_tokens_saved_total") == 40
+    assert _counter(snap, "serve_prefill_tokens_saved_total") \
+        == (41 + 30 - 1) // BS * BS == 68
+    assert _counter(snap, "serve_prefix_answer_tokens_cached_total") > 0
     assert _counter(snap, "serve_prefix_hits_shortened_total") == 1
     width = 4 if dtype == "float32" else 2
     assert _counter(snap, "serve_kv_token_bytes") == 5 * 2 * 2 * 16 * width

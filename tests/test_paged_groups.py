@@ -346,27 +346,36 @@ def test_a_shared_window_block_is_never_written_when_the_ring_laps():
                     for lk in cb._win.layers}
 
         before = contents()
+        # held here too, so that none is handed out again once the cache lets
+        # go of it: whatever changes in them is a write through a shared block
+        with cb._cond:
+            cb._win.alloc.retain(cached)
         longer = np.concatenate([base, tokens(6, seed=5)])
         out = cb.generate(longer, 60, temperature=0.0)
         assert counter(cb, "serve_prefix_cache_hits_total") == 1
         np.testing.assert_array_equal(
             out, generate(m, longer[None], 60, temperature=0.0)[0])
         after = contents()
-        # (the longer prompt's tail replaced the run's older one in the
-        # cache: the first of the four went back to the pool, three stayed)
-        still = [i for i, b in enumerate(cached)
-                 if b in cb._prefix._wruns.values()]
-        assert len(still) == 3
         for lk in before:
             for n in before[lk]:
-                np.testing.assert_array_equal(before[lk][n][still],
-                                              after[lk][n][still])
-        again = np.concatenate([longer, tokens(3, seed=6)])
+                np.testing.assert_array_equal(before[lk][n], after[lk][n])
+        # (the longer prompt's tail replaced the run's older one in the
+        # cache, and the answer's tail that one when the request finished:
+        # what the window group caches now is the tail behind the 26 whole
+        # blocks of prompt and answer)
+        with cb._cond:
+            assert not set(cached) & set(cb._prefix._wruns.values())
+            assert len(cb._prefix._wruns) == 4
+            cb._win.alloc.release(cached)
+        # the next turn reads prompt and answer out of the cache and is right
+        again = np.concatenate([longer, out, tokens(3, seed=6)])
         np.testing.assert_array_equal(
             cb.generate(again, 8, temperature=0.0),
             generate(m, again[None], 8, temperature=0.0)[0])
         assert counter(cb, "serve_prefix_cache_hits_total") == 2
         assert counter(cb, "serve_prefix_hits_shortened_total") == 0
+        assert counter(cb, "serve_prefill_tokens_saved_total") \
+            == 40 + (47 + 60 - 1) // BS * BS
     finally:
         cb.shutdown()
 
@@ -421,5 +430,120 @@ def test_a_fork_shares_the_ring_and_copies_on_write_a_group():
         cb.flush_prefix_cache()
         assert cb._win.alloc.used == 0 and cb._alloc.used == 0
         assert cb._win.committed == 0
+    finally:
+        cb.shutdown()
+
+
+# ------------------------------------------- the answer's tail (ISSUE 35)
+def test_the_ring_holds_the_tail_behind_a_run_at_every_position_of_a_lap():
+    """At the published sizes (window 512, blocks of 16, chunks of 64: 37
+    columns, a tail of 32 blocks): after the step that writes position
+    ``pos``, a finishing request has ``n_end = (pos + 1) // 16`` whole
+    blocks, and a hit on them needs blocks ``n_end - 32 .. n_end - 1``.
+    ``release_behind`` keeps every block the query at ``pos`` can see, and
+    block ``n_end - 32`` ends at most 495 positions behind it: the ring
+    holds them all, at every position of more than a lap (37 x 16 = 592),
+    behind a prompt on and off the chunk grid."""
+    bs, window, chunk = 16, 512, 64
+    columns = ring_blocks(window, chunk, bs)
+    tail = -(-(window - 1) // bs)
+    assert (columns, tail) == (37, 32)
+    for tp in (1, 40, 700, 1000, 1017):
+        alloc = BlockAllocator(2 * columns + 1)
+        ring = RingPages(alloc, bs, window, columns)
+        for off in range(0, tp, chunk):                 # the prompt's chunks
+            ring.release_behind(off)
+            ring.ensure(min(tp, off + chunk))
+        for pos in range(tp, tp + 700):                 # one decode step each
+            ring.release_behind(pos)
+            ring.ensure(pos + 1)
+            ring.row()                                  # no two in a column
+            n_end = (pos + 1) // bs
+            assert all(b in ring.blocks
+                       for b in range(max(0, n_end - tail), n_end)), (tp, pos)
+
+
+def test_the_answers_tail_is_held_when_a_request_finishes():
+    """The same through the batcher at the small sizes (a ring of 7 columns
+    of 4, a tail of 4): requests that end at every position of a lap; what
+    ``_cache_answer`` hands the cache is the whole tail each time."""
+    m = laguna()
+    cb = batcher(m)
+    seen = []
+    real = cb._ring_tail
+
+    def ring_tail(ring, n):
+        held = real(ring, n)
+        seen.append((n, sorted(held)))
+        return held
+
+    cb._ring_tail = ring_tail
+    try:
+        prompt = tokens(30, seed=11)
+        for n_out in range(3, 33):                      # 28 positions a lap
+            cb.flush_prefix_cache()
+            del seen[:]
+            cb.generate(prompt, n_out, temperature=0.0)
+            (n_prompt, held_prompt), (n_end, held) = seen
+            assert n_prompt == 30 // BS
+            assert n_end == (30 + n_out - 1) // BS
+            assert held == list(range(n_end - 4, n_end))
+        assert counter(cb, "serve_prefix_answer_tokens_cached_total") > 0
+    finally:
+        cb.shutdown()
+
+
+def test_the_next_turn_hits_whole_behind_the_answer_and_is_right():
+    """Turn after turn a session sends its answer back: every hit covers
+    prompt and answer (never shortened), the tokens are the uncached ones,
+    and the window LRU holds ONE tail for the session whatever its length
+    (a longer run's tail replaces the older one)."""
+    m = laguna()
+    cb = batcher(m)
+    try:
+        history = tokens(21, seed=12)
+        saved = 0
+        for turn in range(6):
+            prompt = np.concatenate([history, tokens(3 + turn, seed=20 + turn)])
+            n_out = 9 + turn
+            out = cb.generate(prompt, n_out, temperature=0.0)
+            np.testing.assert_array_equal(
+                out, generate(m, prompt[None], n_out, temperature=0.0)[0])
+            if turn:
+                # everything the last turn fed: its prompt and answer but
+                # the token it sampled last
+                saved += (len(history) - 1) // BS * BS
+            assert counter(cb, "serve_prefill_tokens_saved_total") == saved
+            assert counter(cb, "serve_prefix_cache_hits_total") == turn
+            assert counter(cb, "serve_prefix_hits_shortened_total") == 0
+            st = cb.kv_block_stats()
+            assert st["prefix_cache"]["window_entries"] == 4       # one tail
+            assert st["blocks_cached"] == (len(prompt) + n_out - 1) // BS
+            history = np.concatenate([prompt, out])
+        assert len(history) > 100       # the ring lapped several times
+        cb.flush_prefix_cache()
+        assert cb._win.alloc.used == 0 and cb._alloc.used == 0
+        assert cb._win.committed == 0
+    finally:
+        cb.shutdown()
+
+
+def test_two_sessions_keep_a_tail_each():
+    m = laguna()
+    cb = batcher(m)
+    try:
+        hist = [tokens(26, seed=31), tokens(19, seed=32)]
+        for turn in range(4):
+            prompts = [np.concatenate([h, tokens(4, seed=40 + turn + 7 * i)])
+                       for i, h in enumerate(hist)]
+            reqs = [cb.submit(p, 10, temperature=0.0) for p in prompts]
+            for i, (p, r) in enumerate(zip(prompts, reqs)):
+                out = r.wait()
+                np.testing.assert_array_equal(
+                    out, generate(m, p[None], 10, temperature=0.0)[0])
+                hist[i] = np.concatenate([p, out])
+            assert cb.kv_block_stats()["prefix_cache"]["window_entries"] == 8
+        assert counter(cb, "serve_prefix_cache_hits_total") == 6
+        assert counter(cb, "serve_prefix_hits_shortened_total") == 0
     finally:
         cb.shutdown()

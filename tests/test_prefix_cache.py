@@ -17,7 +17,13 @@ The load-bearing properties, each tested directly:
   drain + cache flush every refcount returns to zero;
 - ``fork()``: the child resumes the parent's exact decode state, returns
   exactly the parent's post-fork continuation at temperature 0, and the
-  shared partial tail triggers exactly one copy-on-write block copy.
+  shared partial tail triggers exactly one copy-on-write block copy;
+- the answer stays cached (ISSUE 35): a request that finishes normally
+  leaves the whole blocks of ``prompt ++ out[:-1]`` in the cache, so the
+  next turn of a session prefills its fresh tokens and nothing else; a
+  request that saw two params generations, was aborted, shed or lost to a
+  restart leaves nothing of its answer; a padded chunk never reaches past
+  the capacity (what ROADMAP D12 was).
 """
 
 import time
@@ -223,13 +229,15 @@ class TestBatcherPrefixCache:
             stats = cb.kv_block_stats()
             px = stats["prefix_cache"]
             assert px["hits"] == 1 and px["misses"] == 1
-            assert stats["blocks_cached"] == 2  # both full prompt blocks
+            # both full prompt blocks, and the one the decode steps filled
+            # (positions 8..11 of prompt ++ out[:-1] = 13 tokens)
+            assert stats["blocks_cached"] == 3
             # hit adopted 1 block (adoption is capped at (tp-1)//bs so the
             # last real token still prefills): 4 prompt tokens skipped
             assert cb.metrics.counter(
                 "serve_prefill_tokens_saved_total").value == 4
             # drain + flush returns every refcount to zero
-            assert cb.flush_prefix_cache() == 2
+            assert cb.flush_prefix_cache() == 3
             stats = cb.kv_block_stats()
             assert stats["blocks_used"] == 0 and stats["blocks_shared"] == 0
         finally:
@@ -288,6 +296,386 @@ class TestBatcherPrefixCache:
             assert "prefix_cache" not in stats
             assert stats["blocks_used"] == 0  # nothing retained
             assert cb.flush_prefix_cache() == 0
+        finally:
+            cb.shutdown()
+
+
+@pytest.fixture(scope="module")
+def lm64():
+    from deeplearning4j_tpu.models import CausalLM
+
+    model = CausalLM(seed=0, input_shape=(64,), num_layers=2, d_model=32,
+                     num_heads=4, vocab=50).build()
+    model.init()
+    return model
+
+
+def _count(cb, name):
+    return cb.metrics.counter(name).value
+
+
+def _slow(cb, what="decode", s=0.02):
+    """Stretch every decode tick (or prefill chunk) so that the test thread
+    can act between two of them."""
+    real = getattr(cb._programs, what)
+
+    def slow(*a, **k):
+        time.sleep(s)
+        return real(*a, **k)
+
+    setattr(cb._programs, what, slow)
+
+
+def _assert_only_the_cache_holds_blocks(cb):
+    """No slot is live: every allocated block is a cached one at exactly
+    the cache's one reference, and a flush empties the pool."""
+    with cb._cond:
+        assert cb._alloc._refs == {b: 1 for b in cb._prefix.blocks()}
+        assert not cb._shared_ledger and cb._committed == 0
+        assert (cb._tables_np == 0).all()
+    cb.flush_prefix_cache()
+    st = cb.kv_block_stats()
+    assert st["blocks_used"] == 0 and st["blocks_shared"] == 0
+    assert cb._alloc.available == cb._alloc.usable
+
+
+class TestHashContinuation:
+    def test_a_continued_state_gives_the_hashes_of_the_whole_run(self):
+        import hashlib
+
+        toks = np.random.RandomState(1).randint(0, 99, 43).astype(np.int32)
+        whole = prefix_hashes(toks, 4)
+        for cut in (0, 3, 4, 17, 40, 43):
+            state = hashlib.sha256()
+            head = prefix_hashes(toks[:cut], 4, state)
+            # the state stands behind the head's last WHOLE block: the
+            # head's partial tail is fed again with what follows it
+            rest = prefix_hashes(toks[cut // 4 * 4:], 4, state)
+            assert head + rest == whole
+
+
+class TestAnswerStaysCached:
+    BS = 4
+
+    def _batcher(self, model, **kw):
+        opts = dict(slots=2, capacity=64, block_size=self.BS,
+                    prefill_chunk=4, seed=0)
+        opts.update(kw)
+        return ContinuousBatcher(model, **opts)
+
+    @pytest.mark.parametrize("max_new", [4, 5, 7])
+    def test_next_turn_adopts_the_answer_and_prefills_the_fresh_tokens(
+            self, lm64, max_new):
+        """9 prompt tokens + 4, 5, 7 answers: the last token FED sits 0, 1
+        and ``block_size - 1`` past a block's end."""
+        bs, tp = self.BS, 9
+        cb = self._batcher(lm64)
+        plain = self._batcher(lm64, prefix_cache=False)
+        try:
+            p = np.random.RandomState(3).randint(0, 50, tp).astype(np.int32)
+            o1 = cb.generate(p, max_new, temperature=0.0)
+            assert np.array_equal(o1, plain.generate(p, max_new,
+                                                     temperature=0.0))
+            n_end = (tp + max_new - 1) // bs
+            assert (tp + max_new - 1) % bs == {4: 0, 5: 1, 7: bs - 1}[max_new]
+            assert cb.kv_block_stats()["blocks_cached"] == n_end
+            assert _count(cb, "serve_prefix_answer_tokens_cached_total") \
+                == (n_end - tp // bs) * bs
+            # what is cached is prompt ++ out[:-1] and no block beyond: the
+            # last sampled token was pushed, never fed, so its position
+            # holds no KV — also where it would complete a block
+            run = np.concatenate([p, o1])
+            with cb._cond:
+                assert list(cb._prefix._runs) \
+                    == prefix_hashes(run[:-1], bs)
+                assert prefix_hashes(run, bs)[n_end:] == [] \
+                    or prefix_hashes(run, bs)[n_end] not in cb._prefix._runs
+            # the next turn sends the answer back with 3 fresh tokens
+            p2 = np.concatenate([run, [5, 6, 7]]).astype(np.int32)
+            chunks0 = _count(cb, "serve_prefill_chunks_total")
+            o2 = cb.generate(p2, 6, temperature=0.0)
+            assert _count(cb, "serve_prefill_tokens_saved_total") == n_end * bs
+            assert _count(cb, "serve_prefill_chunks_total") - chunks0 \
+                == len(cb._plan_chunks(p2.shape[0], n_end * bs)) \
+                == blocks_needed(p2.shape[0] - n_end * bs, 4)
+            # KV a decode step wrote serves the next turn as a chunk's would
+            assert np.array_equal(o2, plain.generate(p2, 6, temperature=0.0))
+            _assert_only_the_cache_holds_blocks(cb)
+        finally:
+            cb.shutdown()
+            plain.shutdown()
+
+    def test_eos_and_one_token_requests_cache_what_was_fed(self, lm64):
+        cb = self._batcher(lm64)
+        try:
+            p = np.random.RandomState(5).randint(0, 50, 9).astype(np.int32)
+            o = cb.generate(p, 12, temperature=0.0)
+            cb.flush_prefix_cache()
+            before = _count(cb, "serve_prefix_answer_tokens_cached_total")
+            # stop at the token that first appears latest: 9 + n - 1
+            # positions hold KV
+            n = 1 + max(i for i in range(12) if o[i] not in o[:i])
+            out = cb.generate(p, 12, temperature=0.0, eos_id=int(o[n - 1]))
+            assert np.array_equal(out, o[:n]) and n >= 4
+            assert cb.kv_block_stats()["blocks_cached"] == (9 + n - 1) // 4
+            cb.flush_prefix_cache()
+            # one token: sampled off the prefill, no decode tick, no answer
+            cb.generate(p, 1, temperature=0.0)
+            assert cb.kv_block_stats()["blocks_cached"] == 2
+            assert _count(cb, "serve_prefix_answer_tokens_cached_total") \
+                - before == ((9 + n - 1) // 4 - 2) * 4
+            _assert_only_the_cache_holds_blocks(cb)
+        finally:
+            cb.shutdown()
+
+    @pytest.mark.parametrize("after", [1, 4])
+    def test_a_publish_before_the_last_tick_caches_nothing_of_the_answer(
+            self, lm64, after):
+        """A flip between prefill and the first tick's successors
+        (``after`` = 1 token out) and one mid-decode (4 out): the answer's
+        KV mixes two generations and retires with its slot."""
+        cb = self._batcher(lm64)
+        try:
+            _slow(cb)
+            p = np.random.RandomState(7).randint(0, 50, 9).astype(np.int32)
+            req = cb.submit(p, 12, temperature=0.0)
+            while len(req.out) < after:
+                time.sleep(0.002)
+            snap = cb.registry.current()
+            cb.registry.publish(snap.params, snap.state)
+            req.wait()
+            assert _count(cb, "serve_prefix_answer_tokens_cached_total") == 0
+            # (the prompt's two blocks, of the old generation, go at the
+            # next admission)
+            assert cb.kv_block_stats()["blocks_cached"] == 2
+            # served whole under the new generation, it is cached whole
+            cb.generate(p, 12, temperature=0.0)
+            st = cb.kv_block_stats()
+            assert st["prefix_cache"]["flushes"] == 1
+            assert st["blocks_cached"] == (9 + 12 - 1) // 4
+            _assert_only_the_cache_holds_blocks(cb)
+        finally:
+            cb.shutdown()
+
+    def test_an_aborted_prefill_caches_nothing(self, lm64):
+        cb = self._batcher(lm64)
+        try:
+            _slow(cb, "prefill_chunk")
+            p = np.random.RandomState(9).randint(0, 50, 30).astype(np.int32)
+            req = cb.submit(p, 8, temperature=0.0)
+            while req.disp_t is None:      # its first chunk is dispatched
+                time.sleep(0.002)
+            assert cb.cancel(req)
+            with pytest.raises(ShedError):
+                req.wait()
+            assert req.out == [] and cb.kv_block_stats()["blocks_cached"] == 0
+            _assert_only_the_cache_holds_blocks(cb)
+        finally:
+            cb.shutdown()
+
+    def test_a_cancelled_decode_caches_what_it_wrote(self, lm64):
+        cb = self._batcher(lm64)
+        try:
+            _slow(cb)
+            p = np.random.RandomState(11).randint(0, 50, 9).astype(np.int32)
+            req = cb.submit(p, 40, temperature=0.0)
+            while len(req.out) < 9:
+                time.sleep(0.002)
+            assert cb.cancel(req)
+            with pytest.raises(ShedError):
+                req.wait()
+            n = len(req.out)
+            assert 9 <= n < 40
+            run = np.concatenate([p, req.out]).astype(np.int32)
+            with cb._cond:
+                assert list(cb._prefix._runs) == prefix_hashes(run[:-1], 4)
+            # and what it wrote is right: the rest of the answer follows
+            o2 = cb.generate(run, 5, temperature=0.0)
+            assert _count(cb, "serve_prefill_tokens_saved_total") \
+                == (9 + n - 1) // 4 * 4
+            from deeplearning4j_tpu.nn.generation import generate
+
+            assert np.array_equal(
+                o2, generate(lm64, run[None], 5, temperature=0.0)[0])
+            _assert_only_the_cache_holds_blocks(cb)
+        finally:
+            cb.shutdown()
+
+    @pytest.mark.parametrize("how", ["restart", "shed"])
+    def test_an_abandoned_slot_caches_nothing_of_the_answer(self, lm64, how):
+        from deeplearning4j_tpu.serve.errors import (ServerClosingError,
+                                                     WorkerStallError)
+
+        cb = self._batcher(lm64)
+        try:
+            _slow(cb)
+            p = np.random.RandomState(13).randint(0, 50, 9).astype(np.int32)
+            req = cb.submit(p, 40, temperature=0.0)
+            while len(req.out) < 9:
+                time.sleep(0.002)
+            if how == "restart":        # crash-only: the epoch stales the
+                assert cb.restart_worker("test")   # tick in flight as well
+                with pytest.raises(WorkerStallError):
+                    req.wait()
+            else:
+                cb.shutdown(drain=False)
+                with pytest.raises(ServerClosingError):
+                    req.wait()
+            time.sleep(0.1)             # the stale tick returns, drops its
+            # bookkeeping at the epoch check, and caches nothing
+            assert _count(cb, "serve_prefix_answer_tokens_cached_total") == 0
+            assert cb.kv_block_stats()["blocks_cached"] == 2   # the prompt's
+            _assert_only_the_cache_holds_blocks(cb)
+        finally:
+            cb.shutdown()
+
+    def test_a_forks_child_is_left_out_and_its_parent_is_cached(self, lm64):
+        """The child's tokens before the fork are not in its request, so its
+        run is not hashed; the parent's run is its own and is cached."""
+        cb = self._batcher(lm64)
+        try:
+            _slow(cb)
+            p = np.random.RandomState(15).randint(0, 50, 9).astype(np.int32)
+            parent = cb.submit(p, 20, temperature=0.0)
+            while len(parent.out) < 6:
+                time.sleep(0.002)
+            child = cb.fork(parent)
+            out, cout = parent.wait(), child.wait()
+            assert child.cached_run is None and len(cout) >= 1
+            assert _count(cb, "serve_prefix_answer_tokens_cached_total") \
+                == ((9 + 20 - 1) // 4 - 2) * 4
+            with cb._cond:
+                assert list(cb._prefix._runs) == prefix_hashes(
+                    np.concatenate([p, out[:-1]]).astype(np.int32), 4)
+            _assert_only_the_cache_holds_blocks(cb)
+        finally:
+            cb.shutdown()
+
+    def test_sessions_on_a_small_pool_never_write_a_cached_block(self, lm64):
+        """Three sessions in lockstep rounds on a pool of exactly three full
+        contexts, so the reclaimer runs: before every device call no block
+        about to be WRITTEN is one the cache holds, every greedy answer is
+        the plain one, and the pool drains."""
+        from deeplearning4j_tpu.nn.generation import generate
+
+        bs, cap = self.BS, 64
+        cb = self._batcher(lm64, slots=3, kv_blocks=3 * cap // bs + 1)
+        bad = []
+        real_decode, real_chunk = cb._programs.decode, \
+            cb._programs.prefill_chunk
+
+        def cached():
+            return set(cb._prefix._runs.values())
+
+        def decode(params, state, toks, tables, pos, *rest):
+            hold = cached()
+            for s in range(tables.shape[0]):
+                if tables[s, 0] and tables[s, pos[s] // bs] in hold:
+                    bad.append(("decode", s, int(pos[s])))
+            return real_decode(params, state, toks, tables, pos, *rest)
+
+        def chunk(params, state, tokens, bucket, table_row, off):
+            hold = cached()
+            for b in range(off // bs, blocks_needed(off + len(tokens), bs)):
+                if table_row[0, b] in hold:
+                    bad.append(("chunk", off, b))
+            return real_chunk(params, state, tokens, bucket, table_row, off)
+
+        cb._programs.decode, cb._programs.prefill_chunk = decode, chunk
+        try:
+            rng = np.random.RandomState(17)
+            bases = [rng.randint(0, 50, n).astype(np.int32)
+                     for n in (9, 14, 11)]
+            hist = list(bases)
+            for _ in range(14):
+                turns = []
+                for i in range(3):
+                    fresh = rng.randint(0, 50, rng.randint(2, 7))
+                    n_out = int(rng.randint(3, 10))
+                    if len(hist[i]) + len(fresh) + n_out > cap:
+                        hist[i] = bases[i]        # the session starts over
+                    prompt = np.concatenate([hist[i], fresh]).astype(np.int32)
+                    turns.append((prompt, n_out,
+                                  cb.submit(prompt, n_out, temperature=0.0)))
+                for i, (prompt, n_out, req) in enumerate(turns):
+                    out = req.wait()
+                    assert np.array_equal(out, generate(
+                        lm64, prompt[None], n_out, temperature=0.0)[0])
+                    hist[i] = np.concatenate([prompt, out])
+            assert bad == []
+            st = cb.kv_block_stats()["prefix_cache"]
+            assert st["evictions"] > 0 and st["hits"] > 30
+            assert _count(cb, "serve_prefix_answer_tokens_cached_total") > 0
+            _assert_only_the_cache_holds_blocks(cb)
+        finally:
+            cb.shutdown()
+
+
+class TestPaddingStaysInsideTheCapacity:
+    """ROADMAP D12, found by PR 22 and repaired in PR 35: behind a prefix hit
+    a prompt's tail starts off the chunk grid, and its bucket reached past
+    the capacity. A learned position table has no row there:
+    ``decode_forward``'s ``jnp.take`` answers NaN, the NaN keys and values
+    landed in the trash block, and every slot with an unallocated table
+    entry read them at weight 0: a run of token 0, cached."""
+
+    def test_plan_cuts_a_tail_whose_bucket_would_not_fit(self, lm64):
+        cb = ContinuousBatcher(lm64, slots=1, capacity=64, block_size=4,
+                               prefill_chunk=16, seed=0)
+        try:
+            assert cb._chunk_buckets == (8, 16)
+            # on the grid nothing changes
+            assert cb._plan_chunks(60) == [(0, 16, 16), (16, 16, 16),
+                                           (32, 16, 16), (48, 12, 16)]
+            assert cb._plan_chunks(41, 36) == [(36, 5, 8)]
+            assert cb._plan_chunks(56, 44) == [(44, 12, 16)]    # ends at 60
+            # 52 + 16 would reach 68: a whole bucket of 8, then the rest
+            assert cb._plan_chunks(62, 36) == [(36, 16, 16), (52, 8, 8),
+                                               (60, 2, 8)]
+            for tp in range(2, 65):
+                for start in range(0, tp, 4):
+                    plan = cb._plan_chunks(tp, start)
+                    assert plan[0][0] == start
+                    assert sum(t for _, t, _ in plan) == tp - start
+                    assert all(o2 == o1 + t1 for (o1, t1, _), (o2, _, _)
+                               in zip(plan, plan[1:]))
+                    assert all(t <= b and b in cb._chunk_buckets
+                               for _, t, b in plan)
+                    # what is left: blocks of 4 under a narrowest bucket
+                    # of 8 let a tail of < 8 tokens start in the last 7
+                    # positions (never with blocks >= the narrowest bucket)
+                    assert all(o + b <= 64 or (t < 8 and o > 56)
+                               for o, t, b in plan)
+        finally:
+            cb.shutdown()
+
+    def test_a_tail_behind_a_hit_near_capacity_poisons_nobody(self):
+        from deeplearning4j_tpu.models import CausalLM
+        from deeplearning4j_tpu.nn.generation import generate
+
+        m = CausalLM(seed=0, input_shape=(512,), num_layers=2, d_model=32,
+                     num_heads=4, vocab=50).build()   # 512 learned positions
+        m.init()
+        cb = ContinuousBatcher(m, slots=2, capacity=512, block_size=16,
+                               prefill_chunk=64, seed=0)
+        try:
+            rs = np.random.RandomState(3)
+            p = rs.randint(0, 50, 464).astype(np.int32)
+            cb.generate(p, 2, temperature=0.0)            # caches 29 blocks
+            # the next turn: 40 tokens at offset 464 pad to 64 -> 528 > 512
+            p2 = np.concatenate([p, rs.randint(0, 50, 40)]).astype(np.int32)
+            assert np.array_equal(
+                cb.generate(p2, 4, temperature=0.0),
+                generate(m, p2[None], 4, temperature=0.0)[0])
+            assert _count(cb, "serve_prefill_tokens_saved_total") == 464
+            for pool in cb._programs.pools.values():
+                for a in pool.values():       # nothing NaN in the trash block
+                    assert np.isfinite(np.asarray(a[0])).all()
+            # a short request gathers the trash block behind its own blocks
+            p3 = rs.randint(0, 50, 10).astype(np.int32)
+            assert np.array_equal(
+                cb.generate(p3, 6, temperature=0.0),
+                generate(m, p3[None], 6, temperature=0.0)[0])
         finally:
             cb.shutdown()
 
