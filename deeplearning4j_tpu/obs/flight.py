@@ -15,10 +15,18 @@ Like ``chaos/``, the recorder is process-global via :data:`ACTIVE` with an
 ``install``/``uninstall`` pair: call sites guard with
 ``if _flight.ACTIVE is not None`` so a serving stack with no recorder pays
 one attribute load per site and allocates nothing.
+
+The generation worker's stalls (``obs/trace.py:PhaseClock``) land here as
+``stall`` events, and a recorder with an ``out_dir`` also keeps a watchdog on
+the worker while a slot decodes: ``faulthandler.dump_traceback_later``, whose
+timer is a C thread that needs no interpreter lock, so a worker that stands
+still for ``STACKS_AFTER_S`` gets the stack of EVERY Python thread written to
+``<out_dir>/stall_stacks.txt`` mid-stall, whoever holds the lock.
 """
 
 from __future__ import annotations
 
+import faulthandler
 import json
 import os
 import threading
@@ -27,6 +35,12 @@ from collections import deque
 from typing import Dict, List, Optional
 
 ACTIVE: Optional["FlightRecorder"] = None
+
+STACKS_FILE = "stall_stacks.txt"
+STACKS_AFTER_S = 1.0            # a worker that has not ticked for this long
+#   while a slot decodes gets every thread's stack dumped
+STACKS_REARM_NS = 250_000_000   # ... re-armed at most this often: arming
+#   restarts faulthandler's timer thread, too dear for every 12 ms tick
 
 
 class FlightRecorder:
@@ -49,6 +63,10 @@ class FlightRecorder:
         self._dumps: List[str] = []
         self._dump_seq = 0
         self._lock = threading.Lock()
+        # the stacks file, opened at the first arming, and the stamp of the
+        # last one (None: disarmed)
+        self._stacks_fd = -1
+        self._stacks_armed_ns: Optional[int] = None
 
     # --- recording (cheap, called from hot-adjacent paths) ---
     def record_request(self, record: dict) -> None:
@@ -67,6 +85,47 @@ class FlightRecorder:
             ev["data"] = data
         with self._lock:
             self._events.append(ev)
+
+    # --- the stall watchdog (the one decoding worker's thread calls these) ---
+    def watch_stacks(self, now_ns: int) -> None:
+        """At every tick that leaves a slot decoding: (re)arm
+        ``faulthandler.dump_traceback_later`` so that ``STACKS_AFTER_S``
+        without another arming writes every thread's stack to
+        ``<out_dir>/stall_stacks.txt``. Nothing without an ``out_dir``. The
+        timer is the process's one: one decoding worker a process."""
+        if self.out_dir is None:
+            return
+        armed = self._stacks_armed_ns
+        if armed is not None and now_ns - armed < STACKS_REARM_NS:
+            return
+        if self._stacks_fd < 0:
+            os.makedirs(self.out_dir, exist_ok=True)
+            self._stacks_fd = os.open(
+                os.path.join(self.out_dir, STACKS_FILE),
+                os.O_WRONLY | os.O_CREAT | os.O_APPEND, 0o644)
+        faulthandler.dump_traceback_later(STACKS_AFTER_S,
+                                          file=self._stacks_fd)
+        self._stacks_armed_ns = now_ns
+
+    def unwatch_stacks(self) -> None:
+        """The worker has nothing in decode, or is stopping."""
+        if self._stacks_armed_ns is not None:
+            self._stacks_armed_ns = None
+            faulthandler.cancel_dump_traceback_later()
+
+    def close_stacks(self) -> None:
+        """Disarm and close the stacks file (``uninstall``); a later arming
+        opens it again, to append."""
+        self.unwatch_stacks()
+        fd, self._stacks_fd = self._stacks_fd, -1
+        if fd >= 0:
+            os.close(fd)
+
+    def note_stacks(self, text: str) -> None:
+        """One line into the stacks file (the worker, after a stall long
+        enough to have been dumped: whose the dump above it is)."""
+        if self._stacks_fd >= 0:
+            os.write(self._stacks_fd, ("# " + text + "\n").encode())
 
     # --- inspection / dumping ---
     def requests(self) -> List[dict]:
@@ -126,4 +185,6 @@ def install(recorder: FlightRecorder) -> FlightRecorder:
 def uninstall() -> Optional[FlightRecorder]:
     global ACTIVE
     recorder, ACTIVE = ACTIVE, None
+    if recorder is not None:
+        recorder.close_stacks()
     return recorder

@@ -92,6 +92,7 @@ from typing import Dict, Iterator, List, NamedTuple, Optional, Sequence
 import numpy as np
 
 from ..chaos import faults as _faults
+from ..obs import flight as _flight
 from ..obs import profile as _prof
 from ..obs import trace as _trace
 from .engine import PrefillScheduler
@@ -112,6 +113,14 @@ def _default_prompt_buckets(capacity: int) -> tuple:
         b *= 2
     buckets.append(capacity)
     return tuple(sorted(set(buckets)))
+
+
+# serve_gen_decode_seconds: buckets a tick's median can be read in (x 1.15 from
+# 2 ms to 250 ms, so none is wider than 15% of its value), the default
+# buckets below and above
+TICK_BUCKETS = ((1e-4, 2.5e-4, 5e-4, 1e-3)
+                + tuple(round(2e-3 * 1.15 ** i, 6) for i in range(35))
+                + (0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0))
 
 
 # Constructor knobs a tuned config (aot/tuned.py) may set on the batcher.
@@ -155,8 +164,8 @@ class _GenRequest:
 
     __slots__ = ("prompt", "max_new", "temperature", "top_k", "eos_id",
                  "deadline", "enq_t", "disp_t", "first_t", "event", "result",
-                 "error", "out", "key", "slot", "ctx", "on_done", "cancelled",
-                 "cached_run", "_cv")
+                 "error", "out", "pushed_ns", "key", "slot", "ctx", "on_done",
+                 "cancelled", "cached_run", "_cv")
 
     def __init__(self, prompt: np.ndarray, max_new: int, temperature: float,
                  top_k: Optional[int], eos_id: Optional[int],
@@ -176,6 +185,10 @@ class _GenRequest:
         self.result: Optional[np.ndarray] = None
         self.error: Optional[ServeError] = None
         self.out: List[int] = []
+        # beside each token of ``out``, the worker's perf_counter_ns stamp of
+        # its push (a tick's tokens share the tick's one publish stamp): what
+        # the SSE writer's lag is measured from
+        self.pushed_ns: List[int] = []
         self.key = None       # per-request PRNG key, set at admission
         self.slot: Optional[int] = None
         # request-trace context (obs/reqtrace); None whenever tracing is
@@ -194,9 +207,10 @@ class _GenRequest:
         self._cv = threading.Condition()
 
     # --- token-at-a-time surface (SSE streaming rides on this) ---
-    def _push(self, tok: int) -> None:
+    def _push(self, tok: int, stamp_ns: int) -> None:
         with self._cv:
-            self.out.append(tok)
+            self.pushed_ns.append(stamp_ns)     # first: whoever sees the
+            self.out.append(tok)                # token finds its stamp
             self._cv.notify_all()
 
     def _finish(self, error: Optional[ServeError] = None) -> None:
@@ -438,6 +452,13 @@ class ContinuousBatcher:
         self._hb = time.monotonic()
         self._admitted = 0
         self._peak_active = 0
+        # requests shed so far, all causes, and what that read at the last
+        # published tick (a stall's record says how many fell inside it)
+        self._shed_lock = threading.Lock()
+        self._sheds = self._sheds_at_tick = 0
+        # the flight recorder whose stack watchdog this worker armed
+        self._watched: Optional[_flight.FlightRecorder] = None
+        self._clock: Optional[_trace.PhaseClock] = None
         self._prefill_sigs = set()
         self._decode_sigs = set()
 
@@ -459,13 +480,15 @@ class ContinuousBatcher:
         self._m_tokens = m.counter("serve_gen_tokens_total", self._lbl(),
                                    help="tokens generated across all slots (each request's "
                                         "prefill-sampled first token included)")
-        self._m_decode_s = m.histogram("serve_gen_decode_seconds", self._lbl(),
-                                       help="one all-slots decode tick")
-        self._m_prefill_s = m.histogram("serve_gen_prefill_seconds",
-                                        self._lbl(),
-                                        help="host time to enqueue one prefill "
-                                             "(per chunk when chunked); "
-                                             "unfenced, not device time")
+        self._m_decode_s = m.histogram(
+            "serve_gen_decode_seconds", self._lbl(), buckets=TICK_BUCKETS,
+            help="one all-slots decode tick: gen.tick.dispatch + "
+                 "gen.tick.readback, first upload to both readbacks returned")
+        self._m_prefill_s = m.histogram(
+            "serve_gen_prefill_seconds", self._lbl(),
+            help="the worker's time in one gen.prefill_chunk (per chunk when "
+                 "chunked): table growth, uploads, the enqueue; unfenced, "
+                 "not device time")
         self._m_queue_s = m.histogram(
             "serve_gen_queue_seconds", self._lbl(),
             help="enqueue to first prefill chunk dispatched, per request")
@@ -635,8 +658,12 @@ class ContinuousBatcher:
 
     def _spawn_worker(self) -> None:
         self._hb = time.monotonic()
+        # a clock a worker; a restart's starts where the staled worker's
+        # stopped being charged (obs/trace.py:PhaseClock)
+        self._clock = _trace.PhaseClock(self.metrics, self._lbl(),
+                                        after=self._clock)
         self._thread = threading.Thread(
-            target=self._loop, args=(self._epoch,), daemon=True,
+            target=self._loop, args=(self._epoch, self._clock), daemon=True,
             name=f"serve-continuous-batcher-{self._epoch}")
         self._thread.start()
 
@@ -716,10 +743,13 @@ class ContinuousBatcher:
             out["model"] = self.model_name
         return out
 
-    def _shed_counter(self, cause: str):
-        return self.metrics.counter(
+    def _shed(self, cause: str) -> None:
+        """Count one request shed (any thread)."""
+        self.metrics.counter(
             "serve_shed_total", self._lbl({"cause": cause}),
-            help="requests refused at admission, by cause")
+            help="requests refused at admission, by cause").inc()
+        with self._shed_lock:
+            self._sheds += 1
 
     def queue_depth(self) -> int:
         """Generation requests waiting for a slot (Retry-After input)."""
@@ -742,7 +772,7 @@ class ContinuousBatcher:
                               self.block_size)
         if worst > self._alloc.usable:
             # queueing can't help: this request can NEVER fit
-            self._shed_counter("over_capacity").inc()
+            self._shed("over_capacity")
             raise CapacityError(
                 f"request needs {worst} KV blocks but the pool only has "
                 f"{self._alloc.usable} — raise kv_blocks or lower "
@@ -753,20 +783,20 @@ class ContinuousBatcher:
                           eos_id, deadline, ctx=ctx)
         with self._cond:
             if self._closing:
-                self._shed_counter("shutting_down").inc()
+                self._shed("shutting_down")
                 raise ServerClosingError("batcher is draining; not accepting "
                                          "new requests")
             if not self._thread.is_alive():
                 # fail fast: a dead decode loop means this request would
                 # queue forever — answer typed NOW; a watchdog (if running)
                 # will restart the worker for later traffic
-                self._shed_counter("worker_dead").inc()
+                self._shed("worker_dead")
                 raise ServerClosingError(
                     "batcher worker thread is dead; request refused "
                     "(run a Watchdog for automatic crash-only restart)",
                     cause="worker_dead")
             if len(self._queue) >= self.queue_limit:
-                self._shed_counter("queue_full").inc()
+                self._shed("queue_full")
                 raise ShedError(f"generation queue full "
                                 f"({self.queue_limit}); shedding load")
             self._queue.append(req)
@@ -832,7 +862,7 @@ class ContinuousBatcher:
             else:
                 req.cancelled = err
                 self._cond.notify_all()
-        self._shed_counter(cause).inc()
+        self._shed(cause)
         if queued:
             req._finish(err)
         return True
@@ -876,7 +906,7 @@ class ContinuousBatcher:
                       if self._slot_req[i] is None
                       and self._slot_job[i] is None), None)
             if t is None:
-                self._shed_counter("fork_no_slot").inc()
+                self._shed("fork_no_slot")
                 raise ShedError("fork(): no free decode slot")
             parent_pages = self._slot_pages[s]
             pos = int(self._pos[s])
@@ -897,12 +927,12 @@ class ContinuousBatcher:
             fresh = sum(1 for b in blocks if b not in self._shared_ledger)
             if self._committed + worst + len(self._shared_ledger) + fresh \
                     > self._alloc.usable:
-                self._shed_counter("fork_capacity").inc()
+                self._shed("fork_capacity")
                 raise ShedError(
                     f"fork(): insufficient KV block headroom (need {worst} "
                     f"committed + {fresh} shared)")
             if self._win is not None and not self._win.fits(pos + max_new):
-                self._shed_counter("fork_capacity").inc()
+                self._shed("fork_capacity")
                 raise ShedError("fork(): insufficient KV block headroom in "
                                 "the window group")
             child = _GenRequest(req.prompt, max_new,
@@ -1150,9 +1180,10 @@ class ContinuousBatcher:
             self._m_pf_depth.set(len(self._jobs))
         job.req._finish(err)
 
-    def _prefill_step(self, job: _PrefillJob, snap) -> None:
+    def _prefill_step(self, job: _PrefillJob, snap,
+                      clock: _trace.PhaseClock) -> None:
         """Advance one chunk of one prompt."""
-        with _trace.span(_trace.GEN_PREFILL_CHUNK):
+        with clock.span(_trace.GEN_PREFILL_CHUNK) as chunk:
             off, true_len, bucket = job.chunks[job.idx]
             with self._cond:
                 if self._slot_job[job.slot] is not job:
@@ -1166,22 +1197,18 @@ class ContinuousBatcher:
             if _prof.ACTIVE is not None:
                 # live prompt tokens vs the chunk bucket they padded to
                 _prof.ACTIVE.hint("generate", true_len, bucket)
-            t0 = time.perf_counter()
             last = self._programs.prefill_chunk(
                 self._params_for(snap), snap.state,
                 job.req.prompt[off:off + true_len], bucket, table_row, off)
-            t1 = time.perf_counter()
             req = job.req
             ctx = req.ctx
             if job.idx == 0:  # first chunk closes the queue wait (its offset
                 # is nonzero when a cached prefix was adopted)
-                self._queue_wait_over(req, t0)
-            if ctx is None:
-                self._m_prefill_s.observe(t1 - t0)
-            else:
-                self._m_prefill_s.observe(t1 - t0, trace_id=ctx.trace_id)
-                ctx.add_stage("prefill_chunk", int(t0 * 1e9), int(t1 * 1e9),
-                              offset=off, bucket=bucket)
+                self._queue_wait_over(req, chunk.t0 * 1e-9)
+            if ctx is not None:
+                ctx.add_stage("prefill_chunk", chunk.t0,
+                              time.perf_counter_ns(), offset=off,
+                              bucket=bucket)
             self._m_pf_chunks.inc()
             job.gens.add(snap.generation)
             job.last = last
@@ -1192,8 +1219,12 @@ class ContinuousBatcher:
                     self._prefill_sigs.add(sig)
                     if self._aot is None:  # with a store, AotFunction counts real traces
                         self._m_compiles.inc()
+        # serve_gen_prefill_seconds: the span's own two stamps
+        self._m_prefill_s.observe(
+            (chunk.t1 - chunk.t0) * 1e-9,
+            trace_id=None if ctx is None else ctx.trace_id)
         if job.idx == len(job.chunks):
-            with _trace.span(_trace.GEN_FIRST_TOKEN):
+            with clock.span(_trace.GEN_FIRST_TOKEN):
                 self._finish_prefill(job)
 
     def _queue_wait_over(self, req: _GenRequest, t0: float) -> None:
@@ -1208,8 +1239,9 @@ class ContinuousBatcher:
     def _push_first(self, req: _GenRequest, tok0: int) -> None:
         """The prefill-sampled token: output like any other, and the end of
         the server's own time to first token."""
-        req._push(tok0)
-        req.first_t = time.perf_counter()
+        stamp = time.perf_counter_ns()
+        req._push(tok0, stamp)
+        req.first_t = stamp * 1e-9
         self._m_first_s.observe(req.first_t - req.enq_t)
         self._m_tokens.inc()
 
@@ -1314,20 +1346,21 @@ class ContinuousBatcher:
             self._ring_tail(self._slot_ring[s], n_end))
         self._m_px_answer.inc(added * bs)
 
-    def _maybe_finish(self, s: int, generation=None) -> None:
-        """Retire slot ``s`` if its request is done. ``generation``: that of
-        the lease of the decode tick that just ran (None before any has:
-        there is then no block behind the prompt's to cache)."""
+    def _maybe_finish(self, s: int, generation=None) -> bool:
+        """Retire slot ``s`` if its request is done; True where it is still
+        decoding. ``generation``: that of the lease of the decode tick that
+        just ran (None before any has: there is then no block behind the
+        prompt's to cache)."""
         with self._cond:
             req = self._slot_req[s]
             if req is None:
-                return
+                return False
             done = (req.cancelled is not None
                     or len(req.out) >= req.max_new
                     or (req.eos_id is not None and req.out
                         and req.out[-1] == req.eos_id))
             if not done:
-                return
+                return True
             self._slot_req[s] = None
             if self._slot_pages[s] is not None:
                 self._cache_answer(s, req, generation)
@@ -1345,16 +1378,17 @@ class ContinuousBatcher:
             self._m_completed.inc()
             self._m_active.set(sum(1 for r in self._slot_req if r is not None))
         req._finish(req.cancelled)
+        return False
 
-    def _tick(self, snap, epoch: int) -> None:
+    def _tick(self, snap, epoch: int, clock: _trace.PhaseClock) -> None:
         """Decode one token for every slot; bookkeep the active ones."""
         # chaos seam, deliberately BEFORE any device dispatch or pool
         # mutation: an injected error/hang here simulates a wedged or dying
         # decode step without ever corrupting donated buffers
         if _faults.ACTIVE is not None:
             _faults.ACTIVE.hit("serve.decode_step")
-        with _trace.span(_trace.GEN_TICK) as tick:
-            with _trace.span(_trace.GEN_TICK_PREPARE):
+        with clock.span(_trace.GEN_TICK) as tick:
+            with clock.span(_trace.GEN_TICK_PREPARE):
                 with self._cond:
                     if self._epoch != epoch:
                         # staled by a crash-only restart; the new worker
@@ -1387,7 +1421,7 @@ class ContinuousBatcher:
                             self._cow_copies += 1
                             self._m_cow.inc()
                         self._write_table_row(s, pages.blocks)
-                    ring_cow = self._tick_rings(active)
+                    ring_cow = self._tick_rings(active, clock)
                     self._update_kv_gauges()
                     mask = np.zeros(self.slots, bool)
                     mask[active] = True
@@ -1406,9 +1440,7 @@ class ContinuousBatcher:
             if _prof.ACTIVE is not None:
                 # live slots vs the fixed slot axis the decode step pads to
                 _prof.ACTIVE.hint("generate", len(active), self.slots)
-            # serve_gen_decode_seconds: dispatch + readback, by construction
-            t0 = time.perf_counter()
-            with _trace.span(_trace.GEN_TICK_DISPATCH):
+            with clock.span(_trace.GEN_TICK_DISPATCH) as dispatch:
                 params = self._params_for(snap)
                 if cow:
                     # device-side CoW copies, outside the lock (pools are
@@ -1419,19 +1451,19 @@ class ContinuousBatcher:
                     self._programs.copy_blocks(ring_cow, WINDOW)
                 nxt, new_keys = self._programs.decode(
                     params, snap.state, toks, tables, pos, keys, temps, topks)
-            with _trace.span(_trace.GEN_TICK_READBACK):
+            with clock.span(_trace.GEN_TICK_READBACK) as readback:
                 nxt_np = np.asarray(nxt)
                 keys_np = np.asarray(new_keys, np.uint32)
-            t1 = time.perf_counter()
-            with _trace.span(_trace.GEN_TICK_PUBLISH):
+            with clock.span(_trace.GEN_TICK_PUBLISH):
                 if self._programs.routed:
                     self._count_routing(
                         "decode", self._programs.decode_routing(nxt_np))
                     self._count_chunks_routing()
-                self._m_decode_s.observe(t1 - t0)
+                # serve_gen_decode_seconds: dispatch + readback, by their stamps
+                t0_ns, t1_ns = dispatch.t0, readback.t1
+                self._m_decode_s.observe((t1_ns - t0_ns) * 1e-9)
                 self._m_occupancy.observe(len(active) / self.slots)
                 self._m_tokens.inc(len(active))
-                t0_ns = t1_ns = -1  # converted lazily: only for traced reqs
                 pushes = []
                 with self._cond:
                     if self._epoch != epoch:
@@ -1447,20 +1479,57 @@ class ContinuousBatcher:
                         if req is None:
                             continue
                         if req.ctx is not None:
-                            if t1_ns < 0:
-                                t0_ns, t1_ns = int(t0 * 1e9), int(t1 * 1e9)
                             req.ctx.decode_tick(t0_ns, t1_ns)
                         tok = int(nxt_np[s])
                         self._next_tok[s] = tok
                         self._pos[s] = self._pos[s] + 1
                         self._keys[s] = keys_np[s]
                         pushes.append((req, tok))
+                # the tick's one publish stamp: every pushed token carries
+                # it, and the clock measures the gap from the last one
+                stamp = clock.tick()
+                sheds, self._sheds_at_tick = self._sheds_at_tick, self._sheds
+                if clock.stall is not None:
+                    self._record_stall(clock.stall, len(active),
+                                       self._sheds_at_tick - sheds)
                 for req, tok in pushes:
-                    req._push(tok)
-                for s in active:
-                    self._maybe_finish(s, snap.generation)
+                    req._push(tok, stamp)
+                left = sum(self._maybe_finish(s, snap.generation)
+                           for s in active)
+                clock.decoding(left)
+                self._watch_stacks(stamp if left else None)
 
-    def _tick_rings(self, active: List[int]) -> List[tuple]:
+    def _record_stall(self, stall: dict, active: int, sheds: int) -> None:
+        """A stall the clock caught (counted there already) into the flight
+        recorder, where one is installed, with what the scheduler held as it
+        ended."""
+        rec = _flight.ACTIVE
+        if rec is None:
+            return
+        with self._cond:
+            queue, jobs = len(self._queue), len(self._jobs)
+        rec.record_event(
+            "stall", "gen", f"{stall['gap_s']:.3f}s in {stall['phase']}",
+            active_slots=active, queue_depth=queue, prefill_jobs=jobs,
+            sheds=sheds, **self._lbl(), **stall)
+        if stall["gap_s"] >= _flight.STACKS_AFTER_S:
+            rec.note_stacks(
+                f"stall of {stall['gap_s']:.3f}s in {stall['phase']} ended "
+                f"time_ns={stall['time_ns']}: the dump above, if any, is its")
+
+    def _watch_stacks(self, stamp_ns: Optional[int]) -> None:
+        """Keep the installed flight recorder's stack watchdog armed while a
+        slot decodes (``stamp_ns``: the tick's stamp) and cancelled while
+        none does (None). With no recorder: two attribute loads."""
+        rec = _flight.ACTIVE if stamp_ns is not None else None
+        if rec is not None:
+            rec.watch_stacks(stamp_ns)
+        elif self._watched is not None:
+            self._watched.unwatch_stacks()
+        self._watched = rec
+
+    def _tick_rings(self, active: List[int],
+                    clock: _trace.PhaseClock) -> List[tuple]:
         """Under ``self._cond``, inside the tick's prepare phase: the window
         group's share of it. For every decoding slot, release the ring's
         blocks behind the window of the token this tick writes, map its
@@ -1471,7 +1540,7 @@ class ContinuousBatcher:
         if self._win is None:
             return cow
         alloc = self._win.alloc
-        with _trace.span(_trace.GEN_KV_RELEASE):
+        with clock.span(_trace.GEN_KV_RELEASE):
             for s in active:
                 ring, pos = self._slot_ring[s], int(self._pos[s])
                 self._ring_step(s, ring, pos, pos + 1)
@@ -1500,9 +1569,10 @@ class ContinuousBatcher:
         for sums in self._programs.chunk_routing():
             self._count_routing("prefill", sums)
 
-    def _loop(self, epoch: int) -> None:
+    def _loop(self, epoch: int, clock: _trace.PhaseClock) -> None:
+        clock.bind()
         try:
-            self._run_loop(epoch)
+            self._run_loop(epoch, clock)
         except BaseException:
             # the decode loop is dying (injected fault, bug): a silent
             # death would hang every queued and in-flight caller — shed
@@ -1516,13 +1586,16 @@ class ContinuousBatcher:
                 err = WorkerStallError(
                     "batcher worker died; generation shed, safe to retry")
                 for req in finish:
-                    self._shed_counter("worker_stall").inc()
+                    self._shed("worker_stall")
                     req._finish(err)
             raise
+        finally:
+            clock.close()
+            self._watch_stacks(None)
 
-    def _run_loop(self, epoch: int) -> None:
+    def _run_loop(self, epoch: int, clock: _trace.PhaseClock) -> None:
         while True:
-            with _trace.span(_trace.GEN_ADMIT):
+            with clock.span(_trace.GEN_ADMIT):
                 # registry generation, read OUTSIDE self._cond (the registry
                 # has its own lock): keys prefix-cache adoption, so a publish
                 # flushes stale runs at the next admission
@@ -1550,7 +1623,10 @@ class ContinuousBatcher:
                     plan = self.scheduler.plan(jobs, decoding)
             if idle:
                 # nothing to do: sleep outside the span (waiting for work is
-                # not admission), after a second look under the lock
+                # not admission: the clock's gen.wait), after a second look
+                # under the lock
+                clock.idle()
+                self._watch_stacks(None)
                 with self._cond:
                     if not self._queue and not self._closing \
                             and self._epoch == epoch:
@@ -1560,7 +1636,7 @@ class ContinuousBatcher:
             # lies between them, up to the loop's back edge: the leases, the
             # step's device arrays freed as _tick returns (which lends the
             # interpreter lock to the stream writers the tick just woke)
-            with _trace.span(_trace.GEN_TURN):
+            with clock.span(_trace.GEN_TURN):
                 for job in plan:
                     if job.req.cancelled is not None:
                         # consumer vanished mid-prefill: abort here, where
@@ -1576,14 +1652,15 @@ class ContinuousBatcher:
                         # one lease per chunk: hot-swap drains at chunk
                         # granularity, not whole-prompt granularity
                         with self.registry.lease(tag="gen_prefill") as snap:
-                            self._prefill_step(job, snap)
+                            self._prefill_step(job, snap, clock)
                     except ServeError as e:
                         self._abort_job(job, e)
                     except Exception as e:  # slot loop must outlive any bad request  # jaxlint: disable=broad-except
                         self._abort_job(job,
                                         ServeError(f"{type(e).__name__}: {e}"))
                 with self.registry.lease(tag="gen_decode") as snap:
-                    self._tick(snap, epoch)
+                    self._tick(snap, epoch, clock)
+            clock.turn_end()
 
     # ------------------------------------------------- watchdog + crash-only
     def heartbeat(self) -> float:
@@ -1647,7 +1724,7 @@ class ContinuousBatcher:
             f"in-flight generation abandoned by batcher restart ({reason}); "
             f"safe to retry")
         for req in finish:
-            self._shed_counter("worker_stall").inc()
+            self._shed("worker_stall")
             req._finish(err)
         self.registry.release_thread(old.ident if old is not None else None)
         return True
@@ -1749,7 +1826,7 @@ class ContinuousBatcher:
             f"shutdown drain timed out after {timeout}s with generation "
             f"in flight")
         for req in finish:
-            self._shed_counter("drain_timeout").inc()
+            self._shed("drain_timeout")
             req._finish(err)
         self.registry.release_thread(self._thread.ident)
         return False

@@ -95,6 +95,11 @@ def retry_after_s(depth: int, limit: int, rng=None) -> int:
     return jitter_retry_after(max(1.0, min(30.0, 1 + 29 * frac)), rng)
 
 
+# serve_http_token_write_lag_seconds: 50 us (a writer already awake) to 5 s
+WRITE_LAG_BUCKETS = (5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 1e-2,
+                     2.5e-2, 5e-2, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0)
+
+
 def chaos_status() -> dict:
     """JSON echo of the process-global fault plane (GET /v1/debug/chaos)."""
     plane = _faults.ACTIVE
@@ -181,6 +186,13 @@ class ModelServer(JsonHTTPServerMixin):
         self.strict_aot = bool(strict_aot)
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._gc_pauses = _trace.GcPauses(self.metrics)  # on from start() to stop()
+        # a streamed token's wait between the worker's push and the socket:
+        # beside the worker's own stall log (serve_gen_stalls_total) it tells
+        # a worker that stood still from handler threads that starved
+        self._m_write_lag = self.metrics.histogram(
+            "serve_http_token_write_lag_seconds", buckets=WRITE_LAG_BUCKETS,
+            help="from the generation worker's push of a token to its SSE "
+                 "event flushed to the socket, per streamed token")
         if self.strict_aot and aot_store is None:
             raise ValueError("strict_aot=True requires an aot_store")
         if aot_manifest is not None:
@@ -476,11 +488,14 @@ class ModelServer(JsonHTTPServerMixin):
                     body["generation"] = handle.generation
                 self.reply(200, body)
 
-            def _sse(self, payload):
+            def _sse(self, payload, pushed_ns=None):
                 with _trace.span(_trace.HTTP_STREAM_WRITE):
                     self.wfile.write(
                         b"data: " + json.dumps(payload).encode() + b"\n\n")
                     self.wfile.flush()  # one event per decoded token
+                if pushed_ns is not None:   # a token: the worker's stamp
+                    server._m_write_lag.observe(
+                        (time.perf_counter_ns() - pushed_ns) * 1e-9)
 
             def _generate(self, req, query):
                 ctx = getattr(self, "_obs_ctx", None)
@@ -525,7 +540,8 @@ class ModelServer(JsonHTTPServerMixin):
                 try:
                     for tok in handle.stream():
                         out.append(int(tok))
-                        self._sse({"token": int(tok)})
+                        self._sse({"token": int(tok)},
+                                  handle.pushed_ns[len(out) - 1])
                     self._sse({"done": True, "tokens": out})
                 except ServeError as e:
                     # mid-stream failure: partial output + the typed cause
