@@ -314,6 +314,14 @@ STALL_FACTOR = 4                # x the mean of the last STALL_GAPS gaps: a tick
 #   that carries a prefill chunk is ~1.5 x a plain one and stays out, and so
 #   do the 45 ms ticks of the slowest configuration
 STALL_GAPS = 64
+# The generation worker enqueues decode step n+1 while step n runs, as late as
+# the device allows (serve/continuous.py: so that an arrival's prefill chunk
+# still goes in front of it). It aims for the enqueue to return this long
+# before the running step's expected end: above the jitter of the host's own
+# lead (prepare + dispatch, 2-5 ms with a spread under a millisecond), far
+# below a step. Late costs an idle gap (serve_gen_ticks_ahead_total misses
+# one); early costs an arrival one step of its time to first token.
+AHEAD_MARGIN_NS = 2_000_000
 STALL_TURNS = 32                # turns kept for a stall's record
 STALL_BUCKETS = (0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 SCHEDSTAT = "/proc/thread-self/schedstat"   # ns running, ns RUNNABLE and not

@@ -73,6 +73,16 @@ still covers; copy-on-write and forks work per group. Nothing selects this
 but the model's cache spec, and a model with one group is served exactly as
 before.
 
+The worker runs one decode step AHEAD of what it has read (the comment
+above ``_tick`` has the order of a turn and what it rests on): step n+1 is
+prepared and enqueued while step n runs, its tokens, positions and keys
+handed on inside the device (``serve/programs.py``), so the host's
+milliseconds a tick hide behind the step instead of standing between two
+steps. The next step is enqueued as late as the device allows (``_slack``),
+so that a request arriving meanwhile still gets its prefill chunk in front
+of it. Same seed and same requests give the same tokens as a loop that
+reads every step before it enqueues the next.
+
 The bit-exact baseline for all of it is whole-batch
 ``nn.generation.generate`` over its contiguous caches.
 
@@ -84,6 +94,7 @@ at all; both families stay on whole-batch ``nn.generation.generate``.
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import threading
 import time
@@ -308,6 +319,124 @@ class _PrefillJob:
         return self.req.enq_t
 
 
+class _Mean:
+    """The mean of the last ``STALL_GAPS`` samples (the window the worker's
+    clock keeps its stall limit over), in whole nanoseconds."""
+
+    __slots__ = ("_last", "_sum")
+
+    def __init__(self):
+        self._last = collections.deque(maxlen=_trace.STALL_GAPS)
+        self._sum = 0
+
+    def clear(self) -> None:
+        self._last.clear()
+        self._sum = 0
+
+    def add(self, ns: int) -> None:
+        if len(self._last) == self._last.maxlen:
+            self._sum -= self._last[0]
+        self._last.append(ns)
+        self._sum += ns
+
+    @property
+    def n(self) -> int:
+        return len(self._last)
+
+    @property
+    def mean(self) -> int:
+        return self._sum // len(self._last) if self._last else 0
+
+
+class _Step:
+    """One decode step from its enqueue to its publish. What a tick books
+    belongs to the step it reads, not to the turn that happens to read it,
+    so it travels here: the rows the step was given (slot and the request
+    that held it THEN), its lease and that lease's params generation, its
+    own first dispatch stamp."""
+
+    __slots__ = ("seq", "nxt", "rows", "snap", "lease", "t0", "start",
+                 "chunks", "chunks_upto", "ahead")
+
+    def __init__(self, seq: int, lease, snap):
+        self.seq = seq
+        self.lease, self.snap = lease, snap
+        self.nxt = None         # its tokens, on the device
+        self.rows: List[tuple] = []
+        self.t0 = 0             # gen.tick.dispatch's first stamp
+        # when the device began it, as the host can tell: its enqueue's
+        # return, or the return of the readback of the step in front of it
+        self.start = 0
+        self.chunks = 0         # prefill chunks queued in front of it,
+        #   behind the step before it
+        self.chunks_upto = 0    # ... and all chunks enqueued before it
+        self.ahead = False      # enqueued while the step before it ran
+
+    def release(self) -> None:
+        """Return the lease (idempotent: the registry's token is)."""
+        self.lease.__exit__(None, None, None)
+
+
+class _First(NamedTuple):
+    """A first token the sampler left on the device, not read yet."""
+
+    req: "_GenRequest"  # in its slot (req.slot) since the sampler's enqueue
+    tok: object         # the device scalar
+    before: int         # it is computed in front of the step of this seq
+    chunks_upto: int    # chunks enqueued up to and including its own
+
+
+class _RunAhead:
+    """One worker's view of the device's queue: the decode step enqueued and
+    not read yet, the first tokens not read yet, and what it has measured
+    to decide how late the next step may be enqueued (all of it from the
+    worker's own stamps: the step's length, the host's lead from the
+    decision to the enqueue's return, a chunk's length)."""
+
+    def __init__(self):
+        self.step: Optional[_Step] = None
+        self.seq = 0            # steps enqueued so far
+        self.done = 0           # seq of the last step read back
+        self.firsts: List[_First] = []
+        self.chunks = 0         # chunks enqueued since the last step was
+        self.step_ns, self.lead_ns, self.chunk_ns = _Mean(), _Mean(), _Mean()
+        self.waited = False     # this turn slept in its slack
+
+    def deadline(self) -> Optional[int]:
+        """The stamp at which to begin the next step's prepare so that its
+        enqueue returns ``AHEAD_MARGIN_NS`` before the device runs dry:
+        the running step's expected end, the chunks queued in front of it
+        and behind it included. None where there is nothing to go by (no
+        step running, no measurement yet, a start nobody stamped): enqueue
+        at once."""
+        st = self.step
+        if st is None or not self.step_ns.n or not self.lead_ns.n:
+            return None
+        if st.chunks and not st.ahead:
+            # enqueued behind a chunk that was already running: when that
+            # chunk began nobody stamped, so nothing says when this step will
+            # end (guessing late idles the device for a chunk's length)
+            return None
+        return (st.start + self.step_ns.mean
+                + (st.chunks + self.chunks) * self.chunk_ns.mean
+                - self.lead_ns.mean - _trace.AHEAD_MARGIN_NS)
+
+
+class _ForkCall:
+    """One ``fork()`` on its way to the worker, which runs it between two
+    turns with no step in flight."""
+
+    __slots__ = ("req", "max_new", "temperature", "top_k", "key", "child",
+                 "error", "done")
+
+    def __init__(self, req, max_new, temperature, top_k, key):
+        self.req, self.max_new = req, max_new
+        self.temperature, self.top_k, self.key = temperature, top_k, key
+        self.child: Optional[_GenRequest] = None
+        self.error: Optional[Exception] = None
+        self.done = threading.Event()
+
+
 class ContinuousBatcher:
     """Fixed-slot continuous-batching decode loop over a model registry.
 
@@ -462,11 +591,33 @@ class ContinuousBatcher:
         self._prefill_sigs = set()
         self._decode_sigs = set()
 
+        # the host's view of each slot, as of the last step PUBLISHED:
+        # the token the slot's next step consumes and its position. The
+        # device is up to two steps further (the programs carry tokens,
+        # positions and keys from step to step): these vectors, the keys
+        # and the two sampling vectors are uploaded for the rows the host
+        # sets, at an admission or a fork (_fresh), and not between
         self._next_tok = np.zeros(S, np.int32)
         self._pos = np.zeros(S, np.int32)
         self._temps = np.ones(S, np.float32)
         self._topks = np.full(S, V, np.int32)
         self._keys = np.zeros((S, 2), np.uint32)
+        self._fresh = np.zeros(S, bool)
+        # rows of the slot's request enqueued and not published yet, and the
+        # tokens it has been given or promised (its first one, then a row
+        # each): a request's last step is known by count before it is
+        # enqueued, so no row is given beyond max_new
+        self._unread = np.zeros(S, np.int32)
+        self._made = np.zeros(S, np.int64)
+        # a first token still on the device (the sampler's scalar), and the
+        # key it left the slot (a device value too: reading it back would
+        # wait for everything the device has queued): they reach the slot's
+        # first decode step without visiting the host
+        self._first_dev: List[Optional[object]] = [None] * S
+        self._key_dev: List[Optional[object]] = [None] * S
+        # prefill chunks enqueued / whose routing sums have been read
+        self._chunks_enq = self._chunks_read = 0
+        self._fork_calls: List[_ForkCall] = []
 
         m = self.metrics
         self._m_active = m.gauge("serve_gen_active_slots", self._lbl(),
@@ -482,8 +633,20 @@ class ContinuousBatcher:
                                         "prefill-sampled first token included)")
         self._m_decode_s = m.histogram(
             "serve_gen_decode_seconds", self._lbl(), buckets=TICK_BUCKETS,
-            help="one all-slots decode tick: gen.tick.dispatch + "
-                 "gen.tick.readback, first upload to both readbacks returned")
+            help="one all-slots decode step: its own gen.tick.dispatch's "
+                 "first stamp to the return of its own readback; the worker "
+                 "enqueues the next step in between, so about a period and "
+                 "a half where it runs ahead")
+        self._m_ahead = m.counter(
+            "serve_gen_ticks_ahead_total", self._lbl(),
+            help="decode steps whose enqueue returned while the step before "
+                 "them was still unread and not ready: the device had its "
+                 "next step before it finished the last")
+        self._m_discarded = m.counter(
+            "serve_gen_rows_discarded_total", self._lbl(),
+            help="rows of decode steps computed and thrown away: the step "
+                 "was enqueued before the host saw its request end (eos_id, "
+                 "a cancel)")
         self._m_prefill_s = m.histogram(
             "serve_gen_prefill_seconds", self._lbl(),
             help="the worker's time in one gen.prefill_chunk (per chunk when "
@@ -663,7 +826,8 @@ class ContinuousBatcher:
         self._clock = _trace.PhaseClock(self.metrics, self._lbl(),
                                         after=self._clock)
         self._thread = threading.Thread(
-            target=self._loop, args=(self._epoch, self._clock), daemon=True,
+            target=self._loop, args=(self._epoch, self._clock, _RunAhead()),
+            daemon=True,
             name=f"serve-continuous-batcher-{self._epoch}")
         self._thread.start()
 
@@ -894,84 +1058,124 @@ class ContinuousBatcher:
         # disjoint salt space from admission's fold_in(n): forks fold twice
         key = jax.random.fold_in(
             jax.random.fold_in(self._base_key, 0x666f726b), salt)
-        key_np = np.asarray(key, np.uint32)
+        call = _ForkCall(req, max_new_tokens, temperature, top_k,
+                         np.asarray(key, np.uint32))
+        # The copy is the WORKER's to make, between two turns and with no
+        # step in flight: the worker runs a step or two ahead of what it has
+        # published, and a copy of the published view (token, position,
+        # table row, ring) taken under a step in flight would miss what that
+        # step moves (a ring's blocks released behind its window, most of
+        # all). A fork is rare; the worker drains its queue for it and the
+        # copy is what it always was.
         with self._cond:
-            s = req.slot
-            if s is None or self._slot_req[s] is not req \
-                    or req.event.is_set():
-                raise ServeError("fork() needs a request currently decoding "
-                                 "in a slot (not queued, prefilling, or "
-                                 "finished)")
-            t = next((i for i in range(self.slots)
-                      if self._slot_req[i] is None
-                      and self._slot_job[i] is None), None)
-            if t is None:
-                self._shed("fork_no_slot")
-                raise ShedError("fork(): no free decode slot")
-            parent_pages = self._slot_pages[s]
-            pos = int(self._pos[s])
-            max_new = int(max_new_tokens if max_new_tokens is not None
-                          else max(1, req.max_new - len(req.out)))
-            if max_new < 1:
-                raise ValueError("fork max_new_tokens must be >= 1")
-            if pos + max_new > self.capacity:
-                raise CapacityError(
-                    f"fork at position {pos} + max_new_tokens {max_new} "
-                    f"exceeds cache capacity {self.capacity}")
-            # charge only what the child can ever privately allocate: its
-            # growth blocks plus one CoW copy of the partial tail; whole
-            # shared blocks stay shared forever and ride the ledger instead
-            worst = blocks_needed(pos + max_new, self.block_size) \
-                - pos // self.block_size
-            blocks = list(parent_pages.blocks)
-            fresh = sum(1 for b in blocks if b not in self._shared_ledger)
-            if self._committed + worst + len(self._shared_ledger) + fresh \
-                    > self._alloc.usable:
-                self._shed("fork_capacity")
-                raise ShedError(
-                    f"fork(): insufficient KV block headroom (need {worst} "
-                    f"committed + {fresh} shared)")
-            if self._win is not None and not self._win.fits(pos + max_new):
-                self._shed("fork_capacity")
-                raise ShedError("fork(): insufficient KV block headroom in "
-                                "the window group")
-            child = _GenRequest(req.prompt, max_new,
-                                float(temperature if temperature is not None
-                                      else req.temperature),
-                                top_k if top_k is not None else req.top_k,
-                                req.eos_id, req.deadline)
-            if self._win is not None:
-                # the parent's ring, block for block: a held range of
-                # logical blocks, shared until one of the two writes
-                held = [b for _, b in sorted(self._slot_ring[s].blocks.items())]
-                ring = self._win.open(pos + max_new)
-                self._win.alloc.retain(held)
-                ring.adopt(self._slot_ring[s].first, held)
-                self._slot_ring[t] = ring
-                self._win.tables_np[t] = self._win.tables_np[s]
-            self._alloc.retain(blocks)
-            pages = SlotPages(self._alloc, self.block_size)
-            pages.adopt(blocks)
-            self._ledger_add(blocks)
-            self._committed += worst
-            self._slot_pages[t] = pages
-            self._slot_worst[t] = worst
-            self._slot_req[t] = child
-            child.slot = t
-            self._tables_np[t] = self._tables_np[s]
-            self._next_tok[t] = self._next_tok[s]
-            self._pos[t] = pos
-            self._temps[t] = child.temperature
-            self._topks[t] = child.top_k if child.top_k else self.vocab
-            self._keys[t] = key_np
-            self._forks += 1
-            self._m_forks.inc()
-            self._m_admitted.inc()
-            active = sum(1 for r in self._slot_req if r is not None)
-            self._peak_active = max(self._peak_active, active)
-            self._m_active.set(active)
-            self._update_kv_gauges()
+            self._fork_parent_locked(req)
+            if threading.current_thread() is self._thread \
+                    or not self._thread.is_alive() or self._closing:
+                raise ServeError("fork() needs a running worker to make the "
+                                 "copy, and cannot be called from it")
+            self._fork_calls.append(call)
             self._cond.notify_all()
+        call.done.wait()
+        if call.error is not None:
+            raise call.error
+        return call.child
+
+    def _fork_parent_locked(self, req: _GenRequest) -> int:
+        s = req.slot
+        if s is None or self._slot_req[s] is not req or req.event.is_set():
+            raise ServeError("fork() needs a request currently decoding "
+                             "in a slot (not queued, prefilling, or "
+                             "finished)")
+        return s
+
+    def _run_forks_locked(self) -> None:
+        """Under ``self._cond``, on the worker, nothing in flight: make the
+        copies callers are waiting for."""
+        calls, self._fork_calls = self._fork_calls, []
+        for call in calls:
+            try:
+                call.child = self._fork_locked(call)
+            except (ServeError, ValueError) as e:
+                call.error = e
+            call.done.set()
+
+    def _fork_locked(self, call: _ForkCall) -> _GenRequest:
+        req, max_new_tokens = call.req, call.max_new
+        temperature, top_k = call.temperature, call.top_k
+        s = self._fork_parent_locked(req)
+        t = next((i for i in range(self.slots)
+                  if self._slot_req[i] is None
+                  and self._slot_job[i] is None), None)
+        if t is None:
+            self._shed("fork_no_slot")
+            raise ShedError("fork(): no free decode slot")
+        parent_pages = self._slot_pages[s]
+        pos = int(self._pos[s])
+        max_new = int(max_new_tokens if max_new_tokens is not None
+                      else max(1, req.max_new - len(req.out)))
+        if max_new < 1:
+            raise ValueError("fork max_new_tokens must be >= 1")
+        if pos + max_new > self.capacity:
+            raise CapacityError(
+                f"fork at position {pos} + max_new_tokens {max_new} "
+                f"exceeds cache capacity {self.capacity}")
+        # charge only what the child can ever privately allocate: its
+        # growth blocks plus one CoW copy of the partial tail; whole
+        # shared blocks stay shared forever and ride the ledger instead
+        worst = blocks_needed(pos + max_new, self.block_size) \
+            - pos // self.block_size
+        blocks = list(parent_pages.blocks)
+        fresh = sum(1 for b in blocks if b not in self._shared_ledger)
+        if self._committed + worst + len(self._shared_ledger) + fresh \
+                > self._alloc.usable:
+            self._shed("fork_capacity")
+            raise ShedError(
+                f"fork(): insufficient KV block headroom (need {worst} "
+                f"committed + {fresh} shared)")
+        if self._win is not None and not self._win.fits(pos + max_new):
+            self._shed("fork_capacity")
+            raise ShedError("fork(): insufficient KV block headroom in "
+                            "the window group")
+        child = _GenRequest(req.prompt, max_new,
+                            float(temperature if temperature is not None
+                                  else req.temperature),
+                            top_k if top_k is not None else req.top_k,
+                            req.eos_id, req.deadline)
+        if self._win is not None:
+            # the parent's ring, block for block: a held range of
+            # logical blocks, shared until one of the two writes
+            held = [b for _, b in sorted(self._slot_ring[s].blocks.items())]
+            ring = self._win.open(pos + max_new)
+            self._win.alloc.retain(held)
+            ring.adopt(self._slot_ring[s].first, held)
+            self._slot_ring[t] = ring
+            self._win.tables_np[t] = self._win.tables_np[s]
+        self._alloc.retain(blocks)
+        pages = SlotPages(self._alloc, self.block_size)
+        pages.adopt(blocks)
+        self._ledger_add(blocks)
+        self._committed += worst
+        self._slot_pages[t] = pages
+        self._slot_worst[t] = worst
+        self._slot_req[t] = child
+        child.slot = t
+        self._tables_np[t] = self._tables_np[s]
+        # the five values the host sets (the same five an admission does)
+        self._next_tok[t] = self._next_tok[s]
+        self._pos[t] = pos
+        self._temps[t] = child.temperature
+        self._topks[t] = child.top_k if child.top_k else self.vocab
+        self._keys[t] = call.key
+        self._first_dev[t] = self._key_dev[t] = None
+        self._fresh[t] = True
+        self._unread[t] = self._made[t] = 0
+        self._forks += 1
+        self._m_forks.inc()
+        self._m_admitted.inc()
+        active = sum(1 for r in self._slot_req if r is not None)
+        self._peak_active = max(self._peak_active, active)
+        self._m_active.set(active)
+        self._update_kv_gauges()
         return child
 
     # ---------------------------------------------------------------- serving
@@ -1181,8 +1385,9 @@ class ContinuousBatcher:
         job.req._finish(err)
 
     def _prefill_step(self, job: _PrefillJob, snap,
-                      clock: _trace.PhaseClock) -> None:
-        """Advance one chunk of one prompt."""
+                      clock: _trace.PhaseClock, ra: _RunAhead) -> None:
+        """Advance one chunk of one prompt: enqueued behind the decode step
+        that runs, in front of the next."""
         with clock.span(_trace.GEN_PREFILL_CHUNK) as chunk:
             off, true_len, bucket = job.chunks[job.idx]
             with self._cond:
@@ -1210,6 +1415,8 @@ class ContinuousBatcher:
                               time.perf_counter_ns(), offset=off,
                               bucket=bucket)
             self._m_pf_chunks.inc()
+            self._chunks_enq += 1
+            ra.chunks += 1
             job.gens.add(snap.generation)
             job.last = last
             job.idx += 1
@@ -1225,7 +1432,7 @@ class ContinuousBatcher:
             trace_id=None if ctx is None else ctx.trace_id)
         if job.idx == len(job.chunks):
             with clock.span(_trace.GEN_FIRST_TOKEN):
-                self._finish_prefill(job)
+                self._finish_prefill(job, ra)
 
     def _queue_wait_over(self, req: _GenRequest, t0: float) -> None:
         """Stamp the dispatch of a request's first prefill chunk: the one
@@ -1236,20 +1443,14 @@ class ContinuousBatcher:
         if req.ctx is not None:
             req.ctx.add_stage("queue", int(req.enq_t * 1e9), int(t0 * 1e9))
 
-    def _push_first(self, req: _GenRequest, tok0: int) -> None:
-        """The prefill-sampled token: output like any other, and the end of
-        the server's own time to first token."""
-        stamp = time.perf_counter_ns()
-        req._push(tok0, stamp)
-        req.first_t = stamp * 1e-9
-        self._m_first_s.observe(req.first_t - req.enq_t)
-        self._m_tokens.inc()
-
-    def _finish_prefill(self, job: _PrefillJob) -> None:
-        """Last chunk done: sample the first token, flip the slot from
-        prefilling to decoding."""
+    def _finish_prefill(self, job: _PrefillJob, ra: _RunAhead) -> None:
+        """Last chunk done: enqueue the first token's sample, flip the slot
+        from prefilling to decoding. The token is NOT read here (that would
+        wait for the running step and the chunk, past the next step's
+        deadline): it stays on the device, where the slot's first decode
+        step takes it, and :meth:`_read_firsts` pushes it as soon as it is
+        ready."""
         import jax
-        import numpy as _np
 
         req, s = job.req, job.slot
         gen_now = (self.registry.generation
@@ -1280,11 +1481,12 @@ class ContinuousBatcher:
             req.ctx.decode_begin()
         key = jax.random.fold_in(self._base_key, n)
         key, sub = jax.random.split(key)
-        tok0 = int(_np.asarray(self._programs.sample(
+        tok0 = self._programs.sample(
             job.last[0], sub, req.temperature,
-            req.top_k if req.top_k else self.vocab)))
-        self._count_chunks_routing()
+            req.top_k if req.top_k else self.vocab)
         with self._cond:
+            if self._slot_job[s] is not job:
+                return  # shed by a restart while the sampler was enqueued
             if job in self._jobs:
                 self._jobs.remove(job)
             self._slot_job[s] = None
@@ -1295,17 +1497,60 @@ class ContinuousBatcher:
             req.slot = s
             req.key = None
             self._slot_req[s] = req
-            self._next_tok[s] = tok0
+            # the five values the host sets; the token and the key as
+            # device values
+            self._first_dev[s] = tok0
+            self._key_dev[s] = key
             self._pos[s] = req.prompt.shape[0]
             self._temps[s] = req.temperature
             self._topks[s] = req.top_k if req.top_k else self.vocab
-            self._keys[s] = np.asarray(key, np.uint32)
+            self._fresh[s] = True
+            self._unread[s] = 0
+            self._made[s] = 1       # the first token is promised
             self._m_admitted.inc()
             active = sum(1 for r in self._slot_req if r is not None)
             self._peak_active = max(self._peak_active, active)
             self._m_active.set(active)
-        self._push_first(req, tok0)
-        # a 1-token request (or instant EOS) finishes without ever decoding
+        ra.firsts.append(_First(req, tok0, ra.seq + 1, self._chunks_enq))
+
+    def _read_firsts(self, ra: _RunAhead, block: bool = False) -> None:
+        """Push the first tokens that can be read without waiting: those
+        computed in front of a step already read back, and those the device
+        says are ready. ``block``: also wait for those whose chunk the
+        device is running NOW (nothing but the chunk in front of them: the
+        step before it has been read back), which the caller does where the
+        host has the time."""
+        if not ra.firsts:
+            return
+        keep = []
+        for f in ra.firsts:
+            if f.before <= ra.done or f.tok.is_ready() \
+                    or (block and f.before <= ra.done + 1):
+                self._push_first(f, int(np.asarray(f.tok)))
+            else:
+                keep.append(f)
+        ra.firsts = keep
+
+    def _push_first(self, f: _First, tok0: int) -> None:
+        """The prefill-sampled token: output like any other, and the end of
+        the server's own time to first token."""
+        req, s = f.req, f.req.slot
+        if self._programs.routed:
+            # the chunks in front of this token have run
+            self._count_chunks_routing(f.chunks_upto)
+        with self._cond:
+            if self._slot_req[s] is not req:
+                return      # shed since (a restart, a forced shutdown)
+            self._first_dev[s] = None
+            self._next_tok[s] = tok0    # where the slot's first step is
+            #   still to be enqueued, it is a host value like a fork's
+        stamp = time.perf_counter_ns()
+        req._push(tok0, stamp)
+        req.first_t = stamp * 1e-9
+        self._m_first_s.observe(req.first_t - req.enq_t)
+        self._m_tokens.inc()
+        # a 1-token request finishes without ever decoding; an instant EOS
+        # may have a row or two in flight, which their publish discards
         self._maybe_finish(s)
 
     def _ring_tail(self, ring: Optional[RingPages],
@@ -1362,6 +1607,11 @@ class ContinuousBatcher:
             if not done:
                 return True
             self._slot_req[s] = None
+            # rows of its still in flight are discarded at their publish
+            # (the step holds the request, and the slot no longer does)
+            self._unread[s] = self._made[s] = 0
+            self._fresh[s] = False
+            self._first_dev[s] = self._key_dev[s] = None
             if self._slot_pages[s] is not None:
                 self._cache_answer(s, req, generation)
                 # copy-free retirement: blocks drop one reference (cached/
@@ -1380,36 +1630,106 @@ class ContinuousBatcher:
         req._finish(req.cancelled)
         return False
 
-    def _tick(self, snap, epoch: int, clock: _trace.PhaseClock) -> None:
-        """Decode one token for every slot; bookkeep the active ones."""
+    # ------------------------------------------------------------- the tick
+    # The worker runs ONE STEP AHEAD of what it has read: a turn enqueues
+    # decode step n+1 and only then reads step n back, so the device goes
+    # from one step to the next while the host publishes, admits and
+    # prepares. What step n+1 needs of step n (tokens, positions, keys) the
+    # programs carry on the device (serve/programs.py); its tables and
+    # blocks depend on positions only, which the host knows ahead. What the
+    # host learns one step late is what depends on a token's VALUE:
+    #
+    # - an ``eos_id`` hit (a cancel is handled like one). Step n+1 was
+    #   enqueued with a row for the slot before step n's tokens said it had
+    #   ended. That row is thrown away at its publish, never pushed
+    #   (serve_gen_rows_discarded_total; the step keeps the REQUEST of each
+    #   row, and the slot no longer holds it). What it wrote is harmless:
+    #   it lands at the position of the last sampled token, one past
+    #   everything _cache_answer caches (``prompt ++ out[:-1]``), in a block
+    #   the slot still owned when the step was enqueued (prepare's
+    #   copy-on-write made it private), and the device runs its queue in
+    #   order: a block released at publish n and handed to another slot is
+    #   written by its new owner, in a chunk or step enqueued later, after
+    #   that row wrote it (tests/test_run_ahead.py).
+    # - nothing else: ``max_new`` is a count (_made), so a request's last
+    #   step is known before it is enqueued and no row is given beyond it.
+    #
+    # What is rare drains the queue first (_settle) and is as it always
+    # was: a publish that flipped the params generation, a fork, the chaos
+    # seam. A restart drops the bookkeeping of what was in flight (_epoch).
+    def _wants_row_locked(self, s: int) -> bool:
+        req = self._slot_req[s]
+        return (req is not None and req.cancelled is None
+                and self._made[s] < req.max_new)
+
+    def _tick(self, epoch: int, clock: _trace.PhaseClock,
+              ra: _RunAhead) -> None:
+        """Enqueue the next decode step, then read back and publish the one
+        before it."""
         # chaos seam, deliberately BEFORE any device dispatch or pool
-        # mutation: an injected error/hang here simulates a wedged or dying
-        # decode step without ever corrupting donated buffers
+        # mutation, and with nothing in flight: an injected error/hang here
+        # simulates a wedged or dying decode step without ever corrupting
+        # donated buffers (with the chaos plane installed the worker reads
+        # every step back before it enqueues the next)
         if _faults.ACTIVE is not None:
+            self._settle(epoch, clock, ra)
             _faults.ACTIVE.hit("serve.decode_step")
+        # a consumer that vanished: its slot goes now, whatever is in
+        # flight for it (handled like an eos_id hit)
+        with self._cond:
+            gone = [s for s, r in enumerate(self._slot_req)
+                    if r is not None and r.cancelled is not None]
+        for s in gone:
+            self._maybe_finish(s)
         with clock.span(_trace.GEN_TICK) as tick:
+            step = self._enqueue_step(epoch, clock, ra, tick)
+            # (a drain inside the enqueue has read the step before already)
+            prev, ra.step = ra.step, step
+            self._read_firsts(ra)
+            if prev is not None:
+                self._retire_step(prev, epoch, clock, ra)
+            if ra.step is None:
+                # nothing runs behind them: read them now
+                self._read_firsts(ra, block=True)
+
+    def _enqueue_step(self, epoch: int, clock: _trace.PhaseClock,
+                      ra: _RunAhead, tick) -> Optional[_Step]:
+        """Prepare and dispatch one decode step over every slot that wants
+        a row; None where none does."""
+        t_decided = time.perf_counter_ns()
+        lease = self.registry.lease(tag="gen_decode")
+        snap = lease.__enter__()
+        step = _Step(ra.seq + 1, lease, snap)
+        try:
+            prev = ra.step
+            if prev is not None and prev.snap.generation != snap.generation:
+                # a publish flipped the params: no step of the new
+                # generation is enqueued behind one of the old (rare: drain)
+                self._settle(epoch, clock, ra)
+                prev = None
             with clock.span(_trace.GEN_TICK_PREPARE):
                 with self._cond:
                     if self._epoch != epoch:
                         # staled by a crash-only restart; the new worker
                         # owns the slots
-                        return
+                        return None
                     active = [s for s in range(self.slots)
-                              if self._slot_req[s] is not None]
+                              if self._wants_row_locked(s)]
                     if not active:
-                        return
-                    # grow lazily to cover the token this tick writes;
+                        return None
+                    # grow lazily to cover the token this step writes;
                     # the admission-time worst-case commitment guarantees
                     # success
                     cow: List[tuple] = []
                     for s in active:
                         pages = self._slot_pages[s]
-                        pages.ensure(int(self._pos[s]) + 1)
-                        wb = int(self._pos[s]) // self.block_size
+                        wpos = int(self._pos[s]) + int(self._unread[s])
+                        pages.ensure(wpos + 1)
+                        wb = wpos // self.block_size
                         blk = pages.blocks[wb]
                         if self._alloc.refcount(blk) > 1:
                             # copy-on-write: someone else (a fork peer)
-                            # still references the block this tick writes
+                            # still references the block this step writes
                             # — swap in a private copy first. Only ever
                             # the partial tail: whole shared blocks are
                             # never write targets.
@@ -1425,17 +1745,32 @@ class ContinuousBatcher:
                     self._update_kv_gauges()
                     mask = np.zeros(self.slots, bool)
                     mask[active] = True
-                    # inactive rows: zero tables (writes -> trash),
-                    # position 0
+                    # inactive rows: zero tables (writes -> trash), which
+                    # is also how the program knows them
                     tables = np.where(mask[:, None], self._tables_np, 0)
                     if self._win is not None:
                         tables = {FULL: tables, WINDOW: np.where(
                             mask[:, None], self._win.tables_np, 0)}
-                    pos = np.where(mask, self._pos, 0).astype(np.int32)
-                    toks = np.array(self._next_tok)
-                    temps = np.array(self._temps)
-                    topks = np.array(self._topks)
-                    keys = np.array(self._keys)
+                    # the rows the host sets: admitted or forked since the
+                    # last step was enqueued
+                    fresh = None
+                    if self._fresh[active].any():
+                        sets = mask & self._fresh
+                        fresh = (sets, np.array(self._next_tok),
+                                 np.array(self._pos), np.array(self._keys),
+                                 np.array(self._temps), np.array(self._topks),
+                                 {int(s): (self._first_dev[s],
+                                           self._key_dev[s])
+                                  for s in np.flatnonzero(sets)
+                                  if self._key_dev[s] is not None})
+                        self._fresh[active] = False
+                        for s in active:
+                            self._key_dev[s] = None
+                    step.rows = [(s, self._slot_req[s]) for s in active]
+                    self._unread[active] += 1
+                    self._made[active] += 1
+                    step.chunks, ra.chunks = ra.chunks, 0
+                    step.chunks_upto = self._chunks_enq
                     tick.set_metadata(active=len(active))
             if _prof.ACTIVE is not None:
                 # live slots vs the fixed slot axis the decode step pads to
@@ -1449,55 +1784,123 @@ class ContinuousBatcher:
                     self._programs.copy_blocks(cow)
                 if ring_cow:
                     self._programs.copy_blocks(ring_cow, WINDOW)
-                nxt, new_keys = self._programs.decode(
-                    params, snap.state, toks, tables, pos, keys, temps, topks)
+                step.nxt = self._programs.decode(params, snap.state, tables,
+                                                 fresh)
+                # one question a tick: did the device have this step before
+                # it finished the last?
+                step.ahead = prev is not None and not prev.nxt.is_ready()
+            step.t0 = dispatch.t0
+            step.start = dispatch.t1
+            ra.seq = step.seq
+            ra.lead_ns.add(dispatch.t1 - t_decided)
+            if step.ahead:
+                self._m_ahead.inc()
+            elif ra.waited and not step.chunks:
+                # the slack's wait outlasted the step (with a chunk in
+                # front of this one the device is still busy: no verdict):
+                # the length it went by is no longer the step's. Forget it;
+                # the next steps are enqueued at once and measure it anew
+                ra.step_ns.clear()
+            lease = None
+            return step
+        finally:
+            if lease is not None:
+                lease.__exit__(None, None, None)
+
+    def _retire_step(self, step: _Step, epoch: int,
+                     clock: _trace.PhaseClock, ra: _RunAhead) -> None:
+        """Read one enqueued step back and publish it: its tokens pushed,
+        the clock's one stamp, its finishes."""
+        nxt = ra.step
+        try:
             with clock.span(_trace.GEN_TICK_READBACK) as readback:
-                nxt_np = np.asarray(nxt)
-                keys_np = np.asarray(new_keys, np.uint32)
-            with clock.span(_trace.GEN_TICK_PUBLISH):
-                if self._programs.routed:
-                    self._count_routing(
-                        "decode", self._programs.decode_routing(nxt_np))
-                    self._count_chunks_routing()
-                # serve_gen_decode_seconds: dispatch + readback, by their stamps
-                t0_ns, t1_ns = dispatch.t0, readback.t1
-                self._m_decode_s.observe((t1_ns - t0_ns) * 1e-9)
-                self._m_occupancy.observe(len(active) / self.slots)
-                self._m_tokens.inc(len(active))
-                pushes = []
-                with self._cond:
-                    if self._epoch != epoch:
-                        # restart raced the device call: drop the bookkeeping
-                        return
-                    sig = ("decode", self.slots)
-                    if sig not in self._decode_sigs:
-                        self._decode_sigs.add(sig)
-                        if self._aot is None:  # with a store, AotFunction counts
-                            self._m_compiles.inc()
-                    for s in active:
-                        req = self._slot_req[s]
-                        if req is None:
-                            continue
-                        if req.ctx is not None:
-                            req.ctx.decode_tick(t0_ns, t1_ns)
-                        tok = int(nxt_np[s])
-                        self._next_tok[s] = tok
-                        self._pos[s] = self._pos[s] + 1
-                        self._keys[s] = keys_np[s]
-                        pushes.append((req, tok))
-                # the tick's one publish stamp: every pushed token carries
-                # it, and the clock measures the gap from the last one
-                stamp = clock.tick()
-                sheds, self._sheds_at_tick = self._sheds_at_tick, self._sheds
-                if clock.stall is not None:
-                    self._record_stall(clock.stall, len(active),
-                                       self._sheds_at_tick - sheds)
-                for req, tok in pushes:
-                    req._push(tok, stamp)
-                left = sum(self._maybe_finish(s, snap.generation)
-                           for s in active)
-                clock.decoding(left)
-                self._watch_stacks(stamp if left else None)
+                # with a step enqueued behind it, that enqueue asked; else
+                # ask here (one question a tick either way)
+                blocked = nxt.ahead if nxt is not None \
+                    else not step.nxt.is_ready()
+                nxt_np = np.asarray(step.nxt)
+            ret = readback.t1
+            ra.done = step.seq
+            if nxt is not None and nxt.ahead:
+                nxt.start = ret     # the device went straight on to it
+            if blocked:
+                # the host waited for the device, so the return is the
+                # step's end: its length, or with one chunk in front of it,
+                # the chunk's (more than one: a backlog from before any
+                # step ran, of which nobody knows how much was left)
+                took = ret - step.start
+                if not step.chunks:
+                    ra.step_ns.add(took)
+                elif step.chunks == 1 and ra.step_ns.n:
+                    ra.chunk_ns.add(max(0, took - ra.step_ns.mean))
+            self._publish_step(step, nxt_np, ret, epoch, clock, ra)
+        finally:
+            step.release()
+
+    def _publish_step(self, step: _Step, nxt_np: np.ndarray, ret: int,
+                      epoch: int, clock: _trace.PhaseClock,
+                      ra: _RunAhead) -> None:
+        with clock.span(_trace.GEN_TICK_PUBLISH):
+            # first tokens computed in front of this step come first: a
+            # request's first token is pushed before its second
+            self._read_firsts(ra)
+            if self._programs.routed:
+                self._count_routing(
+                    "decode", self._programs.decode_routing(nxt_np))
+                self._count_chunks_routing(step.chunks_upto)
+            t0_ns, t1_ns = step.t0, ret
+            pushes = []
+            with self._cond:
+                if self._epoch != epoch:
+                    # restart raced the device call: drop the bookkeeping
+                    return
+                sig = ("decode", self.slots)
+                if sig not in self._decode_sigs:
+                    self._decode_sigs.add(sig)
+                    if self._aot is None:  # with a store, AotFunction counts
+                        self._m_compiles.inc()
+                for s, req in step.rows:
+                    if self._slot_req[s] is not req:
+                        continue    # ended since the enqueue: discarded
+                    self._unread[s] -= 1
+                    if req.ctx is not None:
+                        req.ctx.decode_tick(t0_ns, t1_ns)
+                    tok = int(nxt_np[s])
+                    self._next_tok[s] = tok
+                    self._pos[s] = self._pos[s] + 1
+                    pushes.append((s, req, tok))
+            # serve_gen_decode_seconds: the step's own first dispatch stamp
+            # to its own readback's return
+            self._m_decode_s.observe((t1_ns - t0_ns) * 1e-9)
+            self._m_occupancy.observe(len(step.rows) / self.slots)
+            self._m_tokens.inc(len(pushes))
+            if len(pushes) != len(step.rows):
+                self._m_discarded.inc(len(step.rows) - len(pushes))
+            # the tick's one publish stamp: every pushed token carries
+            # it, and the clock measures the gap from the last one
+            stamp = clock.tick()
+            sheds, self._sheds_at_tick = self._sheds_at_tick, self._sheds
+            if clock.stall is not None:
+                self._record_stall(clock.stall, len(step.rows),
+                                   self._sheds_at_tick - sheds)
+            for _, req, tok in pushes:
+                req._push(tok, stamp)
+            for s, _, _ in pushes:
+                self._maybe_finish(s, step.snap.generation)
+            with self._cond:
+                left = sum(1 for r in self._slot_req if r is not None)
+            clock.decoding(left)
+            self._watch_stacks(stamp if left else None)
+
+    def _settle(self, epoch: int, clock: _trace.PhaseClock,
+                ra: _RunAhead) -> None:
+        """Drain the device's queue: read back and publish the step in
+        flight, read every first token. After it the host's view of every
+        slot is the device's."""
+        step, ra.step = ra.step, None
+        if step is not None:
+            self._retire_step(step, epoch, clock, ra)
+        self._read_firsts(ra, block=True)
 
     def _record_stall(self, stall: dict, active: int, sheds: int) -> None:
         """A stall the clock caught (counted there already) into the flight
@@ -1542,7 +1945,8 @@ class ContinuousBatcher:
         alloc = self._win.alloc
         with clock.span(_trace.GEN_KV_RELEASE):
             for s in active:
-                ring, pos = self._slot_ring[s], int(self._pos[s])
+                ring = self._slot_ring[s]
+                pos = int(self._pos[s]) + int(self._unread[s])
                 self._ring_step(s, ring, pos, pos + 1)
                 wb = pos // self.block_size
                 blk = ring.blocks[wb]
@@ -1562,17 +1966,23 @@ class ContinuousBatcher:
             counter.inc(int(v))
         programs.inc(self._programs.routed)
 
-    def _count_chunks_routing(self) -> None:
-        """The sums of the prefill chunks run since the last call. Called
-        only behind a readback of something the device computed after them
-        (a tick's tokens, a first token), so reading them waits for nothing."""
-        for sums in self._programs.chunk_routing():
-            self._count_routing("prefill", sums)
+    def _count_chunks_routing(self, upto: int) -> None:
+        """The sums of the prefill chunks not counted yet among the first
+        ``upto`` enqueued. Called only behind a readback of something the
+        device computed after them (a step's tokens, a first token), so
+        reading them waits for nothing; a chunk enqueued behind that value
+        keeps its sums on the device until a later call."""
+        n = upto - self._chunks_read
+        if n > 0:
+            self._chunks_read = upto
+            for sums in self._programs.chunk_routing(n):
+                self._count_routing("prefill", sums)
 
-    def _loop(self, epoch: int, clock: _trace.PhaseClock) -> None:
+    def _loop(self, epoch: int, clock: _trace.PhaseClock,
+              ra: _RunAhead) -> None:
         clock.bind()
         try:
-            self._run_loop(epoch, clock)
+            self._run_loop(epoch, clock, ra)
         except BaseException:
             # the decode loop is dying (injected fault, bug): a silent
             # death would hang every queued and in-flight caller — shed
@@ -1590,37 +2000,28 @@ class ContinuousBatcher:
                     req._finish(err)
             raise
         finally:
+            if ra.step is not None:
+                # exiting with a step in flight (staled, shut down, dying):
+                # its bookkeeping is dropped, its lease returned
+                ra.step.release()
             clock.close()
             self._watch_stacks(None)
 
-    def _run_loop(self, epoch: int, clock: _trace.PhaseClock) -> None:
+    def _run_loop(self, epoch: int, clock: _trace.PhaseClock,
+                  ra: _RunAhead) -> None:
+        """One turn a pass. With a step in flight (``ra.step``, enqueued by
+        the turn before and running now) a turn is: admit -> the turn's
+        chunk, behind the running step -> the slack (wait for arrivals, as
+        long as the device allows) -> enqueue the next step -> read the
+        running step back -> publish it. With none it is the same without
+        the waits: there is nothing to run ahead of."""
         while True:
-            with clock.span(_trace.GEN_ADMIT):
-                # registry generation, read OUTSIDE self._cond (the registry
-                # has its own lock): keys prefix-cache adoption, so a publish
-                # flushes stale runs at the next admission
-                cur = self.registry.current()
-                gen = cur.generation if self._prefix is not None else 0
-                # no lease is held here: an idle server, too, lets go of
-                # the copy a publish retired
-                self._params_for(cur)
-                with self._cond:
-                    if self._epoch != epoch:
-                        return  # staled by a crash-only restart
-                    self._hb = time.monotonic()
-                    has_active = any(r is not None for r in self._slot_req)
-                    idle = not self._queue and not has_active \
-                        and not self._jobs
-                    if idle and self._closing:
-                        return
-                    if not idle:
-                        self._admit_locked(gen)
-                        self._m_qdepth.set(len(self._queue))
-                        jobs = list(self._jobs)
-                        decoding = any(r is not None for r in self._slot_req)
-                if not idle:
-                    now = time.perf_counter()
-                    plan = self.scheduler.plan(jobs, decoding)
+            if self._fork_calls:
+                self._settle(epoch, clock, ra)
+            ra.waited = False
+            plan, idle = self._admit(epoch, clock, ra)
+            if plan is None:
+                return
             if idle:
                 # nothing to do: sleep outside the span (waiting for work is
                 # not admission: the clock's gen.wait), after a second look
@@ -1629,38 +2030,122 @@ class ContinuousBatcher:
                 self._watch_stacks(None)
                 with self._cond:
                     if not self._queue and not self._closing \
+                            and not self._fork_calls \
                             and self._epoch == epoch:
                         self._cond.wait(0.05)
                 continue
-            # the chunks and the tick nest in gen.turn; its own time is what
-            # lies between them, up to the loop's back edge: the leases, the
-            # step's device arrays freed as _tick returns (which lends the
-            # interpreter lock to the stream writers the tick just woke)
+            # the chunks, the slack and the tick nest in gen.turn; its own
+            # time is what lies between them, up to the loop's back edge:
+            # the step's device arrays freed as _tick returns (which lends
+            # the interpreter lock to the stream writers the tick just woke)
             with clock.span(_trace.GEN_TURN):
-                for job in plan:
-                    if job.req.cancelled is not None:
-                        # consumer vanished mid-prefill: abort here, where
-                        # no device call holds the job's table row
-                        self._abort_job(job, job.req.cancelled)
-                        continue
-                    if job.idx == 0 and job.req.deadline is not None \
-                            and now > job.req.deadline:
-                        self._abort_job(job, DeadlineExceededError(
-                            "deadline exceeded waiting for a decode slot"))
-                        continue
-                    try:
-                        # one lease per chunk: hot-swap drains at chunk
-                        # granularity, not whole-prompt granularity
-                        with self.registry.lease(tag="gen_prefill") as snap:
-                            self._prefill_step(job, snap, clock)
-                    except ServeError as e:
-                        self._abort_job(job, e)
-                    except Exception as e:  # slot loop must outlive any bad request  # jaxlint: disable=broad-except
-                        self._abort_job(job,
-                                        ServeError(f"{type(e).__name__}: {e}"))
-                with self.registry.lease(tag="gen_decode") as snap:
-                    self._tick(snap, epoch, clock)
+                self._prefill_steps(plan, clock, ra)
+                if ra.step is not None:
+                    self._slack(epoch, clock, ra, admit=not plan)
+                self._tick(epoch, clock, ra)
             clock.turn_end()
+
+    def _admit(self, epoch: int, clock: _trace.PhaseClock, ra: _RunAhead):
+        """The top of a turn (and an arrival inside its slack): forks,
+        admission, the plan of this turn's chunks. Returns ``(plan, idle)``;
+        ``(None, _)`` where the worker is to exit."""
+        with clock.span(_trace.GEN_ADMIT):
+            # registry generation, read OUTSIDE self._cond (the registry
+            # has its own lock): keys prefix-cache adoption, so a publish
+            # flushes stale runs at the next admission
+            cur = self.registry.current()
+            gen = cur.generation if self._prefix is not None else 0
+            if ra.step is None:
+                # no lease is held here: an idle server, too, lets go of
+                # the copy a publish retired
+                self._params_for(cur)
+            with self._cond:
+                if self._epoch != epoch:
+                    return None, False  # staled by a crash-only restart
+                self._hb = time.monotonic()
+                if self._fork_calls and ra.step is None and not ra.firsts:
+                    self._run_forks_locked()
+                has_active = any(r is not None for r in self._slot_req)
+                idle = not self._queue and not has_active \
+                    and not self._jobs and ra.step is None
+                if idle and self._closing:
+                    return None, True
+                if idle:
+                    return [], True
+                self._admit_locked(gen)
+                self._m_qdepth.set(len(self._queue))
+                jobs = list(self._jobs)
+                decoding = any(r is not None for r in self._slot_req)
+            return self.scheduler.plan(jobs, decoding), False
+
+    def _prefill_steps(self, plan: list, clock: _trace.PhaseClock,
+                       ra: _RunAhead) -> None:
+        """This turn's chunks, one a planned job."""
+        now = time.perf_counter()
+        for job in plan:
+            if job.req.cancelled is not None:
+                # consumer vanished mid-prefill: abort here, where
+                # no device call holds the job's table row
+                self._abort_job(job, job.req.cancelled)
+                continue
+            if job.idx == 0 and job.req.deadline is not None \
+                    and now > job.req.deadline:
+                self._abort_job(job, DeadlineExceededError(
+                    "deadline exceeded waiting for a decode slot"))
+                continue
+            try:
+                # one lease per chunk: hot-swap drains at chunk
+                # granularity, not whole-prompt granularity
+                with self.registry.lease(tag="gen_prefill") as snap:
+                    self._prefill_step(job, snap, clock, ra)
+            except ServeError as e:
+                self._abort_job(job, e)
+            except Exception as e:  # slot loop must outlive any bad request  # jaxlint: disable=broad-except
+                self._abort_job(job,
+                                ServeError(f"{type(e).__name__}: {e}"))
+
+    def _slack(self, epoch: int, clock: _trace.PhaseClock, ra: _RunAhead,
+               admit: bool) -> None:
+        """A step runs and the next is not enqueued yet: enqueue it as LATE
+        as the device allows, not as early as the host can. A request that
+        arrives now still gets its chunk in front of the next step (first
+        token after what is left of the running step + the chunk); once the
+        next step is in the device's queue the chunk runs behind it, a
+        whole step later. So the worker waits on ``self._cond``, which
+        ``submit()`` notifies, until :meth:`_RunAhead.deadline`; it admits
+        an arrival and enqueues its chunk at once (``admit``: the turn has
+        not spent its chunks yet; one plan a turn), and reads a first token
+        whose chunk the device runs meanwhile. Where the host's lead is
+        longer than the step the deadline has passed before it is asked:
+        no wait. The time is the wait for the running step, and is booked
+        as such: gen.tick.readback."""
+        with clock.span(_trace.GEN_TICK_READBACK):
+            while True:
+                deadline = ra.deadline()
+                if deadline is None \
+                        or time.perf_counter_ns() >= deadline:
+                    return
+                self._read_firsts(ra, block=True)
+                with self._cond:
+                    if self._epoch != epoch or self._closing \
+                            or self._fork_calls:
+                        return
+                    if not any(self._wants_row_locked(s)
+                               for s in range(self.slots)) \
+                            and not self._jobs and not self._queue:
+                        return      # no next step to hold back
+                    if not (admit and self._queue):
+                        left = deadline - time.perf_counter_ns()
+                        if left > 0:
+                            ra.waited = True
+                            self._cond.wait(left * 1e-9)
+                    arrived = admit and bool(self._queue)
+                if arrived:
+                    admit = False
+                    plan, _ = self._admit(epoch, clock, ra)
+                    if plan is None:
+                        return
+                    self._prefill_steps(plan, clock, ra)
 
     # ------------------------------------------------- watchdog + crash-only
     def heartbeat(self) -> float:
@@ -1699,6 +2184,18 @@ class ContinuousBatcher:
             self._release_ring(s, self._slot_ring[s])
             self._slot_ring[s] = None
         self._tables_np[:] = 0
+        # what was in flight for them is dropped at the epoch check; a fork
+        # waiting for the worker has lost its parent
+        self._unread[:] = 0
+        self._made[:] = 0
+        self._fresh[:] = False
+        self._first_dev = [None] * self.slots
+        self._key_dev = [None] * self.slots
+        calls, self._fork_calls = self._fork_calls, []
+        for call in calls:
+            call.error = ServeError("fork(): the batcher shed its in-flight "
+                                    "generations (restart or shutdown)")
+            call.done.set()
         self._update_kv_gauges()
         self._m_pf_depth.set(0)
         self._m_qdepth.set(len(self._queue))

@@ -26,9 +26,11 @@ program IS, and everything only the programs need to know:
   (:meth:`GenPrograms.signatures`) beside the one call that passes it, so
   that warming a params generation is one call (:meth:`GenPrograms.warm`).
 
-The scheduler hands over host values (a slot vector, a table, a prompt
-chunk) and gets device values back; what it reads back, and when, stays
-its decision.
+The scheduler hands over host values (a table, a prompt chunk, the slot
+vectors of the rows it sets) and gets device values back; what it reads
+back, and when, stays its decision. What one decode step needs of the one
+before it (tokens, positions, keys) is kept here, on the device, so that the
+scheduler can enqueue a step before it has read the last one.
 """
 
 from __future__ import annotations
@@ -150,20 +152,36 @@ class GenPrograms:
                 return last, _as_pools(caches), _routing(caches)
             return last, _as_pools(caches)
 
+        S = self.slots
+
         def _decode_paged_fn(params, state, toks, pools, tables, pos,
-                             keys, temps, tks):
+                             keys, temps, tks, fresh, set_toks, set_pos,
+                             set_keys):
             """One token for every slot, batched over the slot axis
             against the shared pools — ONE executable for the server's
-            lifetime (tables/pos are traced operands). Inactive slots
+            lifetime (tables are a traced operand). Inactive slots
             carry zeroed table rows, so their writes land in the trash
-            block and their sampled garbage is discarded host-side."""
+            block and their sampled garbage is discarded host-side.
+
+            ``toks``, ``pos`` and ``keys`` are what the step before this one
+            returned (its ``next``, its positions + 1, its split keys): they
+            never visit the host. A row the host (re)sets, at an admission
+            or a fork, is marked in ``fresh`` and takes ``set_toks`` /
+            ``set_pos`` / ``set_keys`` instead: integer selects, the
+            arithmetic is the step's own."""
             # a live slot's first block is never the trash block (a ring's
             # first column may be: the full group's table says who is live)
             full = tables[FULL] if grouped else tables
-            live = (full[:, :1] != 0) if routed else None
+            live = full[:, :1] != 0
+            toks = jnp.where(fresh, set_toks, toks[:S])
+            pos = jnp.where(fresh, set_pos, pos)
+            keys = jnp.where(fresh[:, None], set_keys, keys)
+            # a row that is not live reads position 0, as it always did
+            # (its carried position may be anything)
+            pos = jnp.where(live[:, 0], pos, 0)
             lg, caches = decode_forward(
                 mdl, params, state, toks[:, None].astype(jnp.int32),
-                _as_caches(pools, tables, live), pos)
+                _as_caches(pools, tables, live if routed else None), pos)
 
             new_keys, subs = jnp.moveaxis(
                 jax.vmap(jax.random.split)(keys), 1, 0)
@@ -171,7 +189,7 @@ class GenPrograms:
             if routed:
                 # the three sums ride the tokens' readback: (S + 3,)
                 nxt = jnp.concatenate([nxt, _routing(caches)])
-            return nxt, _as_pools(caches), new_keys
+            return nxt, _as_pools(caches), pos + 1, new_keys
 
         self._sample = jax.jit(_sample_dynamic)
         # pools are the loop-carried buffers: donated every step
@@ -180,6 +198,19 @@ class GenPrograms:
 
         self._pools_sig = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.pools)
+        # what a decode step hands the next on the device: its tokens (the
+        # routing sums behind them), positions and keys; then the sampling
+        # vectors, uploaded when a row is set and not between; then the
+        # operands of a step that sets no row, made once
+        self._next_width = S + (len(self.routing_fields) if routed else 0)
+        self._carry = (jnp.zeros((self._next_width,), jnp.int32),
+                       jnp.zeros((S,), jnp.int32),
+                       jnp.zeros((S, 2), jnp.uint32))
+        self._sampling = (jnp.ones((S,), jnp.float32),
+                          jnp.full((S,), self.vocab, jnp.int32))
+        self._none_fresh = (jnp.zeros((S,), bool), jnp.zeros((S,), jnp.int32),
+                            jnp.zeros((S,), jnp.int32),
+                            jnp.zeros((S, 2), jnp.uint32))
         self._aot_fns: Dict[str, Any] = {}
         if store is not None:
             from ..aot import AotFunction, arch_fingerprint
@@ -221,10 +252,13 @@ class GenPrograms:
         return {
             "gen_sample": [(sds((V,), f32), sds((2,), u32), sds((), f32),
                             sds((), i32))],
-            "gen_decode_paged": [(params, state, sds((S,), i32), pools,
+            "gen_decode_paged": [(params, state,
+                                  sds((self._next_width,), i32), pools,
                                   tables(S), sds((S,), i32),
                                   sds((S, 2), u32), sds((S,), f32),
-                                  sds((S,), i32))],
+                                  sds((S,), i32), sds((S,), np.bool_),
+                                  sds((S,), i32), sds((S,), i32),
+                                  sds((S, 2), u32))],
             "gen_prefill_chunk": [(params, state, sds((1, b), i32), pools,
                                    tables(1), sds((1,), i32),
                                    sds((), i32))
@@ -269,19 +303,44 @@ class GenPrograms:
         self._routing_pending += routing
         return last
 
-    def decode(self, params, state, toks, tables, pos, keys, temps, tks):
-        """One token for every slot, from the host's slot vectors. Returns
-        the device values ``(next, keys)``: ``next`` holds the ``slots``
-        tokens (and behind them what :meth:`decode_routing` reads)."""
+    def decode(self, params, state, tables, fresh=None):
+        """One token for every slot whose table row is not zero. Each row's
+        token, position and key are what the step before left on the
+        device, but for the rows the host sets: ``fresh`` is None, or
+        ``(mask, toks, pos, keys, temps, tks, first)`` — the host's slot
+        vectors, read where ``mask`` (S,) is set (``temps`` and ``tks``
+        whole: they replace the sampling vectors), and ``first``, ``{slot:
+        (token, key)}``, the device values an admission left: the first
+        token as the sampler's scalar (None once the host has read it into
+        ``toks``) and the slot's key. Returns the device value ``next``: the
+        ``slots`` tokens (and behind them what :meth:`decode_routing`
+        reads). Nothing is read back here, and a step that sets no row
+        uploads the tables and nothing else."""
         import jax
         import jax.numpy as jnp
 
-        nxt, self.pools, new_keys = self._decode(
-            params, state, jnp.asarray(toks), self.pools,
-            jax.tree.map(jnp.asarray, tables), jnp.asarray(pos),
-            jnp.asarray(keys),
-            jnp.asarray(temps), jnp.asarray(tks))
-        return nxt, new_keys
+        sets = self._none_fresh
+        if fresh is not None:
+            mask, set_toks, set_pos, set_keys, temps, tks, first = fresh
+            set_toks, set_keys = jnp.asarray(set_toks), jnp.asarray(set_keys)
+            for s, (tok0, key) in first.items():
+                # eager indexed updates, as copy_blocks' are: no jit site
+                if tok0 is not None:
+                    set_toks = set_toks.at[s].set(tok0)
+                set_keys = set_keys.at[s].set(key)
+            sets = (jnp.asarray(mask), set_toks, jnp.asarray(set_pos),
+                    set_keys)
+            self._sampling = (jnp.asarray(temps), jnp.asarray(tks))
+        # fixed at boot: the slots, and behind them the routing sums' fields
+        toks, pos, keys = self._carry  # jaxlint: shape=toks:(config)
+        temps, tks = self._sampling
+        mask, set_toks, set_pos, set_keys = sets
+        nxt, self.pools, pos, keys = self._decode(
+            params, state, toks, self.pools,
+            jax.tree.map(jnp.asarray, tables), pos, keys, temps, tks,
+            mask, set_toks, set_pos, set_keys)
+        self._carry = (nxt, pos, keys)
+        return nxt
 
     # ------------------------------------------------------------- routing
     def decode_routing(self, nxt: np.ndarray) -> np.ndarray:
@@ -289,13 +348,13 @@ class GenPrograms:
         ``next`` was read back as ``nxt``; only for a model with experts."""
         return nxt[self.slots:]
 
-    def chunk_routing(self) -> List[np.ndarray]:
-        """The sums of the prefill chunks run since the last call. Call it
+    def chunk_routing(self, n: int) -> List[np.ndarray]:
+        """The sums of the ``n`` oldest prefill chunks not read yet. Call it
         only behind a readback of something the device computed after them
-        (a tick's tokens, a first token), so reading them waits for
+        (a step's tokens, a first token), so reading them waits for
         nothing."""
-        sums = [np.asarray(s) for s in self._routing_pending]
-        self._routing_pending = []
+        sums = [np.asarray(s) for s in self._routing_pending[:n]]
+        del self._routing_pending[:n]
         return sums
 
     # ---------------------------------------------------------------- pools

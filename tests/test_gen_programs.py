@@ -282,7 +282,10 @@ def test_the_first_token_sampler_is_the_sort_formula(temperature, top_k):
 
 def test_signatures_and_tags_are_what_they_were():
     """One sampler, one decode step, one chunk program a bucket. The
-    operand lists are the parent's, written out."""
+    operand lists of the sampler and the chunk are PR 28's, written out; the
+    decode step's is PR 41's: the nine it had (tokens, positions and keys
+    now the step before's, on the device) and behind them the rows the host
+    sets: a mask, and the tokens, positions and keys to take there."""
     cb = _batcher(_causal_lm(), None)
     try:
         progs = cb._programs
@@ -304,7 +307,142 @@ def test_signatures_and_tags_are_what_they_were():
     assert decode[0] is params and decode[3] is progs._pools_sig
     assert shapes(decode) == [
         ((S,), "int32"), ((S, B), "int32"), ((S,), "int32"),
-        ((S, 2), "uint32"), ((S,), "float32"), ((S,), "int32")]
+        ((S, 2), "uint32"), ((S,), "float32"), ((S,), "int32"),
+        ((S,), "bool"), ((S,), "int32"), ((S,), "int32"), ((S, 2), "uint32")]
     assert [shapes(c) for c in sigs["gen_prefill_chunk"]] == [
         [((1, b), "int32"), ((1, B), "int32"), ((1,), "int32"),
          ((), "int32")] for b in (4, 8)]
+
+
+# ------------------------- what a step hands the next, on the device (ISSUE 41)
+def _prefilled(model, prompt, n=1):
+    """A batcher whose worker is gone, ``prompt`` prefilled into slot 0 by
+    hand: ``(cb, params, snap, pages, logits of its last token)``."""
+    from deeplearning4j_tpu.serve.paged import SlotPages
+
+    cb = _batcher(model, None, prefix_cache=False)
+    cb.shutdown()
+    snap = cb.registry.current()
+    params = cb._params_for(snap)
+    pages = SlotPages(cb._alloc, cb.block_size)
+    for off, true_len, bucket in cb._plan_chunks(len(prompt)):
+        pages.ensure(off + true_len)
+        cb._write_table_row(0, pages.blocks)
+        last = cb._programs.prefill_chunk(
+            params, snap.state, prompt[off:off + true_len], bucket,
+            cb._table_rows(0), off)
+    return cb, params, snap, pages, last
+
+
+def _steps(model, prompt, steps, temperature, carried, first_on_device=False):
+    """``steps`` decode steps of slot 0 behind ``prompt``. ``carried``: only
+    the first step sets the row, the rest take what the step before left on
+    the device; else every step sets it from the host, the keys read back
+    in between (the parent's order). Returns tokens and the last keys."""
+    import jax
+
+    cb, params, snap, pages, last = _prefilled(model, prompt)
+    progs, S, V = cb._programs, cb.slots, cb.vocab
+    key, sub = jax.random.split(jax.random.PRNGKey(9))
+    tok0 = progs.sample(last[0], sub, temperature, V)
+    mask = np.zeros(S, bool)
+    mask[0] = True
+    toks, pos = np.zeros(S, np.int32), np.zeros(S, np.int32)
+    keys = np.zeros((S, 2), np.uint32)
+    pos[0], keys[0] = len(prompt), np.asarray(key, np.uint32)
+    temps = np.full(S, temperature, np.float32)
+    tks = np.full(S, V, np.int32)
+    first = {0: (tok0, key)} if first_on_device else {}
+    toks[0] = 0 if first_on_device else int(np.asarray(tok0))
+    out = []
+    for i in range(steps):
+        pages.ensure(len(prompt) + i + 1)
+        cb._write_table_row(0, pages.blocks)
+        tables = np.where(mask[:, None], cb._tables_np, 0)
+        fresh = (mask, toks, pos, keys, temps, tks, first) \
+            if i == 0 or not carried else None
+        nxt = progs.decode(params, snap.state, tables, fresh)
+        if not carried:
+            toks[0] = int(np.asarray(nxt)[0])
+            keys[0] = np.asarray(progs._carry[2])[0]
+            pos[0] += 1
+            first = {}
+        out.append(nxt)         # read only now: nothing forced an order
+    out = [int(np.asarray(o)[0]) for o in out]
+    return [int(np.asarray(tok0))] + out, np.asarray(progs._carry[2])[0], \
+        int(np.asarray(progs._carry[1])[0])
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.9], ids=["greedy", "t0.9"])
+@pytest.mark.parametrize("build", [_causal_lm, _olmoe],
+                         ids=["causal_lm", "olmoe_bf16"])
+def test_a_step_takes_the_step_befores_tokens_positions_and_keys(
+        build, temperature):
+    """Six steps enqueued one behind the other with nothing read in between
+    give the tokens, the keys and the position of six steps that each go
+    through the host."""
+    model = build()
+    prompt = np.random.RandomState(4).randint(1, 50, (7,)).astype(np.int32)
+    want = _steps(model, prompt, 6, temperature, carried=False)
+    got = _steps(model, prompt, 6, temperature, carried=True)
+    assert got[0] == want[0]
+    np.testing.assert_array_equal(got[1], want[1])
+    assert got[2] == want[2] == len(prompt) + 6
+
+
+def test_a_first_token_may_ride_as_the_samplers_device_scalar():
+    model = _causal_lm()
+    prompt = np.random.RandomState(5).randint(1, 50, (5,)).astype(np.int32)
+    want = _steps(model, prompt, 4, 0.9, carried=True)
+    got = _steps(model, prompt, 4, 0.9, carried=True, first_on_device=True)
+    assert got[0] == want[0]
+    np.testing.assert_array_equal(got[1], want[1])
+
+
+def test_a_step_that_sets_no_row_uploads_the_tables_and_nothing_else(
+        monkeypatch):
+    """Between two admissions the host sends the block tables: the slot
+    vectors and the sampling vectors are device values from the step (or
+    the admission) before."""
+    import jax.numpy as jnp
+
+    model = _causal_lm()
+    prompt = np.arange(1, 7, dtype=np.int32)
+    cb, params, snap, pages, last = _prefilled(model, prompt)
+    progs, S = cb._programs, cb.slots
+    mask = np.zeros(S, bool)
+    mask[0] = True
+    zeros = np.zeros(S, np.int32)
+    pos = zeros.copy()
+    pos[0] = len(prompt)
+    pages.ensure(len(prompt) + 2)
+    cb._write_table_row(0, pages.blocks)
+    tables = np.where(mask[:, None], cb._tables_np, 0)
+    uploads = []
+    real = jnp.asarray
+
+    def counting(x, *a, **k):
+        if isinstance(x, np.ndarray):
+            uploads.append(x.shape)
+        return real(x, *a, **k)
+
+    monkeypatch.setattr(jnp, "asarray", counting)
+    progs.decode(params, snap.state, tables,
+                 (mask, zeros, pos, np.zeros((S, 2), np.uint32),
+                  np.ones(S, np.float32), np.full(S, cb.vocab, np.int32), {}))
+    assert len(uploads) == 7        # the tables and the six vectors of a set
+    del uploads[:]
+    nxt = progs.decode(params, snap.state, tables, None)
+    assert uploads == [tables.shape]
+    assert nxt.shape == (S,)
+
+
+def test_chunk_routing_reads_the_oldest_sums_and_leaves_the_rest():
+    model = _olmoe()
+    cb, *_ = _prefilled(model, np.arange(1, 20, dtype=np.int32))
+    progs = cb._programs
+    assert len(progs._routing_pending) == 3     # 8 + 8 + 3 tokens
+    (first,) = progs.chunk_routing(1)
+    assert first.shape == (len(progs.routing_fields),)
+    assert len(progs._routing_pending) == 2
+    assert len(progs.chunk_routing(5)) == 2 and not progs._routing_pending

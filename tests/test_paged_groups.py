@@ -414,8 +414,9 @@ def test_a_fork_shares_the_ring_and_copies_on_write_a_group():
         cb._programs.decode = slow
         prompt = tokens(30, seed=9)
         parent = cb.submit(prompt, 40, temperature=0.0)
-        while len(parent.out) < 5:
+        while len(parent.out) < 4:
             time.sleep(0.005)
+        # the worker makes the copy between two turns, a step or two on
         child = cb.fork(parent)
         at = len(parent.out)
         got_parent, got_child = parent.wait(), child.wait()
@@ -426,7 +427,12 @@ def test_a_fork_shares_the_ring_and_copies_on_write_a_group():
         assert n >= 40 - at - 1
         np.testing.assert_array_equal(got_child, want[40 - n:][:n]
                                       if n <= 40 else want)
-        assert counter(cb, "serve_kv_cow_copies_total") >= 2   # one a group
+        # the fork's position, from the child's default budget; where it
+        # falls on a block's edge only whole blocks are shared and nothing
+        # is ever copied
+        at_pos = 30 + (40 - n) - 1
+        assert counter(cb, "serve_kv_cow_copies_total") \
+            >= (2 if at_pos % BS else 0)                  # one a group
         cb.flush_prefix_cache()
         assert cb._win.alloc.used == 0 and cb._alloc.used == 0
         assert cb._win.committed == 0

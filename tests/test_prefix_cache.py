@@ -567,12 +567,15 @@ class TestAnswerStaysCached:
         def cached():
             return set(cb._prefix._runs.values())
 
-        def decode(params, state, toks, tables, pos, *rest):
+        def decode(params, state, tables, *rest):
             hold = cached()
+            # the position a row of this step writes: the slot's published
+            # position and the rows it has in flight, this one not counted
+            pos = cb._pos + cb._unread - 1
             for s in range(tables.shape[0]):
                 if tables[s, 0] and tables[s, pos[s] // bs] in hold:
                     bad.append(("decode", s, int(pos[s])))
-            return real_decode(params, state, toks, tables, pos, *rest)
+            return real_decode(params, state, tables, *rest)
 
         def chunk(params, state, tokens, bucket, table_row, off):
             hold = cached()

@@ -13,6 +13,12 @@ and the routing sums a held share; the three pins above it stand as they
 were, GLM's programs are pinned from PR 33's parent, and Laguna's from the
 commit that brought them.
 
+PR 41 re-took the four ``gen_decode_paged`` pins, and only those: the decode
+step now takes its tokens, positions and keys from the step before it, on the
+device, and selects (integer ``where`` on a per-slot mask) the rows the host
+sets; it returns positions + 1 beside the keys. The sampler's and the
+chunk's texts are the parent's to the byte (their hashes stand as taken).
+
 A change that is MEANT to alter these programs re-takes the hashes (run this
 file with ``-s`` and copy what it prints) and says so in ``PERF.md``.
 """
@@ -31,25 +37,25 @@ from deeplearning4j_tpu.serve.continuous import ContinuousBatcher
 PARENT = {
     "dense": {
         "gen_sample": "5735026fd23a417e6ff6cd8d38548127448acbbb804e224ea67331c92424dc92",
-        "gen_decode_paged": "50f7f66e676efdbc75296609cf6e37e92ace6a6d349696621a1b1cebcc2b4244",
+        "gen_decode_paged": "5724b0e0c459dea1abff707eefbec2ec22e55370a141c770270f5ad9eb47a7a0",
         "gen_prefill_chunk": "a92cab8705c9d83886433ac489b4f39741c788bf32c9a104358879481d95d7be",
     },
     "olmoe": {
         "gen_sample": "5735026fd23a417e6ff6cd8d38548127448acbbb804e224ea67331c92424dc92",
-        "gen_decode_paged": "2a57acd7ac55ca28dab5b7a43b6f98e5ce8e31d9f783f620767f918148deeb2f",
+        "gen_decode_paged": "b9df90ca43a57ce52967e9ffe70a62398d0c2f13ebadc9ba2b3a4c6121723f91",
         "gen_prefill_chunk": "1024b3e649c2900012e645a75ebc0fb4f18852f8583051d01eb38f6c58b1d026",
     },
     # taken at 531c641 (PR 33's parent), when PR 33 touched the cache
     # contract and the experts' routing sums it shares
     "glm": {
         "gen_sample": "5735026fd23a417e6ff6cd8d38548127448acbbb804e224ea67331c92424dc92",
-        "gen_decode_paged": "9238b8af6a7f01a783e38ea31850da433f5fbaec80128fca0f9b82ec4641e03b",
+        "gen_decode_paged": "07134ea7a66eb2bd407e63be7469bd6e1c2d722af04313e7aef273d8902a9ef1",
         "gen_prefill_chunk": "9d64b447c7c2161e6eb1d371ed37589bae546c1341d3c7e88e490b9f685c4c85",
     },
     # taken at PR 33, the commit that brought the model
     "laguna": {
         "gen_sample": "5735026fd23a417e6ff6cd8d38548127448acbbb804e224ea67331c92424dc92",
-        "gen_decode_paged": "cb2665f5f59f495df376ca87f9fec90671b65871b6800948ed3c06b4586071c9",
+        "gen_decode_paged": "39be426a048868f10f9cd650ce247c478912c7fb3cd4d2ee8ab247c7340a3e1c",
         "gen_prefill_chunk": "6647156ad2ac696815543b0566d60f6b244e5dddf5017cce5888993901fbefe2",
     },
 }
