@@ -130,6 +130,15 @@ def log(msg: str) -> None:
     print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
 
+def log_compared(failed_checks: list, compared: dict) -> None:
+    """A run's last lines on standard error: which checks failed, and each
+    number compared beside its limit (the result's line carries the same
+    under ``compared``, its last key)."""
+    for name, (value, limit) in compared.items():
+        log(f"compared {name} = {value:.6g} limit {limit:.6g}")
+    log(f"checks failed: {failed_checks or 'none'}")
+
+
 def trace_window(trace_dir: str, delay_s: float, length_s: float):
     """Profile ``length_s`` seconds starting ``delay_s`` from now, on a
     thread of its own; join the thread to wait for the trace file.

@@ -25,6 +25,8 @@ The declarative sources (``"source": {"type": ...}``):
 - ``span_quantile``: quantile ``q`` of the durations of the program's spans
   called ``name`` (``StepTelemetry``'s tracer), the first ``skip`` left out,
   times ``scale`` (durations are microseconds).
+- ``same_as``: what the per-layer metric ``metric`` reads: one reading
+  under a second name, where another ``moves`` or list of cells wants it.
 
 A term is ``{"counter": name, "field": "value"|"sum"|"count",
 "at": "window"|"boot"|"end", "labels": {...}, "missing": x}`` (a program counter, gauge or
@@ -195,6 +197,9 @@ def read_declared(run: Run, source: dict) -> Optional[float]:
         if not durs:
             return None
         return scale * float(np.quantile(durs, float(source["q"])))
+    if kind == "same_as":
+        v = read(run, source["metric"])
+        return None if v is None else scale * v
     raise ValueError(f"unknown layer-metric source type {kind!r}")
 
 

@@ -17,6 +17,12 @@ is read to its end (at most ``DRAIN_S``). Closed loop (``serve_closed``): each
 session sends its next turn when the previous one ends, timed from when it
 was sent; at the window's end the clients hang up, and the turns in flight
 count for the tokens they delivered inside the window.
+
+Which answers are compared with the reference is the SCHEDULE's choice
+(``trafficgen.checked_seqs``), the same requests in every run of a cell. A
+checked turn still in flight at the window's end is read to its end (at most
+``DRAIN_S``): an answer that comes late is late, not wrong. One that never
+came, or was never sent, is reported as missing, never replaced by another.
 """
 
 from __future__ import annotations
@@ -33,15 +39,17 @@ from typing import List, Optional
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import trafficgen  # noqa: E402  (stdlib only, like this file)
 
-DRAIN_S = 30.0
+DRAIN_S = 60.0
 
 
 class Stream:
     """One ``/generate`` call: status, token times, outcome."""
 
-    def __init__(self, port: int, body: dict, ref_t: Optional[float] = None):
+    def __init__(self, port: int, body: dict, ref_t: Optional[float] = None,
+                 seq: int = -1, checked: bool = False):
         self.port, self.body = port, body
         self.ref_t = ref_t          # due time (open loop) or None: send time
+        self.seq, self.checked = seq, checked   # the plan's number; compared?
         self.sent_t = 0.0
         self.status = 0
         self.token_t: List[float] = []
@@ -140,7 +148,7 @@ def setup_traffic(args, spec) -> dict:
             "context_tokens": ctx_tokens, "setup_failures": bad}
 
 
-def open_loop(args, spec):
+def open_loop(args, spec, checked):
     plan = trafficgen.open_loop_plan(spec, args.seed, args.seconds)
     bodies = [r.body(trafficgen.tokens(r.seed, r.fresh_len, args.vocab))
               for r in plan]
@@ -152,7 +160,8 @@ def open_loop(args, spec):
         delay = due - time.monotonic()
         if delay > 0:
             time.sleep(delay)
-        s = Stream(args.port, body, ref_t=due)
+        s = Stream(args.port, body, ref_t=due, seq=r.seq,
+                   checked=r.seq in checked)
         t = threading.Thread(target=s.run, daemon=True)
         t.start()
         streams.append(s)
@@ -174,7 +183,7 @@ def open_loop(args, spec):
     return streams, t0, t1
 
 
-def closed_loop(args, spec, sessions):
+def closed_loop(args, spec, sessions, checked):
     streams: List[Stream] = []
     lock = threading.Lock()
     stop = threading.Event()
@@ -191,10 +200,12 @@ def closed_loop(args, spec, sessions):
             if stop.is_set():
                 return
             fresh = trafficgen.tokens(r.seed, r.fresh_len, args.vocab)
-            if len(history) + len(fresh) + r.max_new_tokens > args.capacity:
+            if trafficgen.starts_over(len(history), len(fresh),
+                                      r.max_new_tokens, args.capacity):
                 history = list(base)   # the session starts over on its context
             prompt = history + fresh
-            s = Stream(args.port, r.body(prompt))
+            s = Stream(args.port, r.body(prompt), seq=r.seq,
+                       checked=r.seq in checked)
             with lock:
                 streams.append(s)
                 live[ses.index] = s
@@ -215,11 +226,18 @@ def closed_loop(args, spec, sessions):
     stop.set()
     time.sleep(0.2)   # the parent reads its counters before anyone hangs up
     with lock:
-        cut = [s for s in live.values() if not s.done and s.error is None]
+        flying = [s for s in live.values() if not s.done and s.error is None]
+    cut = [s for s in flying if not s.checked]
     for s in cut:
         s.hang_up()
+    # a checked answer in flight is read to its end: late is not wrong
+    deadline = time.monotonic() + (DRAIN_S if len(cut) < len(flying) else 10)
     for t in threads:
-        t.join(10)
+        t.join(max(0.0, deadline - time.monotonic()))
+    for s in flying:
+        if s.checked and not s.done and s.error is None:
+            s.error = "undrained"
+            s.hang_up()
     for s in cut:
         s.cut = True
     return streams, t0, t1
@@ -246,8 +264,10 @@ def tokens_by_second(streams: List[Stream], t0: float, t1: float) -> List[int]:
     return counts
 
 
-def reduce(streams: List[Stream], t0: float, t1: float, kind: str) -> dict:
-    """Everything the parent needs, from the client's side of the wire."""
+def reduce(streams: List[Stream], t0: float, t1: float, kind: str,
+           checked: List[int] = ()) -> dict:
+    """Everything the parent needs, from the client's side of the wire.
+    ``checked`` are the plan's numbers of the requests to compare."""
     in_window = [s for s in streams if t0 <= s.ref_t < t1]
     ttft = [(s.token_t[0] - s.ref_t) * 1e3 for s in in_window if s.token_t]
     timed_gaps = [(b - t0, (b - a) * 1e3) for s in streams
@@ -265,12 +285,10 @@ def reduce(streams: List[Stream], t0: float, t1: float, kind: str) -> dict:
     cut = [s for s in streams if getattr(s, "cut", False)]
     failed = [s for s in streams if not s.ok and s not in cut]
     shed = [s for s in streams if s.status in (429, 503)]
-    checkable = sorted((s for s in streams if s.ok
-                        and float(s.body.get("temperature", 1.0)) == 0.0),
-                       key=lambda s: len(s.body["prompt"]))
-    picked = []
-    if checkable:
-        picked = [checkable[0], checkable[-1], checkable[len(checkable) // 2]]
+    answered = {s.seq: s for s in streams if s.checked and s.ok}
+    sent = {s.seq: s for s in streams if s.checked}
+    missing = [[q, (sent[q].error or "short") if q in sent else "not sent"]
+               for q in checked if q not in answered]
     return {
         "kind": kind, "window_s": t1 - t0,
         "attempted": len(streams), "failed": len(failed), "cut": len(cut),
@@ -291,8 +309,12 @@ def reduce(streams: List[Stream], t0: float, t1: float, kind: str) -> dict:
         # 50 ms of each other: whether every stream stood still at once
         "longest_stalls": stalls,
         "late_p99_ms": quantile(late, 0.99), "late_max_ms": max(late, default=0.0),
-        "checked": [{"prompt": s.body["prompt"], "tokens": s.tokens}
-                    for s in picked],
+        # exactly the requests the schedule marked; one that did not
+        # complete is named in `checked_missing`, never replaced
+        "checked": [{"seq": q, "prompt": answered[q].body["prompt"],
+                     "tokens": answered[q].tokens}
+                    for q in checked if q in answered],
+        "checked_planned": len(checked), "checked_missing": missing,
     }
 
 
@@ -314,11 +336,14 @@ def main(argv=None) -> int:
         setup_failures=prep["setup_failures"])
     if sys.stdin.readline().strip() != "go":
         return 3
+    checked = trafficgen.checked_seqs(spec, args.seed, args.seconds,
+                                      args.capacity)
     if spec["kind"] == "serve_open":
-        streams, t0, t1 = open_loop(args, spec)
+        streams, t0, t1 = open_loop(args, spec, set(checked))
     else:
-        streams, t0, t1 = closed_loop(args, spec, prep["sessions"])
-    out = reduce(streams, t0, t1, spec["kind"])
+        streams, t0, t1 = closed_loop(args, spec, prep["sessions"],
+                                      set(checked))
+    out = reduce(streams, t0, t1, spec["kind"], checked)
     out["context_tokens"] = prep["context_tokens"]
     out["setup_failures"] = prep["setup_failures"]
     with open(args.out, "w") as f:

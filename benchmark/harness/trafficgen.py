@@ -211,6 +211,59 @@ def session_plan(spec: dict, seed: int, turns: int = 48) -> List[Session]:
     return out
 
 
+def starts_over(history_len: int, fresh_len: int, max_new_tokens: int,
+                capacity: int) -> bool:
+    """A session's turn starts over on its context when history, fresh
+    prompt and answer would pass the slot's capacity."""
+    return history_len + fresh_len + max_new_tokens > capacity
+
+
+def _spread(cands: list, count: int) -> list:
+    """``count`` of the ordered ``cands``, evenly over their order, the first
+    and the last among them."""
+    if count >= len(cands):
+        return list(cands)
+    if count <= 1:
+        return cands[-1:]
+    return [cands[round(i * (len(cands) - 1) / (count - 1))]
+            for i in range(count)]
+
+
+def checked_seqs(spec: dict, seed: int, seconds: float,
+                 capacity: Optional[int] = None) -> List[int]:
+    """The ``seq`` of the requests whose answers are compared with the
+    reference: marked by the SCHEDULE (``schedule_seed`` where the file fixes
+    one: lengths and the sampling mix), never by what a run happened to
+    finish. ``spec["checked"]``: ``count`` requests (default 8), greedy ones,
+    spread evenly over the prompt lengths the cell sends, the shortest and
+    the longest among them. Closed loop: turns sent after at most
+    ``after_tokens`` streamed tokens of their session (default 512), so that
+    even a slow server is sent them inside the window; the client reads a
+    checked answer to its end past the window's close. Open loop: any
+    arrival of the window (every stream is drained)."""
+    want = spec.get("checked", {})
+    count = int(want.get("count", 8))
+    cands = []                                   # (prompt length, seq)
+    if spec["kind"] == "serve_open":
+        cands = [(r.fresh_len, r.seq) for r in open_loop_plan(spec, seed, seconds)
+                 if r.temperature == 0.0]
+    elif spec["kind"] == "serve_closed":
+        capacity = int(capacity or spec["server"]["gen_capacity"])
+        after = int(want.get("after_tokens", 512))
+        for ses in session_plan(spec, seed):
+            history, streamed = ses.context_len, 0
+            for r in ses.turns:
+                if streamed > after:
+                    break
+                if starts_over(history, r.fresh_len, r.max_new_tokens, capacity):
+                    history = ses.context_len
+                if r.temperature == 0.0:
+                    cands.append((history + r.fresh_len, r.seq))
+                history += r.fresh_len + r.max_new_tokens
+                streamed += r.max_new_tokens
+    return sorted(seq for _, seq in _spread(sorted(cands), count))
+
+
 def plan_bytes(spec: dict, seed: int, seconds: float) -> bytes:
     """The plan in a fixed line format: equal bytes mean equal traffic."""
     lines = [f"# {SCHEMA} kind={spec['kind']} seed={seed} seconds={seconds:g}"]

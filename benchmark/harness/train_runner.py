@@ -139,6 +139,13 @@ def run(cell, args, t_start: float, watch: env.CompileWatch, dirs: dict) -> dict
         "loss_fell": bool(tail and float(np.mean(tail)) < losses[0]),
         "no_compile_in_window": builds_in_window == 0,
     }
+    compared = {
+        "first_loss_rel_gap": [abs(losses[0] - ref_loss) / abs(ref_loss),
+                               LOSS_RTOL],
+        "compiles_in_window": [builds_in_window, 0],
+        "last_losses_mean_less_first": [
+            (float(np.mean(tail)) if tail else float("nan")) - losses[0], 0.0],
+    }
     env.log(f"checks {checks}; first loss {losses[0]:.5f} reference {ref_loss:.5f} "
         f"last {tail[-1:]}; {steps} steps in {elapsed:.2f}s")
     result = {"train_tokens_per_s": steps * tokens_per_step / elapsed,
@@ -151,7 +158,8 @@ def run(cell, args, t_start: float, watch: env.CompileWatch, dirs: dict) -> dict
            "checks": checks,
            "losses": {"first": losses[0], "reference": ref_loss,
                       "last": tail[-1] if tail else None},
-           "setup": {"xla_cache_misses": misses_setup}}
+           "setup": {"xla_cache_misses": misses_setup},
+           "compared": compared}
     if not args.trace:
         out["metrics"] = {
             m["name"]: {"value": float(result[m["name"]]), "unit": m["unit"]}

@@ -86,6 +86,11 @@ import jax.numpy as jnp
 import numpy as np
 
 Q_BLOCK = 512
+# Since PR 44 the rule "a tie is not judged" is the harness's
+# (``harness/agreement.py``) and the margin the configuration file's
+# (``agreement.tie_margin``, the same number: benchmark/tests holds the two
+# equal). This constant and ``greedy_gaps`` below stay for tier-1's tests of
+# this file (``tests/``); no run of the benchmark calls them.
 # in units of the selection score (a probability plus the bias); see the
 # note on ties above and PERF.md section 6 (PR 31) for the two readings
 ROUTING_TIE = 2e-4

@@ -102,8 +102,18 @@ def hidden(params, ids, cfg: dict):
     return x
 
 
-def logits(params, h):
-    """Logits (t, vocab) of hidden states (t, d)."""
+def hidden_and_margin(params, ids, cfg: dict):
+    """``hidden`` in the shape the harness asks every reference for
+    (``harness/agreement.py``): the hidden states, and each position's
+    routing margin, which a model without a router has none of (infinite:
+    no position is ever a tie)."""
+    h = hidden(params, ids, cfg)
+    return h, jnp.full(h.shape[:1], jnp.inf)
+
+
+def logits(params, h, cfg: dict = None):
+    """Logits (t, vocab) of hidden states (t, d); ``cfg`` is not needed and
+    is taken so that every reference answers the same call."""
     _, _, _, ln, head = _layers(params)
     return _head(params[ln], params[head], h)
 

@@ -80,6 +80,11 @@ import jax.numpy as jnp
 import numpy as np
 
 Q_BLOCK = 256
+# Since PR 44 the rule "a tie is not judged" is the harness's
+# (``harness/agreement.py``) and the margin the configuration file's
+# (``agreement.tie_margin``, the same number: benchmark/tests holds the two
+# equal). This constant and ``greedy_gaps`` below stay for tier-1's tests of
+# this file (``tests/``); no run of the benchmark calls them.
 # in units of the router's score (a softmax probability over 256 experts is
 # of order 0.004-0.03; the 10th-against-11th margin has a median of 6.6e-5
 # over 8 expert layers). The two readings (PERF.md section 6, PR 33; v5e,

@@ -65,7 +65,8 @@ def _rope(x, theta):
                                              "eps", "theta"))
 def block(p, x, *, n_head: int, kv_heads: int, top_k: int, eps: float,
           theta: float):
-    """One block on x: (T, d) float32."""
+    """One block on x: (T, d) float32. Returns the block's output and each
+    position's routing margin: the k-th probability minus the (k+1)-th."""
     with jax.default_matmul_precision("highest"):
         T, d = x.shape
         hd = d // n_head
@@ -97,7 +98,12 @@ def block(p, x, *, n_head: int, kv_heads: int, top_k: int, eps: float,
 
         h = _rms(x, p["ln2_g"], eps)
         prob = jax.nn.softmax(h @ _f32(moe["w_router"]), axis=-1)   # (T, E)
-        _, chosen = jax.lax.top_k(prob, top_k)                      # (T, k)
+        # one more than chosen: the runner-up's score gives the margin
+        # (none where every expert is chosen)
+        best, chosen = jax.lax.top_k(prob, min(top_k + 1, prob.shape[-1]))
+        margin = best[:, top_k - 1] - best[:, top_k] \
+            if top_k < prob.shape[-1] else jnp.full((T,), jnp.inf)
+        chosen = chosen[:, :top_k]
         mask = jnp.zeros_like(prob).at[jnp.arange(T)[:, None], chosen].set(1.0)
         weight = prob * mask            # p_e for the k largest, 0 elsewhere
 
@@ -109,7 +115,7 @@ def block(p, x, *, n_head: int, kv_heads: int, top_k: int, eps: float,
         m, _ = jax.lax.scan(expert, jnp.zeros_like(x),
                             (moe["w_gate"], moe["w_up"], moe["w_down"],
                              weight.T))
-        return x + m
+        return x + m, margin
 
 
 @jax.jit
@@ -130,16 +136,28 @@ def _layers(params):
 
 def hidden(params, ids, cfg: dict):
     """Final hidden states (T, d) of one sequence of token ids (T,)."""
+    return hidden_and_margin(params, ids, cfg)[0]
+
+
+def hidden_and_margin(params, ids, cfg: dict):
+    """Final hidden states (T, d) of one sequence of token ids (T,), and
+    each position's smallest routing margin over the layers (T,): where it is
+    smaller than two computations of the scores can agree, which expert runs
+    is undefined to rounding, and the harness does not judge the position
+    (``harness/agreement.py``; the margin that counts as a tie is the
+    configuration file's, set from readings)."""
     emb, blocks, _, _ = _layers(params)
     heads = int(cfg["num_attention_heads"])
     x = _embed(params[emb], jnp.asarray(ids, jnp.int32))
+    margin = jnp.full(x.shape[:1], jnp.inf)
     for k in blocks:
-        x = block(params[k], x, n_head=heads,
-                  kv_heads=int(cfg.get("num_key_value_heads", heads)),
-                  top_k=int(cfg["num_experts_per_tok"]),
-                  eps=float(cfg["rms_norm_eps"]),
-                  theta=float(cfg["rope_theta"]))
-    return x
+        x, m = block(params[k], x, n_head=heads,
+                     kv_heads=int(cfg.get("num_key_value_heads", heads)),
+                     top_k=int(cfg["num_experts_per_tok"]),
+                     eps=float(cfg["rms_norm_eps"]),
+                     theta=float(cfg["rope_theta"]))
+        margin = jnp.minimum(margin, m)
+    return x, margin
 
 
 def logits(params, h, cfg: dict):

@@ -8,8 +8,10 @@ file (``benchmark/traffic/``); the traffic file's ``kind`` picks the runner
 (``serve_open``, ``serve_closed``, ``train``). The last line of stdout is one
 JSON object: ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
 end-to-end metrics with ``--trace 0``, its per-layer metrics with
-``--trace 1``), ``device`` and, traced, ``breakdown``. Everything else goes
-to stderr.
+``--trace 1``), ``device`` and, traced, ``breakdown``; then ``checks``,
+``failed_checks`` (which of them failed, the agreement gate's numbers by name)
+and, last, ``compared``: each number a check compared, beside its limit.
+Everything else goes to stderr, whose last lines say the same.
 
 No TPU, no number: a cell of the repository's manifest exits 2 on any other
 backend, and with fewer chips than it asks for. ``--manifest`` points at
@@ -76,6 +78,12 @@ def main(argv=None) -> int:
 
     runner = importlib.import_module("harness." + RUNNERS[kind])
     out = runner.run(cell, args, T_START, env.CompileWatch(), dirs)
+    # which checks failed, and each number compared beside its limit: the
+    # last lines of stderr and (`compared`) the last key of the result
+    out.setdefault("failed_checks",
+                   sorted(k for k, v in out["checks"].items() if not v))
+    out["compared"] = out.pop("compared", {})
+    env.log_compared(out["failed_checks"], out["compared"])
     print(json.dumps(out), flush=True)
     return 0
 

@@ -127,6 +127,22 @@ def test_python_reader_train_mfu(run):
         lm.read(run, "no_such_metric")
 
 
+@pytest.mark.parametrize("name", ["ttft_p90_ms", "prefix_saved_share",
+                                  "queue_wait_mean_ms", "server_ttft_mean_ms"])
+def test_a_reading_under_a_second_name_is_the_first_ones(run, name):
+    """``session_<name>`` keeps no copy of the source: it reads ``<name>``."""
+    with open(os.path.join(env.BENCH_DIR, "layer_metrics",
+                           f"session_{name}.json")) as f:
+        assert json.load(f)["source"] == {"type": "same_as", "metric": name}
+    run.client["ttft_p90_ms"] = 281.0
+    run.counters_end["serve_prefill_tokens_saved_total"] = counter(640)
+    for fam in ("serve_gen_queue_seconds", "serve_gen_first_token_seconds"):
+        run.counters_end[fam] = hist([[0.05, 4], ["+Inf", 0]], 0.1, 0.04)
+    assert lm.read(run, f"session_{name}") == lm.read(run, name) is not None
+    assert lm.read_declared(run, {"type": "same_as", "metric": "turn_host_ms"}) \
+        is None                         # nothing to read: nothing, not 0
+
+
 def test_every_metric_of_the_manifest_has_a_reader_and_agrees_with_it():
     manifest = env.load_json(env.MANIFEST)
     e2e = {m["name"] for m in manifest["end_to_end"]}

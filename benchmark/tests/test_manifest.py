@@ -52,23 +52,43 @@ def test_cells_and_chips():
     assert used == {c["name"] for c in M["configs"]}
 
 
-def test_configs_keep_their_widths():
-    for c in M["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert c["file"].startswith("benchmark/") and c["source"].startswith("https://")
-        cfg = env.load_json(os.path.join(env.ROOT, c["file"]))
-        assert cfg["source"] == c["source"]
-        assert c["reduced"] == []                  # full depth, full widths
+WIDTH = re.compile(r"(_dim|_rank|_size)$|^(n_embd|n_inner|n_head|head_dim|"
+                   r"num_attention_heads|num_key_value_heads|"
+                   r"num_experts_per_tok)$")
+
+
+@pytest.mark.parametrize("c", M["configs"], ids=lambda c: c["name"])
+def test_configs_keep_their_widths(c):
+    """Each configuration against ITS OWN file: the source, the reference,
+    what was reduced (never a width, each key with the published value
+    beside it) and the program's build arguments against the file's sizes."""
+    assert set(c) == {"name", "source", "file", "reduced", "why"}
+    assert c["file"].startswith("benchmark/") and c["source"].startswith("https://")
+    cfg = env.load_json(os.path.join(env.ROOT, c["file"]))
+    assert cfg["source"] == c["source"] and cfg["name"] == c["name"]
+    assert os.path.exists(os.path.join(env.BENCH_DIR, "references",
+                                       cfg["reference"] + ".py"))
+    for key in c["reduced"]:
+        assert not WIDTH.search(key) and key != "vocab_size", key
+        assert key in cfg
+        said = {**cfg.get("published", {}), **cfg.get("cut", {})}
+        assert key in said, f"{key}: the published value is not beside it"
+    k = cfg["build"]["kwargs"]
+    if cfg["reference"] == "gpt2_block":          # full depth, full widths
+        assert c["reduced"] == []
         assert (cfg["n_layer"], cfg["n_embd"], cfg["n_head"], cfg["n_inner"]) \
             == (24, 2048, 16, 8192)
-        k = cfg["build"]["kwargs"]
         assert (k["num_layers"], k["d_model"], k["num_heads"], k["vocab"],
                 k["input_shape"][0]) == (cfg["n_layer"], cfg["n_embd"],
                                          cfg["n_head"], cfg["vocab_size"],
                                          cfg["n_positions"])
         assert (k.get("num_kv_heads") == 1) == bool(cfg["multi_query"])
-        assert os.path.exists(os.path.join(env.BENCH_DIR, "references",
-                                           cfg["reference"] + ".py"))
+    else:                                         # cut in depth, never in width
+        assert "num_hidden_layers" in c["reduced"]
+        assert (k["num_layers"], k["d_model"], k["vocab"]) == (
+            cfg["num_hidden_layers"], cfg["hidden_size"], cfg["vocab_size"])
+        assert k["top_k"] == cfg["num_experts_per_tok"]
+        assert k["dtype"] == "bfloat16"           # held once, as published
 
 
 def test_metrics_follow_the_contract():

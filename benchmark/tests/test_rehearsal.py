@@ -34,16 +34,22 @@ def last_line(proc):
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(out)
     assert out["device"]["platform"] == "cpu"      # it says where it ran
-    # The rehearsal checks the harness. Every check must pass but one: the
-    # program has an open fault (PERF.md section 7) that now and then serves
-    # a run of token 0 under this rehearsal's concurrent sessions, and the
-    # agreement check then says so, as it should. The chip cells hold the
-    # program to it; here it is reported, and only its power is asserted.
-    failed = sorted(k for k, v in out["checks"].items() if not v)
-    assert failed in ([], ["agreement"]), (out["checks"], proc.stderr[-2000:])
-    assert out["correct"] is (not failed)
-    if failed:
-        print("agreement failed in the rehearsal:", out["agreement"])
+    # Every check, the agreement gate among them: the rehearsal's
+    # configurations carry bounds set by the gate's own rule from CPU
+    # readings (``data/configs/*.json``, group ``agreement``), every answer
+    # the schedule marked came, and the line says so in its own words.
+    assert out["failed_checks"] == [], (out["checks"], out.get("agreement"),
+                                       proc.stderr[-2000:])
+    assert out["checks"] == {k: True for k in out["checks"]}
+    assert out["correct"] is True
+    assert list(out)[-1] == "compared"      # each number beside its limit
+    assert all(len(pair) == 2 for pair in out["compared"].values())
+    if "agreement" in out:                  # a serving cell
+        agree = out["agreement"]
+        assert out["checks"]["checked_requests"] and agree["sequences"] >= 2
+        assert agree["control_flip_share"] >= agree["control_flip_share_min_limit"]
+        for name in ("big_gap_share", "max_gap_rel", "left_out_share"):
+            assert agree[name] <= agree[name + "_limit"]
     assert out["failed"] == 0 and out["attempted"] > 0
     return out
 
@@ -58,7 +64,7 @@ def test_serving_runners(workload):
     assert all(m["value"] > 0 for m in out["metrics"].values())
     assert out["metrics"]["itl_p50_ms"]["unit"] == "ms"
     assert out["checks"]["no_compile_in_window"]
-    assert out["agreement"]["swapped_mean_gap_rel"] > 0.1   # it can fail
+    assert out["agreement"]["control_flip_share"] >= 0.5   # it can fail
 
 
 def test_traced_serving_run_reports_per_layer_metrics_and_warm_setup():

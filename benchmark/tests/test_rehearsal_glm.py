@@ -27,7 +27,7 @@ def test_untraced_run_judges_the_serving_metrics():
     # every check of the rehearsal; the tiny model's agreement too: the block
     # computes in f32 against the bf16 values the reference is fed
     assert out["checks"] == {k: True for k in out["checks"]}, out["agreement"]
-    assert out["agreement"]["swapped_mean_gap_rel"] > 0.1   # it can fail
+    assert out["agreement"]["control_flip_share"] >= 0.5   # it can fail
 
 
 def test_traced_run_reports_the_cache_and_what_routing_did():

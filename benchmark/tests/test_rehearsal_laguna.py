@@ -29,7 +29,7 @@ def test_untraced_run_judges_the_serving_metrics():
     assert set(out["metrics"]) == {"itl_p50_ms", "ttft_p50_ms", "setup_s"}
     assert out["failed"] == 0
     assert out["checks"] == {k: True for k in out["checks"]}, out["agreement"]
-    assert out["agreement"]["swapped_mean_gap_rel"] > 0.1   # it can fail
+    assert out["agreement"]["control_flip_share"] >= 0.5   # it can fail
 
 
 def test_traced_run_reports_both_groups_and_the_held_experts():

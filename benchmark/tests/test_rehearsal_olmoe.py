@@ -20,7 +20,7 @@ def test_untraced_run_judges_the_serving_metrics():
     out = last_line(run_cell(CELL, trace=0, manifest=MANIFEST))
     assert set(out["metrics"]) == {"itl_p50_ms", "ttft_p50_ms", "setup_s"}
     assert out["checks"]["no_compile_in_window"]
-    assert out["agreement"]["swapped_mean_gap_rel"] > 0.1   # it can fail
+    assert out["agreement"]["control_flip_share"] >= 0.5   # it can fail
 
 
 def test_traced_run_reports_what_routing_did():

@@ -37,7 +37,10 @@ def test_a_traced_serving_run_prints_the_clocks_metrics(workload):
         assert all(m[n] == 0 for n in STALLS)
     else:
         assert m["stall_max_ms"] > 100 and m["stall_share"] > 0
-    # dispatch + readback (and what of gen.tick lies between) is what
-    # serve_gen_decode_seconds times
+    # serve_gen_decode_seconds times ONE step from its own dispatch to the
+    # return of its own readback; since the loop runs a step ahead (PR 41)
+    # the next step's enqueue lies in between, so it reads over a step's own
+    # dispatch and wait and up to two of the tick's periods (a chunk beside)
+    period = m["turn_host_ms"] + m["turn_readback_ms"]
     assert m["turn_dispatch_ms"] + m["turn_readback_ms"] <= m["tick_mean_ms"] \
-        <= m["turn_dispatch_ms"] + m["turn_readback_ms"] + m["turn_self_ms"]
+        <= 2.5 * period

@@ -147,9 +147,12 @@ def test_a_program_without_the_counters_reads_as_nothing(name):
 
 
 def test_the_manifest_lists_them_for_the_five_serving_cells_at_its_end():
+    """All sixteen, in their order, for the five serving cells (a later PR
+    appends its metrics behind them: where they stand is not asserted)."""
     per_layer = env.load_json(env.MANIFEST)["per_layer"]
-    assert tuple(m["name"] for m in per_layer[-len(NEW):]) == NEW
-    for m in per_layer[-len(NEW):]:
+    mine = [m for m in per_layer if m["name"] in NEW]
+    assert tuple(m["name"] for m in mine) == NEW
+    for m in mine:
         assert m["workloads"] == SERVING and m["moves"] == "itl_p50_ms"
         assert m["source"] == "program_counter"
         assert m["layer"].startswith(

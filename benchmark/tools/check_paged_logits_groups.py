@@ -23,12 +23,13 @@ the reference's full forward of the whole sequence (computed in blocks of
 queries), as the largest absolute error in standard deviations of the
 reference's logits at that position.
 
-Positions whose routing is a tie in the reference are left out, as its
-``greedy_gaps`` leaves them out. The control is the CACHE's precision: the
+Positions whose routing is a tie in the reference are left out, as the
+agreement gate leaves them out (``harness/agreement.py``: the margin is the
+configuration file's ``agreement.tie_margin``). The control is the CACHE's precision: the
 reference given keys and values as an 8-bit cache would store them
 (``cache_dtype`` float8_e4m3fn, the nearest precision below the bf16 the
 configuration states) has to come out over the limit. The record also holds
-the two readings ``ROUTING_TIE`` is set between: the largest routing margin
+the two readings that margin is set between: the largest routing margin
 at which the program's logits left the reference's by more than ``SWAP`` (a
 swapped expert, not rounding), over ALL positions, and the same for the
 8-bit control. Exit code 1 where a seed is over the limit or the control
@@ -72,7 +73,7 @@ def run_seed(cell, seed: int, length: int, last: int) -> dict:
     from deeplearning4j_tpu.serve.paged import (FULL, WINDOW, BlockAllocator,
                                                 RingPages, block_bytes,
                                                 build_pools, cache_groups)
-    from harness import model as modelmod
+    from harness import agreement, model as modelmod
 
     mdl = modelmod.build(cell.config)
     params, state = modelmod.init_weights(mdl, seed)
@@ -155,7 +156,7 @@ def run_seed(cell, seed: int, length: int, last: int) -> dict:
     want, margin = ref_logits(cell.config)
     spread = want.std(axis=-1)
     err_all = np.abs(got - want).max(axis=-1) / spread
-    judged = margin >= ref.ROUTING_TIE
+    judged = margin >= agreement.tie_margin(cell.config)
     err = err_all[judged]
     eight, _ = ref_logits({**cell.config, "cache_dtype": "float8_e4m3fn"})
     low_all = np.abs(eight - want).max(axis=-1) / spread

@@ -20,8 +20,9 @@ cell's batch. Their logits are compared with the reference's full forward of
 the whole sequence, as the largest absolute error in standard deviations of
 the reference's logits at that position.
 
-Positions whose routing is a tie in the reference are left out, as its
-``greedy_gaps`` leaves them out (its note says why). The limit is the older
+Positions whose routing is a tie in the reference are left out, as the
+agreement gate leaves them out (``harness/agreement.py``: the margin is the
+configuration file's ``agreement.tie_margin``). The limit is the older
 tool's 0.15, and so is the control: the reference fed
 the weights rounded to 8 bits (float8_e4m3's 3 mantissa bits) has to come out
 over it. Readings for ``glm47f-longchat-decode`` are in PERF.md (PR 31). Exit
@@ -54,7 +55,7 @@ def run_seed(cell, seed: int, length: int, last: int) -> dict:
     from deeplearning4j_tpu.nn.generation import (as_paged, cache_parts,
                                                   decode_forward, paged_parts)
     from deeplearning4j_tpu.serve.paged import block_bytes, build_pools
-    from harness import model as modelmod
+    from harness import agreement, model as modelmod
 
     mdl = modelmod.build(cell.config)
     params, state = modelmod.init_weights(mdl, seed)
@@ -121,7 +122,7 @@ def run_seed(cell, seed: int, length: int, last: int) -> dict:
     # positions whose routing is a tie in the reference are not judged (the
     # reference's note): there the model itself is undefined to rounding
     want, margin = ref_logits(params)
-    judged = margin >= ref.ROUTING_TIE
+    judged = margin >= agreement.tie_margin(cell.config)
     got, want = got[judged], want[judged]
     spread = want.std(axis=-1)
     err = np.abs(got - want).max(axis=-1) / spread
