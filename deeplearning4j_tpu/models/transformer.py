@@ -294,6 +294,80 @@ class LagunaLM(ZooModel):
         return b.build()
 
 
+@register_model
+class MiniCpmSalaLM(ZooModel):
+    """MiniCPM-SALA (``openbmb/MiniCPM-SALA``, ``minicpm_sala``): decoder
+    layers whose mixer is ``mixer_types[first_layer + i]``, block-sparse
+    softmax attention over few KV heads (``minicpm4``) or decayed linear
+    attention (``lightning-attn``), a dense SwiGLU behind both
+    (``nn/layers/minicpm_sala.py``), the family's scaled residual stream
+    (embeddings x ``scale_emb``, every sublayer x ``scale_depth /
+    sqrt(published depth)``, the head fed the final norm over ``d_model /
+    dim_model_base``) and an untied, bias-free head. The defaults are the
+    published sizes; ``sparse`` holds the family's ``sparse_config``.
+    ``num_layers`` layers run, the published layers ``first_layer ..``: a
+    linear layer's decay follows its PUBLISHED index. ``dtype`` is the dtype
+    the parameters and the per-token cache are HELD in, as for ``OlmoeLM``;
+    a linear layer's state is ``state_dtype``."""
+
+    input_shape = (8192,)
+    MIXER_TYPES = tuple(
+        "minicpm4" if i in (0, 9, 16, 17, 22, 29, 30, 31) else "lightning-attn"
+        for i in range(32))
+    SPARSE = dict(kernel_size=32, kernel_stride=16, block_size=64, topk=64,
+                  init_blocks=1, window_size=2048, dense_len=8192)
+
+    def __init__(self, num_classes=None, seed=12345, input_shape=None, *,
+                 num_layers=32, first_layer=0, mixer_types=None,
+                 published_layers=32, d_model=4096, num_heads=32,
+                 num_kv_heads=2, head_dim=128, lightning_heads=32,
+                 ffn_width=16384, scale_emb=12.0, scale_depth=1.4,
+                 dim_model_base=256, rope_base=10000.0, sparse=None,
+                 forced_in_topk=True, state_dtype="float32",
+                 vocab=73448, rms_eps=1e-6, dtype="float32", **kw):
+        super().__init__(num_classes, seed, input_shape, **kw)
+        self.d_model, self.vocab = d_model, vocab
+        self.num_classes = vocab
+        self.rms_eps, self.dtype = rms_eps, dtype
+        self.divide = d_model / dim_model_base
+        mixers = tuple(mixer_types or self.MIXER_TYPES)
+        if first_layer + num_layers > len(mixers):
+            raise ValueError(f"layers {first_layer}..{first_layer + num_layers - 1} "
+                             f"of {len(mixers)} mixer_types")
+        common = dict(head_dim=head_dim, ffn_width=ffn_width, eps=rms_eps,
+                      residual_scale=scale_depth / published_layers ** 0.5)
+        self.blocks = []
+        for i in range(num_layers):
+            layer = first_layer + i
+            own = dict(embed_scale=float(scale_emb) if i == 0 else 1.0)
+            if mixers[layer] == "lightning-attn":
+                own.update(num_heads=lightning_heads,
+                           num_kv_heads=lightning_heads, rope_base=rope_base,
+                           decay_layer=layer, decay_depth=published_layers,
+                           state_dtype=state_dtype)
+            else:
+                own.update(num_heads=num_heads, num_kv_heads=num_kv_heads,
+                           forced_in_topk=forced_in_topk,
+                           **{**self.SPARSE, **(sparse or {})})
+            self.blocks.append(L.MiniCpmSalaBlock(mixer=mixers[layer],
+                                                  **common, **own))
+
+    def build(self) -> Sequential:
+        init = "normal_0.02"   # initializer_range
+        b = (SequentialBuilder(NetConfig(
+                seed=self.seed, dtype=self.dtype,
+                updater={"type": "adamw", "learning_rate": 3e-4}))
+             .input_shape(self.input_shape[0])
+             .layer(L.EmbeddingSequence(n_in=self.vocab, n_out=self.d_model,
+                                        weight_init=init)))
+        for block in self.blocks:
+            b.layer(block)
+        b.layer(L.ScaledRMSNorm(eps=self.rms_eps, divide=self.divide))
+        b.layer(L.RnnOutput(n_out=self.vocab, activation="softmax",
+                            loss="mcxent", use_bias=False, weight_init=init))
+        return b.build()
+
+
 # ---------------------------------------------------------------------------
 # Fully-sharded training step: dp x tp x sp over one mesh.
 # ---------------------------------------------------------------------------

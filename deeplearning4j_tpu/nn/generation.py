@@ -90,8 +90,22 @@ _TOKEN_LOCAL = (ActivationLayer, AlphaDropout, Dense, DropoutLayer,
 # is released and its column zeroed, so the column's next block is a newly
 # allocated one and a block shared with another holder is never written).
 #
+# Two kinds of part have no row a token. A part a layer holds ONCE A STRIDE
+# of tokens (``parts.strides``: ``{name: stride}``; a sparse-attention
+# indexer's pooled keys) is ``(B, C // stride, *shape)`` dense and ``(N,
+# *shape)`` paged: one entry a block, under the same tables, and the pool's
+# block size must be the stride. A STATE part (``parts.state``: ``{name:
+# dtype}``; a linear-attention layer's recurrent state) has no position axis
+# at all: ``(B, *shape)`` dense, ``(slots, *shape)`` paged, row ``s`` the
+# sequence in slot ``s``, whatever its length; it belongs to no block group
+# (``serve/paged.py``: the ``state`` group, slots and not blocks), and a
+# layer that names one masks a chunk's right padding out of it (the tokens
+# its cache entry's ``live`` does not mark leave the state as it was). Both
+# kinds are written and read by the layer that names them
+# (``layers/minicpm_sala.py``).
+#
 # ``cache_write`` / ``cache_gather`` are the only two operations either
-# layout supports (``cache_append`` / ``cache_read`` are their ``k``/``v``
+# layout supports on a per-token part (``cache_append`` / ``cache_read`` are their ``k``/``v``
 # face); everything above them (masking, rope, GQA, a latent's absorbed
 # projections) is layout-agnostic. ``pos`` may be a scalar (whole batch at
 # one offset — prefill, lockstep decode) or a (B,) vector (per-row offsets —
@@ -327,9 +341,20 @@ def attend_cached(q, k, v, cache, pos, *, window=None):
 
 class Parts(dict):
     """``{part: trailing shape}`` of one layer's cache, and the ``window``
-    the layer states for it (None: every position is kept)."""
+    the layer states for it (None: every position is kept). ``strides``:
+    the parts held once a stride of tokens, ``{part: stride}``; ``state``:
+    the parts with no position axis, one a sequence, ``{part: dtype}``
+    (the layout contract above). Every other part is held a token."""
 
     window: Optional[int] = None
+    strides: Dict[str, int] = {}
+    state: Dict[str, str] = {}
+
+    def dense_shape(self, name: str, batch: int, capacity: int) -> tuple:
+        """The dense layout's shape of part ``name``."""
+        if name in self.state:
+            return (batch,) + self[name]
+        return (batch, capacity // self.strides.get(name, 1)) + self[name]
 
 
 def cache_parts(model: Sequential):
@@ -343,10 +368,14 @@ def cache_parts(model: Sequential):
     ring of that reach and pools to match, ``serve/paged.py`` groups layers
     by it), None for a layer that keeps every position. It is everything a
     cache builder (serve/paged.py block pools, external runtimes) needs
-    without walking layer internals; a recurrent state would be further
-    part names with shapes of their own. Recurrent carries are NOT
-    listed: they are opaque layer-owned state with no write/gather
-    contract."""
+    without walking layer internals. A layer that says how it decodes may
+    name a recurrent STATE as a part (``parts.state``: one array a sequence,
+    no position axis: ``{"state": (32, 128, 128)}`` for a linear-attention
+    layer) and parts held once a stride of tokens (``parts.strides``: a
+    sparse layer's pooled keys). The carries of the ``RecurrentLayer``
+    family are NOT listed: they are opaque layer-owned state with no
+    contract a batcher could keep (no mask for a chunk's padding, no
+    snapshot)."""
     spec = []
     for i, layer in enumerate(model.layers):
         parts = _layer_parts(layer, model._shapes[i])
@@ -380,8 +409,15 @@ def says_how_it_decodes(layer) -> bool:
     (``{"latent": (512,), "rope": (64,)}``); ``decode`` finds the cache it
     is handed in the layout contract above under those names. A layer with
     an attribute ``cache_window`` (an int) states that its cache need not
-    reach further back, and is handed a ring when paged. What is left
-    on the ladders is the dense block and the bare attention layer."""
+    reach further back, and is handed a ring when paged; ``cache_strides``
+    (``{part: stride}``) and ``cache_state`` (``{part: dtype}``) name the
+    parts that are held once a stride of tokens and once a sequence. A layer
+    with ``reads_live`` true finds ``cache["live"]`` in a served program (the
+    rows, or a chunk's tokens, that are real); one with ``decode_sums``
+    (``{counter name: help}``) leaves that many int32 sums under ``"sums"``
+    in the cache a decode step returns, and the batcher counts them
+    (``serve_<name>_total``). What is left on the ladders is the dense block
+    and the bare attention layer."""
     return hasattr(layer, "decode") and hasattr(layer, "cache_spec")
 
 
@@ -404,6 +440,8 @@ def _layer_parts(layer, input_shape):
     # ``window=`` of the ladder's attention layers is a band mask over a
     # cache kept at capacity
     parts.window = getattr(layer, "cache_window", None)
+    parts.strides = dict(getattr(layer, "cache_strides", None) or {})
+    parts.state = dict(getattr(layer, "cache_state", None) or {})
     return parts
 
 
@@ -415,8 +453,9 @@ def init_caches(model: Sequential, batch: int, capacity: int, dtype):
         k = _layer_key(i, layer)
         parts = _layer_parts(layer, model._shapes[i])
         if parts is not None:
-            caches[k] = {n: jnp.zeros((batch, capacity) + shape, dtype)
-                         for n, shape in parts.items()}
+            caches[k] = {n: jnp.zeros(parts.dense_shape(n, batch, capacity),
+                                      parts.state.get(n, dtype))
+                         for n in parts}
         elif isinstance(layer, RecurrentLayer):
             caches[k] = layer.init_carry(batch, model._shapes[i], dtype)
     return caches
@@ -513,8 +552,10 @@ def check_decodes(model: Sequential, context: int, what: str, *,
     at least ``context`` long, says how it decodes itself
     (:func:`says_how_it_decodes`), or the final Output. ``served`` adds the
     continuous batcher's terms, which right-pads token prompts into slots:
-    an embedding front, no recurrent carry, an Output last. Returns the
-    vocabulary size."""
+    an embedding front, no ``RecurrentLayer`` carry, an Output last (a layer
+    that says how it decodes and names a state part in its cache spec IS
+    served: it masks the padding out of its state). Returns the vocabulary
+    size."""
     if served and not isinstance(model.layers[0],
                                  (Embedding, EmbeddingSequence)):
         raise ValueError(
@@ -529,8 +570,10 @@ def check_decodes(model: Sequential, context: int, what: str, *,
             if served:
                 raise ValueError(
                     f"layer {i} {type(layer).__name__}: recurrent carries "
-                    f"cannot survive a right-padded prefill — use whole-batch "
-                    f"nn.generation.generate for RNN models")
+                    f"cannot survive a right-padded prefill (a layer that "
+                    f"names its state as a cache part and masks the padding "
+                    f"can: nn.generation.says_how_it_decodes) — use "
+                    f"whole-batch nn.generation.generate for RNN models")
         elif isinstance(layer, PositionalEmbedding):
             # a learned positional TABLE bounds context; rope models have no
             # such layer, so paged capacity is free to exceed training length

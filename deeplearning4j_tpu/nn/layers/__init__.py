@@ -18,6 +18,7 @@ from .custom import CustomLayer, Lambda, resolve_function
 from .moe import MoE, MoETransformerBlock
 from .glm4_moe_lite import Glm4MoeLiteBlock
 from .laguna import LagunaBlock
+from .minicpm_sala import MiniCpmSalaBlock, ScaledRMSNorm
 from .norm import LRN, BatchNorm, LayerNorm, RMSNorm
 from .olmoe import OlmoeBlock
 from .pooling import Flatten, GlobalPooling, Reshape
@@ -35,10 +36,10 @@ __all__ = [
     "GaussianDropout", "GaussianNoise", "Flatten",
     "Frozen", "GRU", "Glm4MoeLiteBlock", "GlobalPooling", "GravesLSTM", "LRN", "LSTM", "Lambda",
     "LagunaBlock", "LastTimeStep",
-    "LayerNorm", "LossLayer", "MoE", "MoETransformerBlock",
+    "LayerNorm", "LossLayer", "MiniCpmSalaBlock", "MoE", "MoETransformerBlock",
     "MultiHeadAttention", "OlmoeBlock", "Output", "PReLU",
     "PositionalEmbedding", "RMSNorm", "RecurrentLayer", "Reshape", "RnnLossLayer", "RnnOutput",
-    "SeparableConv2D", "SimpleRnn", "SpaceToBatch", "SpaceToDepth",
+    "ScaledRMSNorm", "SeparableConv2D", "SimpleRnn", "SpaceToBatch", "SpaceToDepth",
     "Subsampling1D", "Subsampling2D", "TransformerEncoderBlock", "Upsampling1D",
     "Upsampling2D", "VAE", "Yolo2Output", "ZeroPadding1D", "ZeroPadding2D",
 ]

@@ -56,6 +56,11 @@ GEN_TURN = "gen.turn"                    # the worker's turn after admission: th
 GEN_KV_RELEASE = "gen.kv_release"        # inside gen.tick.prepare, only for a
 #   model with a window block group: blocks behind the windows released, the
 #   rings grown (not in SPAN_NAMES: a model without the group never opens it)
+GEN_STATE_SNAPSHOT = "gen.state_snapshot"  # only for a model with a state
+#   group: the device copies that keep a slot's state at a block boundary as
+#   a snapshot under the prefix cache (and a fork's copy of a state). A plain
+#   annotation inside gen.first_token or gen.tick.publish, never a phase of
+#   the worker's clock (not in SPAN_NAMES: no other model opens it)
 HTTP_STREAM_WRITE = "http.stream_write"  # serve/http.py: one SSE event written
 GC_PAUSE = "gc.pause"                    # one collection; generation=0|1|2
 SPAN_NAMES = (GEN_ADMIT, GEN_PREFILL_CHUNK, GEN_FIRST_TOKEN, GEN_TICK,

@@ -86,10 +86,26 @@ reads every step before it enqueues the next.
 The bit-exact baseline for all of it is whole-batch
 ``nn.generation.generate`` over its contiguous caches.
 
+A model whose layers keep a recurrent STATE and name it as a cache part (a
+linear-attention layer: ``nn.generation.Parts.state``) gets the state group
+(``serve/paged.py``): one state a slot a layer, in pools the decode and chunk
+programs update in place; a new request's first chunk starts its slot from
+zeros or from a SNAPSHOT. Snapshots are what the prefix cache needs of such a
+model: a hit of ``n`` blocks is usable only where the states at exactly ``n x
+block_size`` tokens exist, so where a run enters the cache (a prompt's whole
+blocks as its prefill ends, the answer's as the request finishes) the slot's
+state at that block boundary — the programs keep it beside the current one —
+is copied on the device into a snapshot row kept under the run's hash, and
+admission shortens a hit to the longest run that has one
+(``serve_prefix_hits_shortened_total{reason="state"}``). No state is ever
+read back to the host; ``fork()`` copies the parent's.
+
 Scope: embedding-front causal-attention stacks (the CausalLM family).
-Recurrent layers are rejected — a right-padded prefill would run the RNN
-carry over pad rows — and non-causal attention cannot decode incrementally
-at all; both families stay on whole-batch ``nn.generation.generate``.
+``RecurrentLayer`` carries are rejected — a right-padded prefill would run
+the RNN carry over pad rows (a layer that names its state as a cache part and
+masks the padding is served) — and non-causal attention cannot decode
+incrementally at all; both families stay on whole-batch
+``nn.generation.generate``.
 """
 
 from __future__ import annotations
@@ -110,9 +126,10 @@ from .engine import PrefillScheduler
 from .errors import (CapacityError, DeadlineExceededError, DrainTimeoutError,
                      ServeError, ServerClosingError, ShedError,
                      WorkerStallError)
-from .paged import (FULL, WINDOW, BlockAllocator, PrefixCache, RingPages,
-                    SlotPages, WindowGroup, block_bytes, blocks_needed,
-                    cache_groups, prefix_hashes)
+from .paged import (FULL, SNAPSHOTS_A_SLOT, STATE, WINDOW, BlockAllocator,
+                    PrefixCache, RingPages, SlotPages, StateGroup, WindowGroup,
+                    block_bytes, blocks_needed, cache_groups, prefix_hashes,
+                    state_slot_bytes)
 from .programs import GenPrograms
 from .registry import ModelRegistry
 
@@ -291,7 +308,7 @@ class _PrefillJob:
     """One prompt mid-prefill: its slot, block pages, and chunk cursor."""
 
     __slots__ = ("req", "slot", "pages", "chunks", "idx", "worst", "last",
-                 "shared", "hashes", "hash_state", "gens", "ring")
+                 "shared", "hashes", "hash_state", "gens", "ring", "load")
 
     def __init__(self, req: _GenRequest, slot: int, pages: SlotPages,
                  chunks: List[tuple], worst: int, shared: int = 0,
@@ -309,6 +326,10 @@ class _PrefillJob:
         self.hashes = hashes or []  # rolling block-run hashes of the prompt
         self.hash_state = hash_state  # the rolling sha256 behind the last
         self.gens: set = set()  # params generations its chunks ran under
+        # what the slot's recurrent state starts from at the next chunk (a
+        # model with a state group): a snapshot row (pinned until that chunk
+        # is enqueued), -1 zeros, -2 the slot's own state
+        self.load = -1
 
     @property
     def deadline(self):
@@ -538,6 +559,14 @@ class ContinuousBatcher:
                 block_bytes=block_bytes(model, self.block_size, model.dtype,
                                         groups[WINDOW].layers),
                 cached_tails=S if prefix_cache else 0)
+        # the state group: slots, not blocks; its snapshots exist for the
+        # prefix cache alone
+        self._state: Optional[StateGroup] = None
+        if STATE in groups:
+            self._state = StateGroup(
+                groups[STATE], slots=S, slot_bytes=state_slot_bytes(model),
+                snapshots=SNAPSHOTS_A_SLOT * S if prefix_cache else 0)
+        self._snap_pending: List[tuple] = []   # (slot, row) copies to make
         self._prefix: Optional[PrefixCache] = None
         if prefix_cache:
             win = self._win
@@ -545,7 +574,8 @@ class ContinuousBatcher:
                 self._alloc, self.block_size, prefix_cache_blocks,
                 **({} if win is None else dict(
                     window_allocator=win.alloc, window_tail=win.tail,
-                    window_max_blocks=win.cache_blocks)))
+                    window_max_blocks=win.cache_blocks)),
+                **({} if self._state is None else dict(state=self._state)))
             # cached-but-idle runs are reclaimed before anyone sheds
             self._alloc.set_reclaimer(self._prefix.reclaim)
             if win is not None:
@@ -705,6 +735,27 @@ class ContinuousBatcher:
                 help="admissions whose cached prefix run was cut short (or "
                      "to nothing) because the window group no longer held "
                      "the window's tail behind it")
+        if self._state is not None:
+            m.gauge("serve_state_slot_bytes", self._lbl(),
+                    help="bytes of recurrent state one sequence holds, all "
+                         "state layers (a slot's, and a snapshot's)"
+                    ).set(self._state.slot_bytes)
+            self._m_snaps = m.counter(
+                "serve_state_snapshots_total", self._lbl(),
+                help="state snapshots taken: a slot's state at a block "
+                     "boundary copied under the hash of the cached run it "
+                     "ends")
+            self._m_snap_bytes = m.gauge(
+                "serve_state_snapshot_bytes", self._lbl(),
+                help="bytes of the snapshots the prefix cache holds now")
+            self._m_px_short_state = {
+                left: m.counter(
+                    "serve_prefix_hits_shortened_total",
+                    self._lbl({"reason": "state", "left": left}),
+                    help="admissions whose cached prefix run was cut short "
+                         "(left=some) or to nothing (left=none) because no "
+                         "snapshot of the state layers existed at its end")
+                for left in ("some", "none")}
         self._m_pf_depth = m.gauge(
             "serve_prefill_queue_depth", self._lbl(),
             help="prompts mid-prefill (chunked jobs in flight)")
@@ -775,7 +826,13 @@ class ContinuousBatcher:
             block_size=self.block_size,
             chunk_buckets=self._chunk_buckets, metrics=m,
             compile_counter=self._m_compiles, store=aot_store,
-            strict=self.strict_aot, snapshot=snap0)
+            strict=self.strict_aot, snapshot=snap0,
+            **({} if self._state is None
+               else dict(state_snapshots=self._state.snapshots)))
+        # what the layers' decode steps report of themselves (decode_sums)
+        self._m_sums = [
+            m.counter(f"serve_{f}_total", self._lbl(), help=what)
+            for f, what in self._programs.sum_fields.items()]
         # what routing did, per program kind; nothing for a model without
         # experts
         if self._programs.routed:
@@ -1150,6 +1207,11 @@ class ContinuousBatcher:
             ring.adopt(self._slot_ring[s].first, held)
             self._slot_ring[t] = ring
             self._win.tables_np[t] = self._win.tables_np[s]
+        if self._state is not None:
+            # the parent's state and its state at the last block boundary,
+            # copied on the device (nothing is in flight: _run_forks_locked)
+            with _trace.span(_trace.GEN_STATE_SNAPSHOT):
+                self._programs.copy_states([(s, t)])
         self._alloc.retain(blocks)
         pages = SlotPages(self._alloc, self.block_size)
         pages.adopt(blocks)
@@ -1237,6 +1299,15 @@ class ContinuousBatcher:
             self._m_group_used[WINDOW].set(wused)
             self._m_group_bytes[WINDOW].set(wused * self._win.block_bytes)
             live += wused * self._win.block_bytes
+        if self._state is not None:
+            st = self._state
+            held = sum(1 for s in range(self.slots)
+                       if self._slot_req[s] is not None
+                       or self._slot_job[s] is not None)
+            self._m_group_used[STATE].set(held + st.used)
+            self._m_group_bytes[STATE].set((held + st.used) * st.slot_bytes)
+            self._m_snap_bytes.set(st.used * st.slot_bytes)
+            live += (held + st.used) * st.slot_bytes
         self._m_kv_bytes.set(live)
         self._m_px_shared.set(len(self._shared_ledger))
 
@@ -1337,6 +1408,19 @@ class ContinuousBatcher:
                     run = run[:n]
                 if not win.fits(tp + req.max_new):
                     break
+            load, short_state = -1, None
+            if self._state is not None and run:
+                # usable only as far as a snapshot of the state layers
+                # stands at the run's end
+                n, row = self._prefix.match_state(hashes, len(run))
+                if n < len(run):
+                    if win is not None:
+                        # the window group kept the tail behind the longer
+                        # run's end and no other
+                        n, row, ring_run = 0, None, []
+                    short_state = "some" if n else "none"
+                    run = run[:n]
+                load = -1 if row is None else row
             shared = len(run)
             worst = blocks_needed(tp + req.max_new, self.block_size) - shared
             fresh = sum(1 for b in run if b not in self._shared_ledger)
@@ -1352,6 +1436,8 @@ class ContinuousBatcher:
                 ring.adopt(shared - len(ring_run), ring_run)
                 if shared < matched:
                     self._m_px_short.inc()
+            if short_state is not None:     # counted once, not a pass it waits
+                self._m_px_short_state[short_state].inc()
             if shared:
                 self._prefix.adopt(hashes, run, ring_run)
                 pages.adopt(run)
@@ -1367,15 +1453,26 @@ class ContinuousBatcher:
                 self._plan_chunks(tp, shared * self.block_size), worst,
                 shared=shared, hashes=hashes, hash_state=hash_state,
                 ring=ring)
+            if load >= 0:
+                job.load = load
+                self._state.pin(load)
             self._slot_job[s] = job
             self._jobs.append(job)
         self._m_pf_depth.set(len(self._jobs))
+
+    def _unpin_load(self, job: _PrefillJob) -> None:
+        """The snapshot a job starts from is no longer needed: its first
+        chunk is in the device's queue, or the job is gone."""
+        if job.load >= 0:
+            self._state.unpin(job.load)
+        job.load = -2
 
     def _abort_job(self, job: _PrefillJob, err: ServeError) -> None:
         with self._cond:
             if job in self._jobs:
                 self._jobs.remove(job)
             self._slot_job[job.slot] = None
+            self._unpin_load(job)
             self._release_pages(job.pages)
             self._release_ring(job.slot, job.ring)
             self._committed -= job.worst
@@ -1404,7 +1501,12 @@ class ContinuousBatcher:
                 _prof.ACTIVE.hint("generate", true_len, bucket)
             last = self._programs.prefill_chunk(
                 self._params_for(snap), snap.state,
-                job.req.prompt[off:off + true_len], bucket, table_row, off)
+                job.req.prompt[off:off + true_len], bucket, table_row, off,
+                **({} if self._state is None
+                   else dict(slot=job.slot, load=job.load)))
+            if self._state is not None:
+                with self._cond:
+                    self._unpin_load(job)
             req = job.req
             ctx = req.ctx
             if job.idx == 0:  # first chunk closes the queue wait (its offset
@@ -1470,11 +1572,15 @@ class ContinuousBatcher:
                 self._prefix.insert(job.hashes[:nfull],
                                     job.pages.blocks[:nfull], gen_now,
                                     self._ring_tail(job.ring, nfull))
+                # the slot's state stands at that boundary in its own row
+                # of the snapshot pool (the last chunk left it there)
+                self._snapshot_locked(s, job.hashes, nfull)
                 # the answer's blocks follow under the same run's hashes
                 # when the request finishes (_cache_answer)
                 req.cached_run = _CachedRun(job.hashes, job.hash_state,
                                             gen_now)
                 self._update_kv_gauges()
+        self._flush_snapshots()
         if req.ctx is not None:
             # decode starts with the token-0 sample, not the first tick — a
             # request wedged before any tick completes still shows the stage
@@ -1563,7 +1669,29 @@ class ContinuousBatcher:
         return {b: blk for b, blk in ring.blocks.items()
                 if n - self._win.tail <= b < n}
 
-    def _cache_answer(self, s: int, req: _GenRequest, generation) -> None:
+    def _snapshot_locked(self, s: int, hashes: List[bytes], n: int) -> None:
+        """Under ``self._cond``, a run of ``n`` blocks of slot ``s`` has just
+        been inserted: keep the state layers' state at its end, which stands
+        in the slot's own row of the snapshot pool, under the run's hash. The
+        copy is made by :meth:`_flush_snapshots`, outside the lock and before
+        anything else is enqueued."""
+        if self._state is None:
+            return
+        row = self._prefix.snapshot_row(hashes, n)
+        if row is not None:
+            self._snap_pending.append((s, row))
+            self._m_snaps.inc()
+
+    def _flush_snapshots(self) -> None:
+        """The worker, outside the lock: enqueue the snapshot copies the
+        last insertions asked for (device to device; nothing is read)."""
+        if self._snap_pending:
+            pairs, self._snap_pending = self._snap_pending, []
+            with _trace.span(_trace.GEN_STATE_SNAPSHOT, copies=len(pairs)):
+                self._programs.copy_states(pairs, current=False)
+
+    def _cache_answer(self, s: int, req: _GenRequest, generation,
+                      in_flight: int = 0) -> None:
         """Under ``self._cond``, as slot ``s`` retires normally and before
         its pages go: cache the whole blocks of the request's run, the ones
         its decode steps filled included, so that a prompt which sends the
@@ -1590,6 +1718,12 @@ class ContinuousBatcher:
             hashes, self._slot_pages[s].blocks[:n_end], generation,
             self._ring_tail(self._slot_ring[s], n_end))
         self._m_px_answer.inc(added * bs)
+        # the slot's boundary state is the one at n_end blocks unless a row
+        # still in flight for it (an eos_id hit, a cancel: thrown away at
+        # its publish) has carried the slot over the next boundary
+        fed = req.prompt.shape[0] + len(req.out) - 1
+        if (fed + in_flight) // bs == n_end:
+            self._snapshot_locked(s, hashes, n_end)
 
     def _maybe_finish(self, s: int, generation=None) -> bool:
         """Retire slot ``s`` if its request is done; True where it is still
@@ -1609,11 +1743,12 @@ class ContinuousBatcher:
             self._slot_req[s] = None
             # rows of its still in flight are discarded at their publish
             # (the step holds the request, and the slot no longer does)
+            in_flight = int(self._unread[s])
             self._unread[s] = self._made[s] = 0
             self._fresh[s] = False
             self._first_dev[s] = self._key_dev[s] = None
             if self._slot_pages[s] is not None:
-                self._cache_answer(s, req, generation)
+                self._cache_answer(s, req, generation, in_flight)
                 # copy-free retirement: blocks drop one reference (cached/
                 # shared ones survive in their other holders) and the table
                 # row zeroes (points at trash) — no device work
@@ -1627,6 +1762,7 @@ class ContinuousBatcher:
                 self._update_kv_gauges()
             self._m_completed.inc()
             self._m_active.set(sum(1 for r in self._slot_req if r is not None))
+        self._flush_snapshots()
         req._finish(req.cancelled)
         return False
 
@@ -1848,6 +1984,9 @@ class ContinuousBatcher:
                 self._count_routing(
                     "decode", self._programs.decode_routing(nxt_np))
                 self._count_chunks_routing(step.chunks_upto)
+            for counter, v in zip(self._m_sums,
+                                  self._programs.decode_sums(nxt_np)):
+                counter.inc(int(v))
             t0_ns, t1_ns = step.t0, ret
             pushes = []
             with self._cond:
@@ -2166,6 +2305,7 @@ class ContinuousBatcher:
             finish.extend(self._queue)
             self._queue.clear()
         for job in list(self._jobs):
+            self._unpin_load(job)
             self._release_pages(job.pages)
             self._release_ring(job.slot, job.ring)
             self._slot_job[job.slot] = None
@@ -2265,6 +2405,12 @@ class ContinuousBatcher:
                     "blocks_used": w.alloc.used,
                     "blocks_committed": w.committed,
                     "live_bytes": w.alloc.used * w.block_bytes}
+            if self._state is not None:
+                st = self._state
+                out["state_group"] = {
+                    "slot_bytes": st.slot_bytes, "snapshots": st.snapshots,
+                    "snapshots_used": st.used, "snapshots_taken": st.taken,
+                    "snapshots_evicted": st.evictions}
             if self._prefix is not None:
                 px = self._prefix.stats()
                 px["hits"] = self._px_hits

@@ -51,6 +51,19 @@ rolling hashes, and a hit is usable only as far as the window's tail behind
 it is still held (:meth:`PrefixCache.match_window`). A model whose layers
 state no window has one group and everything here is as it was.
 
+**The state group.** A layer may name a part with no position axis, a
+recurrent state (``nn.generation.Parts.state``: a linear-attention layer).
+Such layers form the ``state`` group (:class:`StateGroup`): SLOTS, not
+blocks, so it has pools of its own and no allocator of token blocks. Beside
+each state the pool keeps the state as it stood at the last block boundary the
+slot passed (the programs refresh it), and a number of SNAPSHOTS: copies of
+such boundary states that the prefix cache keeps under the hash of the run
+they end (:meth:`PrefixCache.snapshot_row`), because a hit of ``n`` blocks is
+usable by a state layer only where its state at exactly ``n x block_size``
+tokens exists (:meth:`PrefixCache.match_state` shortens a hit to the longest
+run that has one). A snapshot dies with its run's entry, or when a newer one
+needs its row (LRU).
+
 The device-side layout contract (how positions map into pools, the trash
 block, append/read semantics, the ring) lives in ``nn/generation.py`` next
 to ``cache_write`` / ``cache_gather``; this module only decides *which*
@@ -70,6 +83,10 @@ from .errors import CapacityError
 
 TRASH_BLOCK = 0  # physical block 0 is never allocated; see module docstring
 FULL, WINDOW = "full", "window"   # the block groups' names (metric labels)
+STATE = "state"                   # the group of slots, not blocks
+# snapshots the state group keeps under the prefix cache, a slot: a request
+# leaves one at its prompt's last whole block and one at its answer's end
+SNAPSHOTS_A_SLOT = 2
 
 
 class BlockAllocator:
@@ -178,15 +195,30 @@ class CacheGroup(NamedTuple):
     layers: Tuple[str, ...]
 
 
+def _is_state(lk: str, parts) -> bool:
+    """Whether every part of the layer is a state (none has a position
+    axis); a layer that mixed the two kinds would belong to two groups."""
+    if not parts.state:
+        return False
+    if set(parts.state) != set(parts):
+        raise ValueError(f"{lk} names state parts {sorted(parts.state)} "
+                         f"beside per-token parts: one kind a layer")
+    return True
+
+
 def cache_groups(model) -> List[CacheGroup]:
     """``model``'s cached layers grouped by the cache window they state
-    (``nn.generation.cache_parts``), the full group first. One window value
-    a model: two different ones would be two rings of different reach."""
+    (``nn.generation.cache_parts``), the full group first; the layers whose
+    cache is a state (no position axis) last, as the ``state`` group. One
+    window value a model: two different ones would be two rings of
+    different reach."""
     from ..nn.generation import cache_parts
 
-    spec = cache_parts(model)
-    if not spec:
+    every = cache_parts(model)
+    if not every:
         raise ValueError("model has no attention layers to page")
+    states = tuple(lk for lk, p in every if _is_state(lk, p))
+    spec = [(lk, p) for lk, p in every if lk not in states]
     windows = sorted({p.window for _, p in spec if p.window is not None})
     if len(windows) > 1:
         raise ValueError(f"cached layers state different windows {windows}: "
@@ -196,17 +228,25 @@ def cache_groups(model) -> List[CacheGroup]:
     if windows:
         groups.append(CacheGroup(WINDOW, windows[0], tuple(
             lk for lk, p in spec if p.window is not None)))
+    groups.append(CacheGroup(STATE, None, states))
     return [g for g in groups if g.layers]
 
 
-def build_pools(model, num_blocks, block_size: int, dtype) -> Dict:
+def build_pools(model, num_blocks, block_size: int, dtype,
+                state_rows: Optional[Tuple[int, int]] = None) -> Dict:
     """Zero-filled block pools (device arrays) for every cached layer, one
     per part the layer's spec names (``nn.generation.cache_parts``):
     ``{layer_key: {part: (N, bs, *shape)}}`` — ``{"k": (N, bs, Hkv, hd),
     "v": ...}`` for KV-cached attention, ``{"latent": (N, bs, 512), "rope":
     (N, bs, 64)}`` for a layer that caches a latent and a rope key a token.
     ``num_blocks``: one number for every layer, or ``{group name: blocks}``
-    (:func:`cache_groups`) where the groups' pools differ in length."""
+    (:func:`cache_groups`) where the groups' pools differ in length. A part
+    held once a stride of tokens is ``(N, *shape)``: one entry a block, so
+    ``block_size`` must be its stride. A state layer's parts are sized in
+    slots, ``state_rows = (slots, snapshots)``: ``{part: (slots, *shape),
+    part + "_snap": (slots + snapshots, *shape)}`` in the part's own dtype
+    (row ``s`` of the second: slot ``s``'s state at its last block boundary;
+    the rows behind them: the snapshots)."""
     import jax.numpy as jnp
 
     from ..nn.generation import cache_parts
@@ -220,24 +260,56 @@ def build_pools(model, num_blocks, block_size: int, dtype) -> Dict:
             return num_blocks
         return num_blocks[FULL if parts.window is None else WINDOW]
 
-    return {lk: {n: jnp.zeros((n_of(parts), block_size) + shape, dtype)
-                 for n, shape in parts.items()}
-            for lk, parts in spec}
+    def pools(lk, parts):
+        if _is_state(lk, parts):
+            if state_rows is None:
+                raise ValueError(f"{lk} keeps a state: build_pools needs "
+                                 f"state_rows=(slots, snapshots)")
+            slots, snaps = state_rows
+            out = {}
+            for n, shape in parts.items():
+                out[n] = jnp.zeros((slots,) + shape, parts.state[n])
+                out[n + "_snap"] = jnp.zeros((slots + snaps,) + shape,
+                                             parts.state[n])
+            return out
+        for n, stride in parts.strides.items():
+            if stride != block_size:
+                raise ValueError(
+                    f"{lk} holds {n!r} once every {stride} tokens: the pool's "
+                    f"block size must be {stride}, not {block_size}")
+        return {n: jnp.zeros(
+            (n_of(parts),) + (() if n in parts.strides else (block_size,))
+            + shape, dtype) for n, shape in parts.items()}
+
+    return {lk: pools(lk, parts) for lk, parts in spec}
 
 
 def block_bytes(model, block_size: int, dtype,
                 layers: Optional[Sequence[str]] = None) -> int:
     """Bytes one block holds across ALL cached layers (or those of
     ``layers``: one group's) and all the parts their specs name (k + v; a
-    latent and its rope key) — the unit the live-KV-bytes gauge counts in,
-    and ``block_size`` times what one token costs."""
+    latent and its rope key; a part held once a stride of tokens counts
+    ``block_size // stride`` entries) — the unit the live-KV-bytes gauge
+    counts in, and ``block_size`` times what one token costs. A state is no
+    block's: :func:`state_slot_bytes`."""
     from ..nn.generation import cache_parts
 
     itemsize = np.dtype(dtype).itemsize
-    return sum(block_size * int(np.prod(shape)) * itemsize
+    return sum(block_size // parts.strides.get(n, 1) * int(np.prod(shape))
+               * itemsize
                for lk, parts in cache_parts(model)
                if layers is None or lk in layers
-               for shape in parts.values())
+               for n, shape in parts.items() if n not in parts.state)
+
+
+def state_slot_bytes(model) -> int:
+    """Bytes of recurrent state ONE sequence holds across all state layers
+    (each part in its own dtype): what a slot, and a snapshot, costs."""
+    from ..nn.generation import cache_parts
+
+    return sum(int(np.prod(shape)) * np.dtype(parts.state[n]).itemsize
+               for _, parts in cache_parts(model)
+               for n, shape in parts.items() if n in parts.state)
 
 
 def blocks_needed(tokens: int, block_size: int) -> int:
@@ -298,7 +370,8 @@ class PrefixCache:
                  max_blocks: Optional[int] = None, *,
                  window_allocator: Optional[BlockAllocator] = None,
                  window_tail: int = 0,
-                 window_max_blocks: Optional[int] = None):
+                 window_max_blocks: Optional[int] = None,
+                 state: Optional["StateGroup"] = None):
         self._alloc = allocator
         self.block_size = int(block_size)
         # hard size bound (entries == blocks); None = bounded only by the
@@ -313,6 +386,8 @@ class PrefixCache:
         self.window_tail = int(window_tail)
         self.window_max_blocks = window_max_blocks
         self._wruns: "OrderedDict[bytes, int]" = OrderedDict()
+        # the state group's snapshots of cached runs, by the same hashes
+        self._state = state
 
     def __len__(self) -> int:
         return len(self._runs)
@@ -338,6 +413,8 @@ class PrefixCache:
         if self._wruns:
             self._walloc.release(list(self._wruns.values()))
             self._wruns.clear()
+        if self._state is not None:
+            self._state.clear()
         return n
 
     def match(self, hashes: Sequence[bytes], generation: int,
@@ -370,6 +447,28 @@ class PrefixCache:
         tail = hashes[max(0, best - self.window_tail):best]
         return best, [self._wruns[h] for h in tail]
 
+    def match_state(self, hashes: Sequence[bytes],
+                    n: int) -> Tuple[int, Optional[int]]:
+        """The longest hit ``m <= n`` (in blocks) that ends where the state
+        group holds a snapshot (a state layer can go on from a run only from
+        its state at exactly the run's end), and that snapshot's row; ``(0,
+        None)`` where none does. Pure lookup, like :meth:`match`."""
+        for m in range(min(int(n), len(hashes)), 0, -1):
+            row = self._state.row_of(hashes[m - 1])
+            if row is not None:
+                return m, row
+        return 0, None
+
+    def snapshot_row(self, hashes: Sequence[bytes], n: int) -> Optional[int]:
+        """A row of the state group's snapshot pool for the state at the end
+        of the cached run of ``n`` blocks, which the caller is about to copy
+        there; None where the run is not cached (it was not inserted: a full
+        cache), has its snapshot already, or no row can be had (every one
+        pinned by an admission). Under the lock, after :meth:`insert`."""
+        if not 0 < n <= len(hashes) or hashes[n - 1] not in self._runs:
+            return None
+        return self._state.take(hashes[n - 1])
+
     def adopt(self, hashes: Sequence[bytes], run: List[int],
               window_run: Sequence[int] = ()) -> None:
         """Take one reference per matched block and mark the run
@@ -385,6 +484,8 @@ class PrefixCache:
             self._walloc.retain(window_run)
             for h in hashes[len(run) - len(window_run):len(run)]:
                 self._wruns.move_to_end(h)
+        if self._state is not None:
+            self._state.touch(hashes[len(run) - 1])
 
     def insert(self, hashes: Sequence[bytes], blocks: Sequence[int],
                generation: int,
@@ -428,6 +529,13 @@ class PrefixCache:
         if b is not None:
             self._walloc.release([b])
 
+    def _drop_beside(self, h: bytes) -> None:
+        """An entry left the full group: what the other groups keep under its
+        hash goes with it (a window block, a state snapshot)."""
+        self._drop_window(h)
+        if self._state is not None:
+            self._state.drop(h)
+
     def _insert_full(self, hashes: Sequence[bytes],
                      blocks: Sequence[int]) -> int:
         ins = 0
@@ -452,7 +560,7 @@ class PrefixCache:
             return False
         h, b = self._runs.popitem(last=False)
         self._alloc.release([b])
-        self._drop_window(h)
+        self._drop_beside(h)
         self.evictions += 1
         return True
 
@@ -473,7 +581,7 @@ class PrefixCache:
             if self._alloc.refcount(b) == 1:
                 del self._runs[h]
                 self._alloc.release([b])
-                self._drop_window(h)
+                self._drop_beside(h)
                 self.evictions += 1
                 freed += 1
         return freed
@@ -499,6 +607,8 @@ class PrefixCache:
                "generation": self.generation}
         if self._walloc is not None:
             out["window_entries"] = len(self._wruns)
+        if self._state is not None:
+            out["state_snapshots"] = self._state.used
         return out
 
 
@@ -698,3 +808,89 @@ class WindowGroup:
         self.committed -= ring.committed
         ring.committed = 0
         self.tables_np[s] = 0
+
+
+class StateGroup:
+    """Host state of the state group of one batcher: which rows of the
+    snapshot pool hold which cached run's state. The pool has ``slots +
+    snapshots`` rows a state layer: row ``s < slots`` is slot ``s``'s own (its
+    state at the last block boundary it passed; the programs write it), the
+    rows behind them are snapshots, kept under the rolling hash of the run
+    they end, least recently used first to go. A row an admission has matched
+    is PINNED until the request's first chunk, which loads it, is enqueued:
+    the device runs its queue in order, so a copy enqueued later may then
+    overwrite it."""
+
+    def __init__(self, group: CacheGroup, *, slots: int, snapshots: int,
+                 slot_bytes: int):
+        self.layers = group.layers
+        self.slots = int(slots)
+        self.snapshots = int(snapshots)
+        self.slot_bytes = int(slot_bytes)
+        self._free: List[int] = list(range(self.slots + self.snapshots - 1,
+                                           self.slots - 1, -1))
+        self._rows: "OrderedDict[bytes, int]" = OrderedDict()
+        self._pins: Dict[int, int] = {}
+        self._orphans: set = set()     # dropped while pinned: freed at unpin
+        self.taken = 0
+        self.evictions = 0
+
+    @property
+    def used(self) -> int:
+        """Snapshots held."""
+        return len(self._rows)
+
+    def row_of(self, h: bytes) -> Optional[int]:
+        return self._rows.get(h)
+
+    def touch(self, h: bytes) -> None:
+        if h in self._rows:
+            self._rows.move_to_end(h)
+
+    def take(self, h: bytes) -> Optional[int]:
+        """A row for a new snapshot under ``h``: a free one, else the least
+        recently used one nobody has pinned. None where ``h`` has one
+        already (it is refreshed in the LRU) or none can be had."""
+        if h in self._rows:
+            self._rows.move_to_end(h)
+            return None
+        if not self._free:
+            old = next((k for k, r in self._rows.items()
+                        if r not in self._pins), None)
+            if old is None:
+                return None
+            self._free.append(self._rows.pop(old))
+            self.evictions += 1
+        row = self._free.pop()
+        self._rows[h] = row
+        self.taken += 1
+        return row
+
+    def drop(self, h: bytes) -> None:
+        row = self._rows.pop(h, None)
+        if row is not None:
+            self._release(row)
+
+    def clear(self) -> None:
+        rows, self._rows = list(self._rows.values()), OrderedDict()
+        for row in rows:
+            self._release(row)
+
+    def _release(self, row: int) -> None:
+        if row in self._pins:
+            self._orphans.add(row)
+        else:
+            self._free.append(row)
+
+    def pin(self, row: int) -> None:
+        self._pins[row] = self._pins.get(row, 0) + 1
+
+    def unpin(self, row: int) -> None:
+        c = self._pins.get(row, 0)
+        if c > 1:
+            self._pins[row] = c - 1
+            return
+        self._pins.pop(row, None)
+        if row in self._orphans:
+            self._orphans.discard(row)
+            self._free.append(row)

@@ -19,7 +19,12 @@ program IS, and everything only the programs need to know:
   it always did;
 - how a model with expert layers reports what routing did: which rows are
   live, and the three sums that ride a decode step's tokens as ``(S + 3,)``
-  (four where the layers hold a share of their experts);
+  (four where the layers hold a share of their experts); the sums a layer
+  names itself (``decode_sums``) ride behind them the same way;
+- the STATE group of a model whose layers keep a recurrent state
+  (``serve/paged.py``): pools sized in slots, updated in place by both
+  programs; the chunk program is told which slot its one row is and what the
+  slot starts from (a snapshot, zeros, its own state);
 - the persistent-store wrapping under the tags ``gen_sample``,
   ``gen_prefill_chunk``, ``gen_decode_paged``;
 - each program's operand list, written down once as abstract shapes
@@ -39,7 +44,16 @@ from typing import Any, Dict, List, Sequence
 
 import numpy as np
 
-from .paged import FULL, build_pools, cache_groups
+from .paged import FULL, STATE, build_pools, cache_groups
+
+
+def _index_pairs(pairs: List[tuple]):
+    """``(src, dst)`` pairs as two device index vectors (the operands of an
+    eager row copy: XLA's eager cache keys on shapes, not on values)."""
+    import jax.numpy as jnp
+
+    return tuple(jnp.asarray(np.fromiter((p[i] for p in pairs), np.int32,
+                                         len(pairs))) for i in (0, 1))
 
 
 class GenPrograms:
@@ -55,7 +69,8 @@ class GenPrograms:
     def __init__(self, model, *, slots: int, table_blocks: int, vocab: int,
                  kv_blocks: int, block_size: int,
                  chunk_buckets: Sequence[int], metrics, compile_counter,
-                 store=None, strict: bool = False, snapshot=None):
+                 store=None, strict: bool = False, snapshot=None,
+                 state_snapshots: int = 0):
         import jax
         import jax.numpy as jnp
 
@@ -92,12 +107,16 @@ class GenPrograms:
             return _sample_rows(logits[None], key[None], temperature[None],
                                 top_k[None])[0]
 
-        self.pools = build_pools(mdl, kv_blocks, block_size, mdl.dtype)
+        #: block group -> its layers; more than one BLOCK group makes every
+        #: tables operand a dict (the state group has slots, no tables)
+        self.group_layers = {g.name: g.layers for g in cache_groups(mdl)}
+        stateful = self.group_layers.get(STATE, ())
+        self.pools = build_pools(
+            mdl, kv_blocks, block_size, mdl.dtype,
+            **({"state_rows": (self.slots, int(state_snapshots))}
+               if stateful else {}))
         # layer key -> the parts its pools hold (k and v; latent and rope)
         names = {lk: tuple(pool) for lk, pool in self.pools.items()}
-        #: block group -> its layers; more than one group makes every
-        #: tables operand a dict
-        self.group_layers = {g.name: g.layers for g in cache_groups(mdl)}
         grouped = isinstance(table_blocks, dict)
         group_of = {lk: g for g, layers in self.group_layers.items()
                     for lk in layers}
@@ -111,6 +130,27 @@ class GenPrograms:
                   and getattr(layer, "num_experts", 0)]
         #: expert layers a program runs; 0 for a model without experts
         self.routed = len(routed)
+        # layers that ask which rows (a chunk: which tokens) are real
+        # (``reads_live``), and layers whose decode steps report sums of
+        # their own (``decode_sums``: nn.generation.says_how_it_decodes)
+        lively, summed = [], []
+        #: what those sums count, in order: ``{counter name: help}``
+        self.sum_fields: Dict[str, str] = {}
+        for i, layer in enumerate(mdl.layers):
+            if not says_how_it_decodes(layer):
+                continue
+            if getattr(layer, "reads_live", False):
+                lively.append(_layer_key(i, layer))
+            fields = dict(getattr(layer, "decode_sums", None) or {})
+            if fields:
+                if self.sum_fields and fields != self.sum_fields:
+                    raise ValueError(
+                        f"{_layer_key(i, layer)} reports {list(fields)}, the "
+                        f"layers before it {list(self.sum_fields)}: one list "
+                        f"a model")
+                self.sum_fields = fields
+                summed.append(_layer_key(i, layer))
+        self.stateful = bool(stateful)
         from ..nn.layers.experts import ELSEWHERE_FIELDS, ROUTING_FIELDS
 
         #: what the sums a program reports count, in order: a fourth where
@@ -122,10 +162,18 @@ class GenPrograms:
         # something computed behind them (chunk_routing)
         self._routing_pending: List[Any] = []
 
-        def _as_caches(pools, tables, live=None):
+        def _as_caches(pools, tables, live=None, slot=None, load=None):
             caches = {lk: as_paged(pools[lk], tables[group_of[lk]]
-                                   if grouped else tables) for lk in names}
-            for lk in routed:
+                                   if grouped else tables)
+                      for lk in names if lk not in stateful}
+            for lk in stateful:
+                # no tables: row s is slot s (the decode step), or the one
+                # row is ``slot`` and starts from ``load`` (a chunk)
+                caches[lk] = {**{f"{n}_pool": a
+                                 for n, a in pools[lk].items()},
+                              "slot": slot, "load": load,
+                              "every": block_size}
+            for lk in routed + lively:
                 caches[lk]["live"] = live
             return caches
 
@@ -137,16 +185,19 @@ class GenPrograms:
                     for lk, parts in names.items()}
 
         def _prefill_chunk_fn(params, state, ids, pools, table_row, pos,
-                              true_len):
+                              true_len, *slot_load):
             """One prompt chunk for one slot. ``ids`` (1, Tb)
             right-padded; ``pos`` (1,) chunk offset; pad garbage writes
             past the row's blocks land in the trash block. Logits are
-            gathered at the last REAL token of the chunk."""
+            gathered at the last REAL token of the chunk. ``slot_load``
+            (a model with a state group only): the slot (1,) the row is, and
+            what its state starts from (1,): a snapshot's row, -1 zeros, -2
+            the slot's own state (the chunk before this one left it)."""
             live = (jnp.arange(ids.shape[1]) < true_len)[None] \
-                if routed else None
+                if routed or lively else None
             lg, caches = decode_forward(
                 mdl, params, state, ids,
-                _as_caches(pools, table_row, live), pos)
+                _as_caches(pools, table_row, live, *slot_load), pos)
             last = jnp.take(lg, true_len - 1, axis=1)  # (1, V)
             if routed:
                 return last, _as_pools(caches), _routing(caches)
@@ -181,7 +232,8 @@ class GenPrograms:
             pos = jnp.where(live[:, 0], pos, 0)
             lg, caches = decode_forward(
                 mdl, params, state, toks[:, None].astype(jnp.int32),
-                _as_caches(pools, tables, live if routed else None), pos)
+                _as_caches(pools, tables,
+                           live if routed or lively else None), pos)
 
             new_keys, subs = jnp.moveaxis(
                 jax.vmap(jax.random.split)(keys), 1, 0)
@@ -189,6 +241,10 @@ class GenPrograms:
             if routed:
                 # the three sums ride the tokens' readback: (S + 3,)
                 nxt = jnp.concatenate([nxt, _routing(caches)])
+            if summed:
+                # and the sums the layers name themselves behind them
+                nxt = jnp.concatenate(
+                    [nxt, sum(caches[lk]["sums"] for lk in summed)])
             return nxt, _as_pools(caches), pos + 1, new_keys
 
         self._sample = jax.jit(_sample_dynamic)
@@ -202,7 +258,8 @@ class GenPrograms:
         # routing sums behind them), positions and keys; then the sampling
         # vectors, uploaded when a row is set and not between; then the
         # operands of a step that sets no row, made once
-        self._next_width = S + (len(self.routing_fields) if routed else 0)
+        self._next_width = S + (len(self.routing_fields) if routed else 0) \
+            + len(self.sum_fields)
         self._carry = (jnp.zeros((self._next_width,), jnp.int32),
                        jnp.zeros((S,), jnp.int32),
                        jnp.zeros((S, 2), jnp.uint32))
@@ -262,6 +319,8 @@ class GenPrograms:
             "gen_prefill_chunk": [(params, state, sds((1, b), i32), pools,
                                    tables(1), sds((1,), i32),
                                    sds((), i32))
+                                  + ((sds((1,), i32),) * 2
+                                     if self.stateful else ())
                                   for b in self.chunk_buckets],
         }
 
@@ -282,11 +341,14 @@ class GenPrograms:
                             np.int32(top_k))
 
     def prefill_chunk(self, params, state, tokens: np.ndarray, bucket: int,
-                      table_row, off: int):
+                      table_row, off: int, slot: int = 0, load: int = -2):
         """Run ``tokens``, a prompt chunk at offset ``off`` right-padded to
         ``bucket``, through the slot whose table row is ``table_row``
         ``(1, table_blocks)`` (``{group: row}`` with more than one group).
-        Returns the logits (1, V) at its last real token, on the device."""
+        With a state group: the row is slot ``slot`` and its state starts
+        from snapshot row ``load``, from zeros (-1), or goes on from the
+        slot's own (-2). Returns the logits (1, V) at its last real token, on
+        the device."""
         import jax
         import jax.numpy as jnp
 
@@ -299,7 +361,9 @@ class GenPrograms:
             params, state, jnp.asarray(ids), self.pools,
             jax.tree.map(jnp.asarray, table_row),
             np.full((1,), off, np.int32),
-            np.int32(true_len))
+            np.int32(true_len),
+            *((np.full((1,), slot, np.int32), np.full((1,), load, np.int32))
+              if self.stateful else ()))
         self._routing_pending += routing
         return last
 
@@ -313,9 +377,9 @@ class GenPrograms:
         (token, key)}``, the device values an admission left: the first
         token as the sampler's scalar (None once the host has read it into
         ``toks``) and the slot's key. Returns the device value ``next``: the
-        ``slots`` tokens (and behind them what :meth:`decode_routing`
-        reads). Nothing is read back here, and a step that sets no row
-        uploads the tables and nothing else."""
+        ``slots`` tokens (and behind them what :meth:`decode_routing` and
+        :meth:`decode_sums` read). Nothing is read back here, and a step
+        that sets no row uploads the tables and nothing else."""
         import jax
         import jax.numpy as jnp
 
@@ -346,7 +410,12 @@ class GenPrograms:
     def decode_routing(self, nxt: np.ndarray) -> np.ndarray:
         """The routing sums (ROUTING_FIELDS) of the decode step whose
         ``next`` was read back as ``nxt``; only for a model with experts."""
-        return nxt[self.slots:]
+        return nxt[self.slots:self._next_width - len(self.sum_fields)]
+
+    def decode_sums(self, nxt: np.ndarray) -> np.ndarray:
+        """The sums the layers of that step name themselves
+        (``sum_fields``); empty for a model without such layers."""
+        return nxt[self._next_width - len(self.sum_fields):]
 
     def chunk_routing(self, n: int) -> List[np.ndarray]:
         """The sums of the ``n`` oldest prefill chunks not read yet. Call it
@@ -365,16 +434,24 @@ class GenPrograms:
         NOT a jit site, so the committed compile-surface budget (decode ==
         one executable) is untouched; the indices ride as device operands,
         so XLA's eager cache reuses one executable per pool shape."""
-        import jax.numpy as jnp
-
-        src = jnp.asarray(np.fromiter((p[0] for p in pairs), np.int32,
-                                      len(pairs)))
-        dst = jnp.asarray(np.fromiter((p[1] for p in pairs), np.int32,
-                                      len(pairs)))
+        src, dst = _index_pairs(pairs)
         for lk in self.group_layers[group]:
             pool = self.pools[lk]
             for n in pool:
                 pool[n] = pool[n].at[dst].set(pool[n][src])
+
+    def copy_states(self, pairs: List[tuple], current: bool = True) -> None:
+        """Copy rows of the state group's pools, ``(src, dst)`` each: of the
+        snapshot pools (a slot's boundary state kept as a snapshot), and with
+        ``current`` of the slots' states too (a fork: the child's slot takes
+        the parent's state and boundary state). Eager indexed updates, as
+        :meth:`copy_blocks`; nothing is read back."""
+        src, dst = _index_pairs(pairs)
+        for lk in self.group_layers[STATE]:
+            pool = self.pools[lk]
+            for n in pool:
+                if current or n.endswith("_snap"):
+                    pool[n] = pool[n].at[dst].set(pool[n][src])
 
     def aot_functions(self) -> dict:
         """Tag -> :class:`~..aot.AotFunction` for every store-backed

@@ -1,5 +1,7 @@
-"""The paged cache's two block groups (``serve/paged.py``, the layout
-contract in ``nn/generation.py``): layers that state a cache window share a
+"""The paged cache's groups (``serve/paged.py``, the layout contract in
+``nn/generation.py``); at the end of the file the third, the STATE group of a
+model whose layers keep a recurrent state (slots, not blocks), beside
+``full`` and ``window``. First the two block groups: layers that state a cache window share a
 ring table, pools of the ring's length and an allocator of their own beside
 the full group's. A small Laguna (window 16, blocks of 4, chunks of 8: a ring
 of 7 columns) through the units and through the batcher; and a model whose
@@ -15,10 +17,12 @@ from deeplearning4j_tpu.nn.generation import (Parts, cache_parts,
                                               ring_blocks, ring_positions)
 from deeplearning4j_tpu.obs.metrics import MetricsRegistry
 from deeplearning4j_tpu.serve.continuous import ContinuousBatcher
-from deeplearning4j_tpu.serve.paged import (FULL, WINDOW, BlockAllocator,
-                                            PrefixCache, RingPages,
+from deeplearning4j_tpu.serve.paged import (FULL, STATE, WINDOW,
+                                            BlockAllocator, PrefixCache,
+                                            RingPages, StateGroup,
                                             block_bytes, build_pools,
-                                            cache_groups, prefix_hashes)
+                                            cache_groups, prefix_hashes,
+                                            state_slot_bytes)
 
 W, BS, CHUNK = 16, 4, 8
 
@@ -551,5 +555,73 @@ def test_two_sessions_keep_a_tail_each():
             assert cb.kv_block_stats()["prefix_cache"]["window_entries"] == 8
         assert counter(cb, "serve_prefix_cache_hits_total") == 6
         assert counter(cb, "serve_prefix_hits_shortened_total") == 0
+    finally:
+        cb.shutdown()
+
+
+# ------------------------------------------------------- the state group
+def sala(**kw):
+    args = dict(seed=3, input_shape=(128,), num_layers=3, published_layers=3,
+                mixer_types=["minicpm4", "lightning-attn", "lightning-attn"],
+                d_model=32, num_heads=4, num_kv_heads=2, head_dim=8,
+                lightning_heads=4, ffn_width=48, dim_model_base=8,
+                sparse=dict(kernel_size=8, kernel_stride=BS, block_size=8,
+                            topk=3, init_blocks=1, window_size=8,
+                            dense_len=24), vocab=64)
+    args.update(kw)
+    m = models.MiniCpmSalaLM(**args).build()
+    m.init()
+    return m
+
+
+def test_state_layers_form_a_third_group_of_slots_not_blocks():
+    m = sala()
+    groups = cache_groups(m)
+    assert [(g.name, g.window, g.layers) for g in groups] == [
+        (FULL, None, ("layer_1",)), (STATE, None, ("layer_2", "layer_3"))]
+    parts = dict(cache_parts(m))
+    assert parts["layer_2"].state == {"state": "float32"}
+    assert parts["layer_1"].strides == {"kpool": BS}
+    # blocks hold the per-token parts (k, v: 2 x 8 x 4 B a token) and one
+    # pooled key a block; a state is no block's
+    assert block_bytes(m, BS, "float32") == BS * 2 * 64 + 64
+    assert block_bytes(m, BS, "float32", groups[0].layers) \
+        == block_bytes(m, BS, "float32")
+    assert state_slot_bytes(m) == 2 * 4 * 8 * 8 * 4
+    pools = build_pools(m, 9, BS, "float32", state_rows=(2, 3))
+    assert {n: a.shape for n, a in pools["layer_1"].items()} == {
+        "k": (9, BS, 2, 8), "v": (9, BS, 2, 8), "kpool": (9, 2, 8)}
+    assert {n: (a.shape, str(a.dtype)) for n, a in pools["layer_2"].items()} \
+        == {"state": ((2, 4, 8, 8), "float32"),
+            "state_snap": ((5, 4, 8, 8), "float32")}
+    with pytest.raises(ValueError, match="state_rows"):
+        build_pools(m, 9, BS, "float32")
+    with pytest.raises(ValueError, match="block size must be 4"):
+        build_pools(m, 9, 8, "float32", state_rows=(2, 3))
+    # the models above have no such group, and are laid out as before
+    assert [g.name for g in cache_groups(laguna())] == [FULL, WINDOW]
+    assert [g.name for g in cache_groups(dense())] == [FULL]
+
+
+def test_batcher_counts_the_state_group_and_serves_through_it():
+    m = sala()
+    cb = batcher(m, slots=2, capacity=96)
+    try:
+        assert isinstance(cb._state, StateGroup) and cb._win is None
+        assert cb._state.snapshots == 4 and cb._state.slots == 2
+        shapes = {n: a.shape for n, a in cb._programs.pools["layer_3"].items()}
+        assert shapes == {"state": (2, 4, 8, 8), "state_snap": (6, 4, 8, 8)}
+        prompts = np.stack([tokens(40, seed=s) for s in (1, 2)])
+        want = generate(m, prompts, 12, temperature=0.0, capacity=64)
+        assert np.array_equal(cb.generate(prompts, 12, temperature=0.0), want)
+        stats = cb.kv_block_stats()
+        # both requests left a snapshot at their prompt's end and one at
+        # their answer's; the gauges count them in the state group
+        assert stats["state_group"]["snapshots_used"] == 4
+        assert counter(cb, "serve_kv_group_live_bytes", group=STATE) \
+            == 4 * state_slot_bytes(m)
+        assert counter(cb, "serve_kv_live_bytes") == stats["live_bytes"] \
+            + 4 * state_slot_bytes(m)
+        assert counter(cb, "serve_kv_token_bytes") == 2 * 64 + 16
     finally:
         cb.shutdown()

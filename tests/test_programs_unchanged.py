@@ -19,6 +19,12 @@ device, and selects (integer ``where`` on a per-slot mask) the rows the host
 sets; it returns positions + 1 beside the keys. The sampler's and the
 chunk's texts are the parent's to the byte (their hashes stand as taken).
 
+PR 45 gave the cache contract a third group (a linear-attention layer's
+recurrent state: slots, not blocks), parts held once a stride of tokens, and
+the chunk program two more operands FOR SUCH A MODEL; every pin above it
+stands as it was, and MiniCPM-SALA's programs are pinned from the commit that
+brought them.
+
 A change that is MEANT to alter these programs re-takes the hashes (run this
 file with ``-s`` and copy what it prints) and says so in ``PERF.md``.
 """
@@ -57,6 +63,12 @@ PARENT = {
         "gen_sample": "5735026fd23a417e6ff6cd8d38548127448acbbb804e224ea67331c92424dc92",
         "gen_decode_paged": "39be426a048868f10f9cd650ce247c478912c7fb3cd4d2ee8ab247c7340a3e1c",
         "gen_prefill_chunk": "6647156ad2ac696815543b0566d60f6b244e5dddf5017cce5888993901fbefe2",
+    },
+    # taken at PR 45, the commit that brought the model
+    "sala": {
+        "gen_sample": "5735026fd23a417e6ff6cd8d38548127448acbbb804e224ea67331c92424dc92",
+        "gen_decode_paged": "a9f8352287d28ba1acba17b354b5db6266276c95f61e59571a74a4f413277967",
+        "gen_prefill_chunk": "4fd9c65628c5f40979a31b87688c3e7cf5e8ab2933aef5a754a03f70bb707a23",
     },
 }
 
@@ -107,6 +119,23 @@ def _laguna():
     return m
 
 
+def _sala():
+    """``minicpm-sala``'s (PR 45): sparse layers that select inside the paged
+    cache (the pool's block size is the indexer's stride), linear layers with
+    a state group, parameters held once in bf16. Pinned from its first commit
+    on."""
+    m = models.MiniCpmSalaLM(
+        seed=0, input_shape=(64,), num_layers=4, published_layers=4,
+        mixer_types=["minicpm4", "lightning-attn", "lightning-attn",
+                     "minicpm4"], d_model=64, num_heads=4, num_kv_heads=2,
+        head_dim=16, lightning_heads=4, ffn_width=128, dim_model_base=16,
+        sparse=dict(kernel_size=32, kernel_stride=16, block_size=32, topk=1,
+                    init_blocks=1, window_size=16, dense_len=32),
+        vocab=128, dtype="bfloat16").build()
+    m.init()
+    return m
+
+
 def lowered_hashes(model):
     cb = ContinuousBatcher(model, slots=2, capacity=64, block_size=16,
                            prefill_chunk=16, metrics=MetricsRegistry())
@@ -131,7 +160,8 @@ def lowered_hashes(model):
 @pytest.mark.parametrize("tag", ["gen_sample", "gen_decode_paged",
                                  "gen_prefill_chunk"])
 @pytest.mark.parametrize("name,build", [("dense", _dense), ("olmoe", _olmoe),
-                                        ("glm", _glm), ("laguna", _laguna)])
+                                        ("glm", _glm), ("laguna", _laguna),
+                                        ("sala", _sala)])
 def test_lowered_text_is_the_parents(name, build, tag):
     got = lowered_hashes(build())
     print(name, got)
