@@ -107,7 +107,15 @@ _TOKEN_LOCAL = (ActivationLayer, AlphaDropout, Dense, DropoutLayer,
 # ``cache_write`` / ``cache_gather`` are the only two operations either
 # layout supports on a per-token part (``cache_append`` / ``cache_read`` are their ``k``/``v``
 # face); everything above them (masking, rope, GQA, a latent's absorbed
-# projections) is layout-agnostic. ``pos`` may be a scalar (whole batch at
+# projections) is layout-agnostic. One read goes AROUND the gather: a DECODE
+# step (one query a row) over a paged ``k``/``v`` cache kept whole reads the
+# two pools in place, each row's ``pos // bs + 1`` live blocks and nothing
+# past them (``reads_in_place`` / ``attend_in_place``: the Pallas kernel of
+# ``ops/paged_attention.py``, MQA, MHA and GQA alike). It leans on the same
+# contract: a table's entries past the live blocks are never followed, and
+# what the last live block holds past ``pos`` is masked inside the kernel.
+# Prefill chunks, a ring, a latent pool, a sparse layer's selected blocks and
+# the dense layout gather as before. ``pos`` may be a scalar (whole batch at
 # one offset — prefill, lockstep decode) or a (B,) vector (per-row offsets —
 # continuous-batching decode, where every slot sits at its own position).
 #
@@ -299,17 +307,48 @@ def causal_valid(pos, Tq: int, C: int, window=None, kpos=None):
     return valid
 
 
+def reads_in_place(cache, q, window=None) -> bool:
+    """Whether a chunk of queries ``q`` (B, Tq, H, hd) is a DECODE step over
+    a paged k/v cache kept whole, which :func:`attend_in_place` serves without
+    a gather: ``tables`` there, one query a row, no window (a ring's table
+    and a band over a cache at capacity stay on ``cache_gather``), and dtypes
+    the kernel multiplies as exactly as the einsums."""
+    from ..ops import paged_attention
+
+    return ("tables" in cache and q.shape[1] == 1 and window is None
+            and paged_attention.supports(q.dtype, cache["k_pool"].dtype))
+
+
+def attend_in_place(q, cache, pos):
+    """Attend a decode step's ``q`` (B, 1, H, hd) at ``pos`` (scalar or (B,))
+    over a paged cache that already holds this step's keys and values:
+    (B, 1, H, hd) in the wider of ``q``'s and the pools' dtype, as the
+    einsums answer. The k/v pools are read where they lie,
+    each row's live blocks only (``ops/paged_attention.py``): no gathered
+    copy, no mask over the capacity."""
+    from ..ops.paged_attention import paged_attention_decode
+
+    B = q.shape[0]
+    p = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    return paged_attention_decode(q[:, 0], cache["k_pool"], cache["v_pool"],
+                                  cache["tables"], p)[:, None]
+
+
 def attend_cached(q, k, v, cache, pos, *, window=None):
     """The layout-agnostic half of a cached attention: append the chunk's
     ``k``/``v`` (B, Tq, Hkv, hd; already rotated) at ``pos``, read the cache
     back, and attend ``q`` (B, Tq, H, hd) causally over slots 0..pos+t.
     Returns ((B, Tq, H*hd), new_cache). A layer with projections of its own
-    (``layers/olmoe.py``) calls this from its ``decode``."""
+    (``layers/olmoe.py``) calls this from its ``decode``. A decode step over
+    a paged cache reads the pools in place (:func:`reads_in_place`); every
+    other call gathers."""
     B, Tq, H, hd = q.shape
     Hkv = k.shape[2]
     D = H * hd
     pv = _pos_vec(pos)
     cache = cache_append(cache, k, v, pos)
+    if reads_in_place(cache, q, window):
+        return attend_in_place(q, cache, pos).reshape(B, Tq, D), cache
     ck, cv = cache_read(cache)
     C = ck.shape[1]
     scale = 1.0 / np.sqrt(hd)

@@ -168,7 +168,8 @@ class LagunaBlock(Layer):
         what routing did puts ``"live"`` ((B, Tq) bool, broadcastable: the
         rows that are real tokens) into the cache entry and finds
         ``"routing"`` in the one returned."""
-        from ..generation import (cache_gather, cache_write, causal_valid,
+        from ..generation import (attend_in_place, cache_gather, cache_write,
+                                  causal_valid, reads_in_place,
                                   ring_positions)
 
         Tq = x.shape[1]
@@ -181,13 +182,17 @@ class LagunaBlock(Layer):
         with jax.named_scope("attention"):
             h, q, k, v = self._qkv(params, x, positions)
             new = cache_write(cache, {"k": k, "v": v}, pos, ring=ring)
-            ck, cv = cache_gather(new, ("k", "v"))          # (B, L, Hkv, hd)
-            kpos = ring_positions(new["tables"], new["k_pool"].shape[1], pos,
-                                  Tq) if ring else None
-            valid = causal_valid(pos, Tq, ck.shape[1], self.window, kpos)
-            valid = valid[None, None, None] if valid.ndim == 2 \
-                else valid[:, None, None]
-            x = x + self._attend(params, h, q, ck, cv, valid)
+            if reads_in_place(new, q, self.window):   # a full layer's step
+                a = attend_in_place(q, new, pos)
+            else:
+                ck, cv = cache_gather(new, ("k", "v"))      # (B, L, Hkv, hd)
+                kpos = ring_positions(new["tables"], new["k_pool"].shape[1],
+                                      pos, Tq) if ring else None
+                valid = causal_valid(pos, Tq, ck.shape[1], self.window, kpos)
+                valid = valid[None, None, None] if valid.ndim == 2 \
+                    else valid[:, None, None]
+                a = self._attend(h, q, ck, cv, valid)
+            x = x + self._gate_project(params, h, a)
         m, routing = self._ffn(params, x, cache.get("live"))
         if routing is not None:
             new = {**new, "routing": routing}
@@ -206,7 +211,8 @@ class LagunaBlock(Layer):
             see = see[None, None, None]
             if mask is not None:     # (B, T) padding: never a key
                 see = see & mask[:, None, None, None, :].astype(jnp.bool_)
-            x = x + self._attend(params, h, q, k, v, see)
+            x = x + self._gate_project(params, h,
+                                       self._attend(h, q, k, v, see))
         m, _ = self._ffn(params, x, None)
         return x + m, state, mask
 
@@ -233,18 +239,23 @@ class LagunaBlock(Layer):
         v = wide_einsum("btd,dhe->bthe", h, p["w_v"]).astype(h.dtype)
         return h, self._rope(q, positions), self._rope(k, positions), v
 
-    def _attend(self, params, h, q, k, v, see):
+    def _attend(self, h, q, k, v, see):
         """Grouped-query attention of ``q`` (B, Tq, H, hd) over keys and
         values (B, L, Hkv, hd) — the chunk's own in the full forward, the
-        cache's in ``decode`` — under ``see`` (broadcastable to (B, Hkv, G,
-        Tq, L)), gated a head and projected: (B, Tq, D)."""
-        p = params["attn"]
+        cache's gathered copy in ``decode`` — under ``see`` (broadcastable
+        to (B, Hkv, G, Tq, L)): (B, Tq, H, hd) in f32."""
         B, Tq, H, hd = q.shape
         Hkv = k.shape[2]
         qg = q.reshape(B, Tq, Hkv, H // Hkv, hd)
         s = wide_einsum("bqhgd,bkhd->bhgqk", qg, k) / np.sqrt(hd)
         w = jax.nn.softmax(jnp.where(see, s, -1e30), axis=-1).astype(h.dtype)
-        a = wide_einsum("bhgqk,bkhd->bqhgd", w, v).reshape(B, Tq, H, hd)
+        return wide_einsum("bhgqk,bkhd->bqhgd", w, v).reshape(B, Tq, H, hd)
+
+    def _gate_project(self, params, h, a):
+        """The attention's tail: ``a`` (B, Tq, H, hd) gated a head from the
+        normed rows ``h`` and projected: (B, Tq, D)."""
+        p = params["attn"]
+        B, Tq, H, hd = a.shape
         with jax.named_scope("attn_gate"):
             gate = GATE_ACTS[self.gate_act](
                 wide_einsum("btd,dh->bth", h, p["w_head_gate"]))

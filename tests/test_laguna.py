@@ -221,9 +221,9 @@ def test_the_gate_is_one_a_head_a_token():
     gates = jnp.zeros((64, 4))
 
     def attend(w_head_gate, w_o):
-        return blk._attend({"attn": dict(params["attn"], w_o=w_o,
-                                         w_head_gate=w_head_gate)},
-                           h, q, k, v, see)
+        return blk._gate_project({"attn": dict(params["attn"], w_o=w_o,
+                                               w_head_gate=w_head_gate)},
+                                 h, blk._attend(h, q, k, v, see))
 
     w_o = params["attn"]["w_o"]
     shut = attend(gates.at[:, 2].set(-1.0), w_o)

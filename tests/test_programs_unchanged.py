@@ -25,6 +25,16 @@ the chunk program two more operands FOR SUCH A MODEL; every pin above it
 stands as it was, and MiniCPM-SALA's programs are pinned from the commit that
 brought them.
 
+PR 46 re-took the ``gen_decode_paged`` pins of ``dense``, ``olmoe`` and
+``laguna``, and only those: a decode step over a paged k/v cache kept whole
+now reads the pools in place through the Pallas kernel of
+``ops/paged_attention.py`` (on the CPU its interpreter-mode lowering is part
+of the text) where it gathered at capacity. The other twelve hashes stand byte
+for byte: ``glm``'s and ``sala``'s decode steps (a latent pool, a sparse
+layer's selected blocks: they gather themselves) and every sampler and chunk
+program are the parent's, which is that PR's proof that
+``glm47f-longchat-decode`` and ``sala-longctx-decode`` cannot move by its doing.
+
 A change that is MEANT to alter these programs re-takes the hashes (run this
 file with ``-s`` and copy what it prints) and says so in ``PERF.md``.
 """
@@ -43,12 +53,12 @@ from deeplearning4j_tpu.serve.continuous import ContinuousBatcher
 PARENT = {
     "dense": {
         "gen_sample": "5735026fd23a417e6ff6cd8d38548127448acbbb804e224ea67331c92424dc92",
-        "gen_decode_paged": "5724b0e0c459dea1abff707eefbec2ec22e55370a141c770270f5ad9eb47a7a0",
+        "gen_decode_paged": "692e89af3a245104f15535b4916e73408869949827f4091bd721e0f586e9b99e",  # re-taken in PR 46
         "gen_prefill_chunk": "a92cab8705c9d83886433ac489b4f39741c788bf32c9a104358879481d95d7be",
     },
     "olmoe": {
         "gen_sample": "5735026fd23a417e6ff6cd8d38548127448acbbb804e224ea67331c92424dc92",
-        "gen_decode_paged": "b9df90ca43a57ce52967e9ffe70a62398d0c2f13ebadc9ba2b3a4c6121723f91",
+        "gen_decode_paged": "efbf78042ed01f74e53d48c06b8aba181e135219eaf862c194c0d8f93e5fc81c",  # re-taken in PR 46
         "gen_prefill_chunk": "1024b3e649c2900012e645a75ebc0fb4f18852f8583051d01eb38f6c58b1d026",
     },
     # taken at 531c641 (PR 33's parent), when PR 33 touched the cache
@@ -61,7 +71,7 @@ PARENT = {
     # taken at PR 33, the commit that brought the model
     "laguna": {
         "gen_sample": "5735026fd23a417e6ff6cd8d38548127448acbbb804e224ea67331c92424dc92",
-        "gen_decode_paged": "39be426a048868f10f9cd650ce247c478912c7fb3cd4d2ee8ab247c7340a3e1c",
+        "gen_decode_paged": "361e270ad88421c770c8d26cccd8a547046aca6b7e5918a02a0352793339aa9e",  # re-taken in PR 46
         "gen_prefill_chunk": "6647156ad2ac696815543b0566d60f6b244e5dddf5017cce5888993901fbefe2",
     },
     # taken at PR 45, the commit that brought the model

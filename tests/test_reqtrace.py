@@ -480,10 +480,11 @@ class TestFleetTracingEndToEnd:
         lm = CausalLM(seed=0, input_shape=(16,), num_layers=2, d_model=32,
                       num_heads=4, vocab=50).build()
         lm.init()
-        # deadline comfortably above a CPU compile pause (which can stretch
-        # past 2s when the whole suite loads the machine), far below the
-        # injected hang — the warm pass must not trip a false stall
-        fleet = FleetRegistry(watchdog_s=3.0)
+        # deadline comfortably above a CPU compile pause (3.8 s since PR 46:
+        # the decode step holds the paged-attention kernel's interpreter-mode
+        # lowering, and more when the whole suite loads the machine), far
+        # below the injected hang — the warm pass must not trip a false stall
+        fleet = FleetRegistry(watchdog_s=8.0)
         fleet.add("g", lm, gen_opts={"slots": 2, "capacity": 24, "seed": 0})
         tracer = Tracer()
         reqtrace_mod.install(RequestTracer(
@@ -495,7 +496,7 @@ class TestFleetTracingEndToEnd:
             body = {"prompt": [3, 1, 4], "max_new_tokens": 6,
                     "temperature": 0.0, "stream": False}
             cl.post("/v1/models/g/generate", body)  # warm, fault-free
-            fp.inject_spec("serve.decode_step:hang:hang_s=8,times=1")
+            fp.inject_spec("serve.decode_step:hang:hang_s=20,times=1")
             with pytest.raises(urllib.error.HTTPError) as ei:
                 cl.post("/v1/models/g/generate", body)
             assert ei.value.code == 503
