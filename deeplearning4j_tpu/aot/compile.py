@@ -4,7 +4,9 @@ The serving tier's contract is a *bounded executable set*; this module
 makes that set *persistent*. :class:`AotFunction` wraps one jitted
 function and resolves each call signature in order:
 
-1. in-memory executable map (steady state: one dict lookup),
+1. in-memory executable map (steady state: one flatten of the operands
+   and one dict lookup by :func:`~.keys.structural_key`; the string
+   signature is built once, with the executable it names),
 2. the persistent :class:`~.store.AotStore` — ``deserialize_and_load`` of
    an executable some earlier process compiled (cold-start/hot-swap win),
 3. live ``jit(...).lower(...).compile()`` — the normal tracing path,
@@ -35,7 +37,7 @@ from ..chaos.retry import RetryPolicy
 from ..obs import profile as _prof
 from ..obs import reqtrace as _rt
 from .keys import arch_fingerprint, cache_key, call_signature, \
-    runtime_fingerprint
+    runtime_fingerprint, structural_key
 from .store import AotCorruptEntry, AotStore, AotStoreError, AotVersionError
 
 _BLOB_SCHEMA = 2  # 2: device ids recorded beside the executable
@@ -129,8 +131,8 @@ class AotFunction:
         self._retry = retry if retry is not None else RetryPolicy(
             attempts=3, base_s=0.02, cap_s=0.5, metrics=metrics)
         self._runtime = None  # resolved lazily: jax may not be booted yet
+        # structural key -> (executable, its string signature, store key)
         self._exes: dict = {}
-        self._keys: dict = {}  # signature -> store key, for coverage records
         self._lock = threading.RLock()
         self._acquire_seconds = 0.0
         if metrics is not None and self.store is not None:
@@ -147,6 +149,10 @@ class AotFunction:
             self._m_strict = metrics.counter(
                 "serve_aot_strict_misses_total", labels,
                 help="signatures refused (typed 503) by strict AOT mode")
+            self._m_sigs = metrics.counter(
+                "serve_aot_signature_strings_total", {**labels, "tag": tag},
+                help="string signatures built: one an executable acquired, "
+                     "none a call")
         else:
             from ..obs.metrics import MetricsRegistry
 
@@ -160,16 +166,17 @@ class AotFunction:
                 "serve_aot_fallback_total", {**labels, "cause": cause})
             self._m_strict = null.counter(
                 "serve_aot_strict_misses_total", labels)
+            self._m_sigs = null.counter(
+                "serve_aot_signature_strings_total", {**labels, "tag": tag})
 
     # ------------------------------------------------------------------ calls
     def __call__(self, *args):
         if self.store is None:
             return self._fn(*args)
-        sig = call_signature(args)
+        skey = structural_key(args)
         with self._lock:
-            exe = self._exes.get(sig)
-        if exe is None:
-            exe = self._acquire(sig, args)
+            held = self._exes.get(skey)
+        exe, sig, _ = held if held is not None else self._acquire(skey, args)
         # continuous-profiler seam (obs/profile): one attribute load + a
         # None check when profiling is off — the hot decode tick's cost
         prof = _prof.ACTIVE
@@ -183,30 +190,28 @@ class AotFunction:
         ``jax.ShapeDtypeStruct`` leaves. Returns True when AOT-capable."""
         if self.store is None:
             return False
-        sig = call_signature(args)
-        with self._lock:
-            if sig not in self._exes:
-                self._acquire(sig, args)
+        self._acquire(structural_key(args), args)
         return True
 
     @property
     def executables(self) -> dict:
         """Signature -> loaded executable (diagnostic)."""
         with self._lock:
-            return dict(self._exes)
+            return {sig: exe for exe, sig, _ in self._exes.values()}
 
     def store_key(self, sig: Tuple[str, ...]) -> str:
         """The store key of one acquired signature ("" before acquire) —
         how the profiler stamps its (component, tag, sig, key) identity."""
         with self._lock:
-            return self._keys.get(sig, "")
+            return next((key for _, acquired, key in self._exes.values()
+                         if acquired == sig), "")
 
     def warmed_keys(self) -> list:
         """Sorted store keys of every executable this wrapper acquired —
         the concrete coverage a prebuild run stamps into the store's
         coverage record (``aot/manifest.py``)."""
         with self._lock:
-            return sorted(set(self._keys.values()))
+            return sorted({key for _, _, key in self._exes.values()})
 
     @property
     def acquire_seconds(self) -> float:
@@ -222,14 +227,18 @@ class AotFunction:
         return cache_key(self.tag, self.arch, sig, donate=self.donate,
                          runtime=self._runtime)
 
-    def _acquire(self, sig: Tuple[str, ...], args: Sequence[Any]):
+    def _acquire(self, skey, args: Sequence[Any]):
         """Store -> live trace, under the lock (a concurrent publish warm
-        and the dispatch thread must not double-compile one signature)."""
+        and the dispatch thread must not double-compile one signature).
+        Returns the map's entry for ``skey``; the only place the string
+        signature is built."""
         with self._lock:
-            exe = self._exes.get(sig)
-            if exe is not None:
-                return exe
+            held = self._exes.get(skey)
+            if held is not None:
+                return held
             t0 = time.perf_counter()
+            sig = call_signature(args)
+            self._m_sigs.inc()
             key = self._key(sig)
             with _rt.span("aot.acquire", tag=self.tag):
                 exe = self._load(key)
@@ -251,10 +260,9 @@ class AotFunction:
                     if self._compile_counter is not None:
                         self._compile_counter.inc()  # a real trace happened
                     self._save(key, exe)
-            self._exes[sig] = exe
-            self._keys[sig] = key
+            held = self._exes[skey] = (exe, sig, key)
             self._acquire_seconds += time.perf_counter() - t0
-            return exe
+            return held
 
     def _load(self, key: str):
         try:

@@ -44,16 +44,23 @@ def runtime_fingerprint() -> dict:
     }
 
 
-def _leaf_sig(leaf: Any) -> str:
-    """One pytree leaf as a stable string: arrays by shape/dtype, python
-    scalars by type (their value is traced, not compiled in)."""
+def _leaf_key(leaf: Any) -> Any:
+    """What of one pytree leaf shapes a compilation, as a value that
+    hashes: arrays by ``(shape, dtype)``, python scalars by their type's
+    name (their value is traced, not compiled in)."""
     shape = getattr(leaf, "shape", None)
     dtype = getattr(leaf, "dtype", None)
     if shape is not None and dtype is not None:
-        return f"{tuple(shape)}:{str(dtype)}"
-    if leaf is None:
-        return "none"
-    return f"py:{type(leaf).__name__}"
+        return tuple(shape), dtype
+    return None if leaf is None else type(leaf).__name__
+
+
+def _leaf_sig(leaf: Any) -> str:
+    """:func:`_leaf_key` as a stable string."""
+    key = _leaf_key(leaf)
+    if isinstance(key, tuple):
+        return f"{key[0]}:{key[1]}"
+    return "none" if key is None else f"py:{key}"
 
 
 def arch_fingerprint(params: Any, state: Any = None) -> str:
@@ -75,12 +82,28 @@ def call_signature(args: Sequence[Any]) -> Tuple[str, ...]:
     """The bucket signature of one call: flattened leaf shapes/dtypes plus
     the argument treedef. This is what the serving tier's shape buckets
     vary over — and exactly what a compiled executable is specialized to.
-    Hashable (a tuple of strings), so it doubles as the in-memory
-    executable-map key."""
+    A tuple of strings: what :func:`cache_key` hashes and the profiler
+    reports. Formatting it costs milliseconds on a deep model, so it is
+    built when an executable is acquired; the call path looks its
+    executable up by :func:`structural_key`."""
     import jax
 
     leaves, treedef = jax.tree.flatten(tuple(args))
     return tuple(_leaf_sig(leaf) for leaf in leaves) + (str(treedef),)
+
+
+def structural_key(args: Sequence[Any]) -> Tuple[Any, tuple]:
+    """What :func:`call_signature` separates, from one flatten and no
+    string: ``(treedef, (shape, dtype) or type name per leaf)``, the
+    values the signature formats. Two operand lists have equal keys
+    exactly when their signatures are equal (``PyTreeDef`` and numpy
+    dtypes hash and compare by value), so an abstract
+    ``jax.ShapeDtypeStruct`` keys as the array it stands for. The
+    in-memory executable-map key."""
+    import jax
+
+    leaves, treedef = jax.tree.flatten(tuple(args))
+    return treedef, tuple(map(_leaf_key, leaves))
 
 
 def cache_key(tag: str, arch: str, sig: Iterable[str],

@@ -21,6 +21,7 @@ The load-bearing properties, each tested directly:
 """
 
 import hashlib
+import itertools
 import pickle
 import threading
 
@@ -32,6 +33,8 @@ import pytest
 from deeplearning4j_tpu.aot import (AotCorruptEntry, AotFunction, AotStore,
                                     arch_fingerprint, cache_key,
                                     call_signature, runtime_fingerprint)
+from deeplearning4j_tpu.aot import keys as aot_keys
+from deeplearning4j_tpu.aot.keys import structural_key
 from deeplearning4j_tpu.obs.metrics import MetricsRegistry
 
 
@@ -47,6 +50,31 @@ def _series(metrics, name):
 def _fallbacks_by_cause(metrics):
     return {dict(k)["cause"]: v for k, v in
             _series(metrics, "serve_aot_fallback_total").items()}
+
+
+_F32 = np.zeros((2, 3), np.float32)
+#: name -> (the class it belongs to, an operand list): two lists of one class
+#: must share an executable, two of different classes must not
+_OPERANDS = {
+    "zeros": ("base", (_F32, np.int32(7))),
+    "other_values": ("base", (np.ones((2, 3), np.float32), np.int32(9))),
+    "jax_array": ("base", (jnp.zeros((2, 3), jnp.float32), jnp.int32(1))),
+    "shape_dtype_struct": ("base", (jax.ShapeDtypeStruct((2, 3), jnp.float32),
+                                    jax.ShapeDtypeStruct((), jnp.int32))),
+    "other_shape": ("shape", (np.zeros((2, 4), np.float32), np.int32(7))),
+    "other_rank": ("rank", (np.zeros((2, 3, 1), np.float32), np.int32(7))),
+    "float16": ("f16", (np.zeros((2, 3), np.float16), np.int32(7))),
+    "bfloat16": ("bf16", (jnp.zeros((2, 3), jnp.bfloat16), np.int32(7))),
+    "bfloat16_struct": ("bf16", (jax.ShapeDtypeStruct((2, 3), jnp.bfloat16),
+                                 np.int32(0))),
+    "none_for_array": ("none", (None, np.int32(7))),
+    "python_float": ("pyfloat", (_F32, 7.0)),
+    "python_int": ("pyint", (_F32, 7)),
+    "numpy_float32": ("npfloat", (_F32, np.float32(7.0))),
+    "dict_of_leaves": ("dict", ({"a": _F32, "b": np.int32(7)},)),
+    "dict_other_names": ("dict2", ({"a": _F32, "c": np.int32(7)},)),
+    "tuple_of_leaves": ("tuple", ((_F32, np.int32(7)),)),
+}
 
 
 class TestKeys:
@@ -96,6 +124,22 @@ class TestKeys:
         d = call_signature((jax.ShapeDtypeStruct((2, 3), jnp.float32),
                             jax.ShapeDtypeStruct((), jnp.int32)))
         assert a == d
+
+    @pytest.mark.parametrize(
+        "a, b", itertools.combinations(sorted(_OPERANDS), 2),
+        ids=lambda name: name)
+    def test_structural_key_partitions_as_the_signature(self, a, b):
+        """The executable map's key and the string signature put two
+        operand lists together, or apart, alike: what ``warm()`` or an
+        earlier call acquired under one is what the next call finds under
+        the other, and no two signatures share an executable."""
+        (same_a, args_a), (same_b, args_b) = _OPERANDS[a], _OPERANDS[b]
+        ka, kb = structural_key(args_a), structural_key(args_b)
+        assert (ka == kb) == (same_a == same_b)
+        assert (call_signature(args_a) == call_signature(args_b)) \
+            == (same_a == same_b)
+        if same_a == same_b:
+            assert hash(ka) == hash(kb)
 
 
 class TestStore:
@@ -306,6 +350,152 @@ class TestAotFunction:
         assert f.store is None
         np.testing.assert_array_equal(np.asarray(f(_P, _X)), _X @ _P)
         assert AotStore(tmp_path).stats()["entries"] == 0
+
+
+def _sig_strings(metrics):
+    """``serve_aot_signature_strings_total`` by tag."""
+    return {dict(k)["tag"]: v for k, v in
+            _series(metrics, "serve_aot_signature_strings_total").items()}
+
+
+class TestLookupBuildsNoString:
+    """The call path finds its executable by ``structural_key``; the string
+    signature is built where an executable is acquired and nowhere else."""
+
+    #: the runtime the pinned key below was computed under
+    RUNTIME = {"jax": "0.9.0", "jaxlib": "0.9.0", "backend": "tpu",
+               "device_kind": "TPU v5 lite", "device_count": 1,
+               "process_count": 1}
+
+    @staticmethod
+    def _batcher(store, metrics, **kw):
+        from deeplearning4j_tpu import models
+        from deeplearning4j_tpu.serve.continuous import ContinuousBatcher
+
+        model = models.CausalLM(seed=0, input_shape=(32,), num_layers=2,
+                                d_model=32, num_heads=4, num_kv_heads=1,
+                                vocab=50).build()
+        model.init()
+        # one chunk bucket: the static set is one executable a tag
+        return ContinuousBatcher(model, slots=2, capacity=32, block_size=4,
+                                 prefill_chunk=8, prompt_buckets=(8, 32),
+                                 seed=0, aot_store=store, metrics=metrics,
+                                 **kw)
+
+    def test_gen_programs_one_string_an_executable(self, tmp_path,
+                                                   monkeypatch):
+        from deeplearning4j_tpu.serve.errors import AotTraceError
+
+        m = MetricsRegistry()
+        cb = self._batcher(AotStore(tmp_path), m)
+        try:
+            progs = cb._programs
+            assert progs.chunk_buckets == (8,)
+            assert _sig_strings(m) == {"gen_sample": 1, "gen_decode_paged": 1,
+                                       "gen_prefill_chunk": 1}
+            entered, calls = [], {}
+            real_leaf_sig = aot_keys._leaf_sig
+            monkeypatch.setattr(
+                aot_keys, "_leaf_sig",
+                lambda leaf: entered.append(1) or real_leaf_sig(leaf))
+            for name in ("decode", "prefill_chunk", "sample"):
+                def counted(*a, _real=getattr(progs, name), _name=name, **k):
+                    calls[_name] = calls.get(_name, 0) + 1
+                    return _real(*a, **k)
+                monkeypatch.setattr(progs, name, counted)
+            rng = np.random.RandomState(5)
+            for i in range(5):
+                prompt = rng.randint(1, 50, (3 + i,)).astype(np.int32)
+                out = cb.generate(prompt, 12, temperature=0.7 * (i % 2),
+                                  top_k=5)
+                assert len(out) == 12
+            assert calls["decode"] >= 50 and calls["prefill_chunk"] == 5 \
+                and calls["sample"] == 5, calls
+            assert not entered, "a call formatted its signature again"
+            assert _sig_strings(m) == {"gen_sample": 1, "gen_decode_paged": 1,
+                                       "gen_prefill_chunk": 1}
+        finally:
+            cb.shutdown()
+        # the store that boot wrote serves a strict replica, and an operand
+        # list of another shape is refused before anything runs
+        m2 = MetricsRegistry()
+        cb2 = self._batcher(AotStore(tmp_path), m2, strict_aot=True)
+        try:
+            compiles = m2.counter("serve_compile_misses_total",
+                                  {"component": "generate"})
+            fns = cb2.aot_functions()
+            held = {t: set(f.executables) for t, f in fns.items()}
+            with pytest.raises(AotTraceError):
+                cb2._programs.sample(jnp.zeros((51,), jnp.float32),
+                                     jnp.zeros((2,), jnp.uint32), 0.0, 50)
+            assert compiles.value == 0
+            assert {t: set(f.executables) for t, f in fns.items()} == held
+            assert _series(m2, "serve_aot_strict_misses_total")[
+                (("component", "generate"),)] == 1
+            assert len(cb2.generate(np.array([3, 4, 5], np.int32), 4,
+                                    temperature=0.0)) == 4
+        finally:
+            cb2.shutdown()
+
+    def test_strict_refuses_another_shape_and_runs_nothing(self, tmp_path):
+        from deeplearning4j_tpu.serve.errors import AotTraceError
+
+        traced = []
+
+        def fwd(p, x):
+            traced.append(x.shape)
+            return x @ p + 1.0
+
+        _wrapper(jax.jit(fwd), AotStore(tmp_path), MetricsRegistry())(_P, _X)
+        assert traced == [(2, 4)]
+        m = MetricsRegistry()
+        f = AotFunction(jax.jit(fwd), tag="fwd", store=AotStore(tmp_path),
+                        metrics=m, arch=arch_fingerprint(_P),
+                        component="generate", strict=True)
+        np.testing.assert_array_equal(np.asarray(f(_P, _X)), _X @ _P + 1.0)
+        with pytest.raises(AotTraceError):
+            f(_P, np.ones((3, 4), np.float32))
+        with pytest.raises(AotTraceError):
+            f.warm(_P, jax.ShapeDtypeStruct((2, 4), jnp.bfloat16))
+        assert traced == [(2, 4)]  # the strict wrapper traced nothing
+        assert len(f.executables) == 1
+        assert _series(m, "serve_aot_strict_misses_total")[
+            (("component", "generate"),)] == 2
+
+    def test_store_keys_are_the_old_lookups(self, tmp_path):
+        """The key on disk is a hash of the STRING signature: it is what it
+        was when the string also keyed the in-memory map (the literal below
+        was computed by that code), so a store built then is hit now."""
+        args = ({"w": _P, "b": None}, _X, np.int32(3), 0.5)
+        pinned = ("63dbeba1e8dcc5372963a50f9beb7bb6"
+                  "214cce21fdbf3510cb63811243b31737")
+        assert cache_key("fwd", "arch0", call_signature(args), donate=(1,),
+                         runtime=self.RUNTIME) == pinned
+
+        def wrapper(metrics):
+            f = AotFunction(
+                jax.jit(lambda p, x, n, t: x @ p["w"] * t + n),
+                tag="fwd", store=AotStore(tmp_path), metrics=metrics,
+                arch="arch0", component="generate", donate_argnums=(1,),
+                compile_counter=metrics.counter(
+                    "serve_compile_misses_total", {"component": "generate"}))
+            f._runtime = self.RUNTIME
+            return f
+
+        m1 = MetricsRegistry()
+        f1 = wrapper(m1)
+        f1.warm(*args)
+        assert AotStore(tmp_path).keys() == [pinned] == f1.warmed_keys()
+        (sig,) = f1.executables
+        assert sig == call_signature(args) and f1.store_key(sig) == pinned
+        m2 = MetricsRegistry()
+        f2 = wrapper(m2)
+        f2(*args)
+        assert m2.counter("serve_compile_misses_total",
+                          {"component": "generate"}).value == 0
+        assert _series(m2, "serve_aot_hits_total")[
+            (("component", "generate"),)] == 1
+        assert _sig_strings(m2) == {"fwd": 1}
 
 
 class TestPublishWarming:
